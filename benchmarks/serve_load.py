@@ -490,8 +490,9 @@ def multihost_run(mix, count, rate, *, hosts=2, backend="ref", seed=0,
     from benchmarks.common import row
     from repro.obs import StreamingHistogram
     from repro.serve import SVDRouter
-    from repro.serve.worker import spawn_worker_process
+    from repro.serve.worker import check_fleet_fits, spawn_worker_process
 
+    check_fleet_fits(hosts)
     if kill_host and jax_distributed:
         raise ValueError("kill_host + jax_distributed: a SIGKILLed peer "
                          "fatally cascades through the XLA coordination "

@@ -94,7 +94,7 @@ def main(argv=None) -> int:
         # searches' sigma-agreement column reads ~1e-5 instead of ~1e-16.
         import jax
         jax.config.update("jax_enable_x64", True)
-    backend, _ = ops.resolve_backend(args.backend)
+    backend, _ = ops.resolve_backend(args.backend, dtype=dtype)
     try:
         batches = tuple(sorted({int(b) for b in args.batches.split(",")
                                 if b.strip()}))

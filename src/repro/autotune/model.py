@@ -108,20 +108,18 @@ def device_kind(device=None) -> str:
 
 
 def profile_for(kind: str | None = None) -> DeviceProfile:
-    """Best-effort profile for a device kind string (normalized prefix
-    match: "TPU v5 lite" and "tpu v5e" both hit the v5e row); unknown kinds
-    fall back by platform family, ultimately to the cpu row."""
+    """The profile row for a device kind string (normalized prefix match:
+    "TPU v5 lite" and "tpu v5e" both hit the v5e row).  A kind with no row
+    raises ``ValueError``: costing one chip with another's constants would
+    silently mis-tune it."""
     k = (kind if kind is not None else device_kind()).lower()
     norm = k.replace("tpu v5 lite", "tpu v5e").replace("tpu v5litepod",
                                                        "tpu v5e")
     for name, prof in PROFILES.items():
         if norm.startswith(name) or name.startswith(norm):
             return prof
-    if "tpu" in norm:
-        return PROFILES["tpu v5e"]
-    if any(tag in norm for tag in ("gpu", "cuda", "rocm", "nvidia")):
-        return PROFILES["gpu"]
-    return PROFILES["cpu"]
+    raise ValueError(f"no device profile for kind {kind!r}; known kinds: "
+                     f"{sorted(PROFILES)}")
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .householder import exact_matmul as _mm
 from .bidiag_svd import (_gk_prescale, _vectors_from_sigma,
                          bidiag_singular_values, bidiag_svd,
                          default_bisect_iters, gk_offdiag)
@@ -224,7 +225,7 @@ def _leaf_eigen(a: jax.Array, b: jax.Array, *, bisect_iters: int,
         mask = ((ks < k) & (lam[k] - lam < ctol)).astype(acc)
 
         def clean(w):
-            w = w - (mask * (rows @ w)) @ rows
+            w = w - _mm(mask * _mm(rows, w), rows)
             return w, jnp.linalg.norm(w)
 
         w1, n1 = clean(rows[k])

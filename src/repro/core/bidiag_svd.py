@@ -27,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.householder import exact_matmul as _mm
+
 __all__ = ["gk_offdiag", "sturm_count", "bidiag_singular_values",
            "bidiag_svd", "default_bisect_iters"]
 
@@ -298,7 +300,7 @@ def _orthonormalize_pairs(us, vs, sig, dd, ee):
         mask = ((karr < k) & ((sig - sig[k]) < ctol)).astype(acc)
 
         def clean(w):
-            w = w - (mask * (rows @ w)) @ rows
+            w = w - _mm(mask * _mm(rows, w), rows)
             return w, jnp.linalg.norm(w)
 
         w1, n1 = clean(vec)

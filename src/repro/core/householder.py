@@ -15,7 +15,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["make_reflector", "apply_left", "apply_right", "reflector_matrix"]
+__all__ = ["make_reflector", "apply_left", "apply_right", "reflector_matrix",
+           "exact_matmul"]
+
+
+def exact_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at the operands' full precision.
+
+    A TPU runs an f32 dot as one bf16 pass unless asked for more, which
+    would cap every f32 result at ~3 significant digits; the pipeline's
+    accuracy contract (a few n*eps of the working dtype) needs the exact
+    passes.  Other platforms compute f32/f64 dots exactly either way.
+    """
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
 
 
 def make_reflector(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -47,7 +60,7 @@ def apply_left(v: jax.Array, tau: jax.Array, c: jax.Array) -> jax.Array:
     """C <- (I - tau v v^T) C,  v: (L,), C: (L, m)."""
     acc = jnp.float32 if c.dtype in (jnp.bfloat16, jnp.float16) else c.dtype
     vv = v.astype(acc)
-    w = vv @ c.astype(acc)              # (m,)
+    w = exact_matmul(vv, c.astype(acc))  # (m,)
     out = c.astype(acc) - tau.astype(acc) * jnp.outer(vv, w)
     return out.astype(c.dtype)
 
@@ -56,7 +69,7 @@ def apply_right(v: jax.Array, tau: jax.Array, c: jax.Array) -> jax.Array:
     """C <- C (I - tau v v^T),  v: (L,), C: (m, L)."""
     acc = jnp.float32 if c.dtype in (jnp.bfloat16, jnp.float16) else c.dtype
     vv = v.astype(acc)
-    w = c.astype(acc) @ vv              # (m,)
+    w = exact_matmul(c.astype(acc), vv)  # (m,)
     out = c.astype(acc) - tau.astype(acc) * jnp.outer(w, vv)
     return out.astype(c.dtype)
 

@@ -21,10 +21,20 @@ import math
 import numpy as np
 
 __all__ = [
+    "sigma_error",
     "reduce_stage_dense_ref",
     "bidiagonalize_dense_ref",
     "bidiagonalize_dense_ref_uv",
 ]
+
+
+def sigma_error(sigma, a) -> float:
+    """Normwise error ``max|sigma - sigma_ref| / sigma_max`` of a computed
+    spectrum against the fp64 LAPACK singular values of the input ``a`` —
+    the oracle every precision of the pipeline is judged against."""
+    ref = np.linalg.svd(np.asarray(a, np.float64), compute_uv=False)
+    smax = max(float(np.max(ref)), np.finfo(np.float64).tiny)
+    return float(np.max(np.abs(np.asarray(sigma, np.float64) - ref)) / smax)
 
 
 def _np_reflector(x: np.ndarray):

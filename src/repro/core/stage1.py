@@ -25,6 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.householder import exact_matmul as _mm
+
 __all__ = ["band_reduce", "wy_t_factor"]
 
 
@@ -58,10 +60,10 @@ def _masked_reflector(col: jax.Array, pivot: jax.Array):
 def wy_t_factor(v: jax.Array, taus: jax.Array) -> jax.Array:
     """Compact-WY T (upper triangular): H_0 H_1 ... H_{k-1} = I - V T V^T."""
     k = taus.shape[0]
-    vtv = v.T @ v
+    vtv = _mm(v.T, v)
 
     def body(j, t):
-        col = -taus[j] * (t @ jnp.where(jnp.arange(k) < j, vtv[:, j], 0))
+        col = -taus[j] * _mm(t, jnp.where(jnp.arange(k) < j, vtv[:, j], 0))
         col = col.at[j].set(taus[j])
         keep = jnp.arange(k) <= j
         return t.at[:, j].set(jnp.where(keep, col, 0))
@@ -122,7 +124,7 @@ def _band_reduce_2d(a: jax.Array, *, nb: int, backend: str,
             c = c0 + j
             stripe = jax.lax.dynamic_slice(a, (0, c0), (big, nb))
             v, tau, beta = _masked_reflector(stripe[:, j], c)
-            w = v @ stripe
+            w = _mm(v, stripe)
             stripe = stripe - tau * jnp.outer(v, w)
             newcol = jnp.where(idx > c, 0.0, stripe[:, j])       # structural 0s
             newcol = newcol.at[c].set(jnp.where(tau != 0, beta, newcol[c]))
@@ -149,9 +151,9 @@ def _band_reduce_2d(a: jax.Array, *, nb: int, backend: str,
             # kernel was a no-op there already.
             a = jax.lax.dynamic_update_slice(a, stripe, (0, c0))
         else:
-            u = v_blk.T @ a
+            u = _mm(v_blk.T, a)
             u = jnp.where(idx[None, :] >= c0 + nb, u, 0)
-            a = a - v_blk @ (t.T @ u)
+            a = a - _mm(v_blk, _mm(t.T, u))
 
         # -------- LQ panel: rows [c0, c0+nb), pivot col c0+nb+j --------------
         def lq_reflector(j, carry):
@@ -160,7 +162,7 @@ def _band_reduce_2d(a: jax.Array, *, nb: int, backend: str,
             c_piv = c0 + nb + j
             stripe = jax.lax.dynamic_slice(a, (c0, 0), (nb, big))
             v, tau, beta = _masked_reflector(stripe[j, :], c_piv)
-            w = stripe @ v
+            w = _mm(stripe, v)
             stripe = stripe - tau * jnp.outer(w, v)
             newrow = jnp.where(idx > c_piv, 0.0, stripe[j, :])
             newrow = newrow.at[c_piv].set(jnp.where(tau != 0, beta, newrow[c_piv]))
@@ -173,9 +175,9 @@ def _band_reduce_2d(a: jax.Array, *, nb: int, backend: str,
                                                   (a, v0, t0))
         tr = wy_t_factor(vr_blk, taus_r)
         # blocked trailing update from the right on rows >= c0+nb
-        w = a @ vr_blk
+        w = _mm(a, vr_blk)
         w = jnp.where(idx[:, None] >= c0 + nb, w, 0)
-        a = a - w @ (tr @ vr_blk.T)
+        a = a - _mm(w, _mm(tr, vr_blk.T))
         if not tape:
             return a
         vqs, tqs, vls, tls = carry[1:]

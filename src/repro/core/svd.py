@@ -48,6 +48,7 @@ from repro.core import bidiag_dc as s3dc
 from repro.core import bidiag_svd as s3
 from repro.core import transforms
 from repro.core import tuning
+from repro.core.householder import exact_matmul
 from repro.kernels import ops
 
 __all__ = ["singular_values", "banded_singular_values", "bidiagonal_of",
@@ -167,13 +168,8 @@ def _resolve_tracer(trace):
     ambient one (``repro.obs.current()``), else None.  Host spans are only
     meaningful outside jax tracing (DESIGN.md §16)."""
     tr = trace if trace is not None else obs.current()
-    if tr is None:
+    if tr is None or not jax.core.trace_ctx.is_top_level():
         return None
-    try:
-        if not jax.core.trace_state_clean():
-            return None
-    except Exception:
-        pass
     return tr
 
 
@@ -256,8 +252,8 @@ def _fused_path(a: jax.Array, cfg: tuning.PipelineConfig, *,
                                   config=cfg)
     ub, sig, vtb = _stage3_svd(d, e, cfg)
     # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
-    u = jnp.matmul(u2, ub)
-    vt = jnp.matmul(vtb, vt2)
+    u = exact_matmul(u2, ub)
+    vt = exact_matmul(vtb, vt2)
     return (u.reshape(lead + (n, n)), sig.reshape(lead + (n,)),
             vt.reshape(lead + (n, n)))
 
@@ -438,8 +434,8 @@ def _uv_pipeline(a: jax.Array, *, config: tuning.PipelineConfig,
     ub, sig, vtb = _stage3_svd_traced(d, e, config)
     # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
     with obs.span("compose") as sp:
-        u = jnp.matmul(u2, ub)
-        vt = jnp.matmul(vtb, vt2)
+        u = exact_matmul(u2, ub)
+        vt = exact_matmul(vtb, vt2)
         sp.fence((u, vt))
     return u, sig, vt
 

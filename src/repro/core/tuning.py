@@ -365,7 +365,8 @@ class PipelineConfig:
         """Resolve every knob to a concrete value.
 
         ``backend="auto"`` and ``interpret=None`` are resolved by the backend
-        registry (pallas on TPU, ref elsewhere; interpret off-TPU only);
+        registry (pallas on TPU, ref elsewhere and for float64 on TPU;
+        interpret off-TPU only);
         ``tw=None`` falls back to the cache-line/lane heuristic;
         ``max_batch=None`` uses the Eq.-1 occupancy deficit for (n, bw);
         ``fuse=None`` asks the VMEM model for the deepest super-step that
@@ -402,7 +403,7 @@ class PipelineConfig:
         bw = max(bw, 1)
         if n is not None:
             bw = min(bw, max(n, 1))
-        backend, interpret = ops.resolve_backend(backend, interpret)
+        backend, interpret = ops.resolve_backend(backend, interpret, dtype)
         tuned = None
         if autotune and n is not None:
             from repro.autotune import cache as _at_cache   # deferred: cycle

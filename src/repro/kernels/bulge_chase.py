@@ -20,12 +20,13 @@ Fused super-steps (DESIGN.md §9): with fuse depth K >= 2 a grid step owns
 the CONTIGUOUS band-storage block (H, K*b_in + tw + 1) covering K
 consecutive cycles of its sweep.  The diagonal shear that rolls band
 storage into dense windows — done host-side per cycle at K=1 — moves inside
-the kernel: one relayout (transpose + pad + reshape, the flatten shear)
-builds a VMEM-resident dense workspace, the K cycles chase at static
-offsets reusing the tw+1-column overlap between consecutive windows without
-ever leaving VMEM, and one inverse relayout writes the block back.  HBM
-sees one contiguous block load + store per K cycles instead of K sheared
-gather/scatter round trips.
+the kernel: one strided roll plus a transpose builds a VMEM-resident dense
+workspace, and the K cycles chase at the workspace origin (the workspace is
+rolled by b_in between cycles), reusing the tw+1-column overlap between
+consecutive windows without ever leaving VMEM.  HBM sees one contiguous
+block load per K cycles; the kernel hands back the sheared rows and the
+wrapper un-shears them in the store (Mosaic has no roll by minus the row
+index).
 
 The kernel is batch-oblivious: a window neither knows nor cares which matrix
 it came from, so the batch-native pipeline (DESIGN.md §4) simply flattens a
@@ -42,83 +43,106 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["chase_cycle_pallas", "chase_superstep_pallas"]
 
 
-def _reflector_in_kernel(x, acc):
-    """larfg on a VREG-resident vector; tau=0 on zero tails (edge no-op)."""
+def _reflector_in_kernel(x, pos, axis, acc):
+    """larfg on a VREG-resident 2-D vector; tau=0 on zero tails (edge no-op).
+
+    ``x`` is a (1, L) row (``axis=1``) or an (L, 1) column (``axis=0``);
+    ``pos`` is the int32 iota along that axis.  Scalars come back as (1, 1)
+    arrays: Mosaic keeps every value at least 2-D.
+    """
     xa = x.astype(acc)
-    alpha = xa[0]
-    sigma = jnp.sum(xa[1:] * xa[1:])
+    alpha = jnp.sum(jnp.where(pos == 0, xa, 0), axis=axis, keepdims=True)
+    tail = jnp.where(pos > 0, xa, 0)
+    sigma = jnp.sum(tail * tail, axis=axis, keepdims=True)
     mu = jnp.sqrt(alpha * alpha + sigma)
     beta = jnp.where(alpha >= 0, -mu, mu)
     safe = sigma > 0
     denom = jnp.where(safe, alpha - beta, 1.0)
     tau = jnp.where(safe, (beta - alpha) / jnp.where(beta == 0, 1.0, beta), 0.0)
-    v = jnp.where(jnp.arange(x.shape[0]) > 0, xa / denom, 1.0)
+    v = jnp.where(pos > 0, xa / denom, 1.0)
     return v, tau, jnp.where(safe, beta, alpha)
 
 
-def _chase_window_vmem(win, first, *, b_in: int, tw: int):
-    """One chase cycle on a VMEM-resident rolled dense window (H, W).
+def _chase_window_vmem(wr, first, *, b_in: int, tw: int):
+    """One chase cycle, in place, on a VMEM ref holding a rolled dense
+    window (H, W).
 
-    Returns ``(win, (v, tau), (v2, tau2))`` — shared by the K=1 kernel and
-    every fused cycle of the super-step kernel, so fusing changes data
-    movement only, never an arithmetic operation.
+    Every read and write is a static-offset ref slice (no value-level
+    scatter), so Mosaic lowers it to masked vector loads/stores.  Returns
+    ``(v, tau, v2, tau2)``: the right reflector as a (1, tw+1) row, the left
+    one transposed to a row too, and both taus as (1, 1) — shared by the
+    K=1 kernel and every fused cycle of the super-step kernel, so fusing
+    changes data movement only, never an arithmetic operation.
     """
     h = b_in + 2 * tw + 1
-    dt = win.dtype
+    w = b_in + tw + 1
+    k = tw + 1
+    dt = wr.dtype
     acc = jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
 
     # ---- right reflector: annihilate the TW-element row bulge ------------
     # overhang row: y = tw (steady) or y = 2*tw (sweep's first cycle); rows in
     # between are structurally zero in cols [0, tw], so the apply is a no-op
     # on them — select statically instead of dynamic-slicing.
-    x = jnp.where(first, win[2 * tw, : tw + 1], win[tw, : tw + 1])
-    v, tau, beta = _reflector_in_kernel(x, acc)
-    blk = win[tw:, : tw + 1].astype(acc)               # rows [tw, H)
-    wdot = blk @ v
-    blk = blk - tau * wdot[:, None] * v[None, :]
-    win = win.at[tw:, : tw + 1].set(blk.astype(dt))
+    x = jnp.where(first, wr[2 * tw:2 * tw + 1, :k], wr[tw:tw + 1, :k])
+    v, tau, beta = _reflector_in_kernel(x, lane, 1, acc)
+    blk = wr[tw:, :k].astype(acc)                      # rows [tw, H)
+    wdot = jnp.sum(blk * v, axis=1, keepdims=True)     # (H - tw, 1)
+    wr[tw:, :k] = (blk - tau * wdot * v).astype(dt)
     # structural zeros on the annihilated row
-    fix = jnp.zeros((tw + 1,), acc).at[0].set(beta).astype(dt)
+    fix = jnp.where(lane == 0, beta, 0.0).astype(dt)
     hit = tau != 0
-    win = win.at[tw, : tw + 1].set(
-        jnp.where(hit & ~first, fix, win[tw, : tw + 1]))
-    win = win.at[2 * tw, : tw + 1].set(
-        jnp.where(hit & first, fix, win[2 * tw, : tw + 1]))
+    wr[tw:tw + 1, :k] = jnp.where(hit & jnp.logical_not(first), fix,
+                                  wr[tw:tw + 1, :k])
+    wr[2 * tw:2 * tw + 1, :k] = jnp.where(hit & first, fix,
+                                          wr[2 * tw:2 * tw + 1, :k])
 
     # ---- left reflector: annihilate the TW-element column bulge ----------
     y0 = h - 1 - tw                                    # matrix row p (pivot)
-    xc = win[y0:, 0]
-    v2, tau2, beta2 = _reflector_in_kernel(xc, acc)
-    blk2 = win[y0:, :].astype(acc)                     # (tw+1, W)
-    w2 = v2 @ blk2
-    blk2 = blk2 - tau2 * v2[:, None] * w2[None, :]
-    colfix = jnp.zeros((tw + 1,), acc).at[0].set(beta2)
-    blk2 = blk2.at[:, 0].set(jnp.where(tau2 != 0, colfix, blk2[:, 0]))
-    win = win.at[y0:, :].set(blk2.astype(dt))
-    return win, (v, tau), (v2, tau2)
+    v2, tau2, beta2 = _reflector_in_kernel(wr[y0:, 0:1], sub, 0, acc)
+    blk2 = wr[y0:, :].astype(acc)                      # (tw+1, W)
+    w2 = jnp.sum(v2 * blk2, axis=0, keepdims=True)     # (1, W)
+    blk2 = blk2 - tau2 * v2 * w2
+    col0 = jax.lax.broadcasted_iota(jnp.int32, (k, w), 1) == 0
+    colfix = jnp.where(sub == 0, beta2, 0.0)
+    blk2 = jnp.where(col0 & (tau2 != 0), colfix, blk2)
+    wr[y0:, :] = blk2.astype(dt)
+    # column -> row without a relayout: mask the diagonal, reduce sublanes
+    v2_row = jnp.sum(jnp.where(sub == lane, v2, 0.0), axis=0, keepdims=True)
+    return v, tau, v2_row, tau2
+
+
+def _record_pair(vs_ref, taus_ref, row: int, v, tau, v2, tau2):
+    """Reflector tape (DESIGN.md §8): write the pair a cycle applied at tape
+    rows ``row`` (right reflector: spans matrix columns [p, p+tw], replayed
+    into V) and ``row + 1`` (left: rows [p, p+tw], into U) — the same
+    VMEM-resident values the applies used."""
+    dt = vs_ref.dtype
+    vs_ref[row:row + 1, :] = v.astype(dt)
+    vs_ref[row + 1:row + 2, :] = v2.astype(dt)
+    taus_ref[row:row + 1, :] = tau.astype(dt)
+    taus_ref[row + 1:row + 2, :] = tau2.astype(dt)
 
 
 def _chase_kernel(first_ref, win_ref, out_ref, *refs, b_in: int, tw: int):
     # refs: optionally (vs_ref, taus_ref) when the reflector tape is recorded.
-    vs_ref, taus_ref = refs if refs else (None, None)
-    dt = win_ref.dtype
-    win = win_ref[0]                                   # (H, W) in VMEM
-    first = first_ref[0, 0] != 0
-    win, (v, tau), (v2, tau2) = _chase_window_vmem(win, first, b_in=b_in,
-                                                   tw=tw)
-    out_ref[0] = win
-    if vs_ref is not None:
-        # Reflector tape (DESIGN.md §8): the pair this cycle applied, written
-        # alongside the in-place band update.  Row 0: right reflector (spans
-        # matrix columns [p, p+tw], replayed into V); row 1: left (rows
-        # [p, p+tw], into U).  Same VMEM-resident values the applies used.
-        vs_ref[0] = jnp.stack([v.astype(dt), v2.astype(dt)])
-        taus_ref[0] = jnp.stack([tau, tau2]).astype(dt)[:, None]
+    out_ref[...] = win_ref[...]                        # (H, W) in VMEM
+    first = first_ref[pl.program_id(0)] != 0           # SMEM scalar
+    v, tau, v2, tau2 = _chase_window_vmem(out_ref, first, b_in=b_in, tw=tw)
+    if refs:
+        _record_pair(*refs, 0, v, tau, v2, tau2)
+
+
+_I0 = np.int32(0)   # int32 block index literal, whatever jax_enable_x64 says
 
 
 @functools.partial(jax.jit, static_argnames=("b_in", "tw", "interpret",
@@ -128,29 +152,31 @@ def chase_cycle_pallas(windows: jax.Array, is_first: jax.Array, *, b_in: int,
                        with_tape: bool = False):
     """windows: (G, H, W) disjoint rolled windows; is_first: (G,) bool.
 
-    ``with_tape=True`` additionally returns the wavefront's reflector tape
-    slice ``(vs (G, 2, tw+1), taus (G, 2))`` — the window update itself is
-    computed by the identical instruction sequence either way."""
+    ``is_first`` is scalar-prefetched into SMEM; each grid step reads its own
+    flag by ``pl.program_id``.  ``with_tape=True`` additionally returns the
+    wavefront's reflector tape slice ``(vs (G, 2, tw+1), taus (G, 2))`` —
+    the window update itself is computed by the identical instruction
+    sequence either way."""
     g, h, w = windows.shape
     assert h == b_in + 2 * tw + 1 and w == b_in + tw + 1, (windows.shape, b_in, tw)
-    first = is_first.astype(jnp.int32).reshape(g, 1)
+    first = is_first.astype(jnp.int32).reshape(g)
     kern = functools.partial(_chase_kernel, b_in=b_in, tw=tw)
+    slot = lambda i, _f: (i, _I0, _I0)
     out_shape = [jax.ShapeDtypeStruct(windows.shape, windows.dtype)]
-    out_specs = [pl.BlockSpec((1, h, w), lambda i: (i, 0, 0))]
+    out_specs = [pl.BlockSpec((None, h, w), slot)]
     if with_tape:
         out_shape += [jax.ShapeDtypeStruct((g, 2, tw + 1), windows.dtype),
                       jax.ShapeDtypeStruct((g, 2, 1), windows.dtype)]
-        out_specs += [pl.BlockSpec((1, 2, tw + 1), lambda i: (i, 0, 0)),
-                      pl.BlockSpec((1, 2, 1), lambda i: (i, 0, 0))]
+        out_specs += [pl.BlockSpec((None, 2, tw + 1), slot),
+                      pl.BlockSpec((None, 2, 1), slot)]
     res = pl.pallas_call(
         kern,
         out_shape=tuple(out_shape),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # is_first scalar
-            pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),  # window in VMEM
-        ],
-        out_specs=tuple(out_specs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(g,),
+            in_specs=[pl.BlockSpec((None, h, w), slot)],  # window in VMEM
+            out_specs=tuple(out_specs)),
         input_output_aliases={1: 0},
         interpret=interpret,
     )(first, windows)
@@ -164,60 +190,59 @@ def chase_cycle_pallas(windows: jax.Array, is_first: jax.Array, *, b_in: int,
 # Fuse-depth-K super-steps (DESIGN.md §9)
 # ---------------------------------------------------------------------------
 
-def _shear_roll(block):
-    """Band block (H, WK) -> VMEM dense workspace (H + WK - 1, WK).
-
-    ``dense[y, w] = rev[y - w, w]`` with ``rev = block[::-1]`` — the column
-    shear that aligns matrix rows with workspace rows.  Implemented as the
-    *flatten shear*: transpose, pad WK zero columns, and reinterpret the
-    flat buffer at row pitch ``H + WK - 1`` — each row lands shifted by its
-    index, zeros fill the off-parallelogram cells.  On TPU this lowers to
-    relayout + reshape (no gather); the workspace height ``H + WK - 1``
-    makes the shear a pure permutation, so roll -> unroll round-trips every
-    block cell bit-exactly.
-    """
-    h, wk = block.shape
-    hc = h + wk - 1
-    bt = block[::-1].T                         # (WK, H): row w = reversed col w
-    btp = jnp.pad(bt, ((0, 0), (0, wk)))       # (WK, H + WK)
-    return btp.reshape(-1)[: wk * hc].reshape(wk, hc).T
+def _roll(x, shift: int, axis: int, **stride):
+    """``pltpu.roll`` with an int32 shift whatever jax_enable_x64 says."""
+    return pltpu.roll(x, np.int32(shift), axis, **stride)
 
 
-def _shear_unroll(dense, h):
-    """Inverse of :func:`_shear_roll`: (H + WK - 1, WK) -> (H, WK)."""
-    hc, wk = dense.shape
-    flat = jnp.pad(dense.T.reshape(-1), (0, wk))
-    x = flat.reshape(wk, hc + 1)[:, :h]        # x[w, r] = dense[r + w, w]
-    return x[:, ::-1].T
-
-
-def _chase_superstep_kernel(first_ref, act_ref, blk_ref, out_ref, *refs,
+def _chase_superstep_kernel(first_ref, act_ref, revt_ref, out_ref, *refs,
                             b_in: int, tw: int, fuse: int):
-    # refs: optionally (vs_ref, taus_ref) when the reflector tape is recorded.
-    vs_ref, taus_ref = refs if refs else (None, None)
+    # refs: optionally (vs_ref, taus_ref) when the reflector tape is
+    # recorded, then the (L, L) dense workspace and one (H, W) window.
+    *tape, ws_ref, win_ref = refs
     h = b_in + 2 * tw + 1
     w = b_in + tw + 1
-    dt = blk_ref.dtype
-    block = blk_ref[0]                                 # (H, WK) in VMEM
-    first = first_ref[0, 0] != 0
-    dense = _shear_roll(block)                         # stays in VMEM
-    vs, taus = [], []
+    wk = fuse * b_in + tw + 1
+    ell = ws_ref.shape[0]
+    dt = ws_ref.dtype
+    g = pl.program_id(0)
+    first = first_ref[g] != 0
+    # Flatten shear on the XLU.  The wrapper hands the kernel the block
+    # reversed and transposed, ``revt[c, r] = block[H-1-r, c]``; at the
+    # corner of a zeroed (L, L) workspace, a sublane-strided lane roll shifts
+    # row c right by c, and one transpose gives the rolled dense form
+    # ``dense[y, c] = revt[c, y - c]`` in which matrix rows align with
+    # workspace rows.  ``L >= H + WK - 1`` keeps the wrapped cells zero.
+    ws_ref[...] = jnp.zeros((ell, ell), dt)
+    ws_ref[0:wk, 0:h] = revt_ref[...]
+    ws_ref[...] = _roll(ws_ref[...], 0, 1, stride=1, stride_axis=0).T
     for i in range(fuse):
-        # cycle i's window sits at static offset (i*b_in, i*b_in): the
-        # tw+1-column overlap with cycle i-1's window is already updated in
-        # the workspace — the residency the host round trip threw away.
-        act = act_ref[0, i] != 0
-        win = dense[i * b_in:i * b_in + h, i * b_in:i * b_in + w]
-        new, (v, tau), (v2, tau2) = _chase_window_vmem(
-            win, jnp.logical_and(first, i == 0), b_in=b_in, tw=tw)
-        new = jnp.where(act, new, win)
-        dense = dense.at[i * b_in:i * b_in + h, i * b_in:i * b_in + w].set(new)
-        vs.append(jnp.stack([v.astype(dt), v2.astype(dt)]))
-        taus.append(jnp.stack([tau, tau2]).astype(dt)[:, None])
-    out_ref[0] = _shear_unroll(dense, h)
-    if vs_ref is not None:
-        vs_ref[0] = jnp.stack(vs)                      # (fuse, 2, tw+1)
-        taus_ref[0] = jnp.stack(taus)                  # (fuse, 2, 1)
+        # cycle i's window sits at the workspace origin (the previous cycle
+        # rolled it there): the tw+1-column overlap with cycle i-1's window
+        # is already updated — the residency the host round trip threw away.
+        act = act_ref[g * fuse + i] != 0
+        saved = ws_ref[0:h, 0:w]
+        win_ref[...] = saved
+        v, tau, v2, tau2 = _chase_window_vmem(
+            win_ref, first if i == 0 else False, b_in=b_in, tw=tw)
+        ws_ref[0:h, 0:w] = jnp.where(act, win_ref[...], saved)
+        if tape:
+            _record_pair(*tape, 2 * i, v, tau, v2, tau2)
+        if i + 1 < fuse:
+            ws = _roll(ws_ref[...], ell - b_in, 0)
+            ws_ref[...] = _roll(ws, ell - b_in, 1)
+    ws = ws_ref[...]
+    if fuse > 1:
+        back = (fuse - 1) * b_in
+        ws = _roll(_roll(ws, back, 0), back, 1)
+    # Mosaic has no lane roll by minus the row index, so the inverse shear
+    # is left to the wrapper: hand back the sheared rows, transposed.
+    ws_ref[...] = ws.T
+    out_ref[...] = ws_ref[0:wk, 0:h + wk - 1]
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 @functools.partial(jax.jit, static_argnames=("b_in", "tw", "fuse",
@@ -231,38 +256,48 @@ def chase_superstep_pallas(blocks: jax.Array, is_first: jax.Array,
     active: (G, fuse) bool prefix mask of live cycles per slot.
 
     One grid step = one K-cycle super-step of one sweep, entirely
-    VMEM-resident.  ``with_tape=True`` additionally returns the super-step's
-    reflector tape slice ``(vs (G, fuse, 2, tw+1), taus (G, fuse, 2))``.
+    VMEM-resident; both masks are scalar-prefetched into SMEM.  The row
+    reversal and transpose the in-kernel shear wants, and the inverse shear
+    of the result, are done here, where XLA fuses them into the caller's
+    block gather and scatter.
+    ``with_tape=True`` additionally returns the super-step's reflector tape
+    slice ``(vs (G, fuse, 2, tw+1), taus (G, fuse, 2))``.
     """
     g, h, wk = blocks.shape
     assert h == b_in + 2 * tw + 1 and wk == fuse * b_in + tw + 1, (
         blocks.shape, b_in, tw, fuse)
-    first = is_first.astype(jnp.int32).reshape(g, 1)
-    act = active.astype(jnp.int32).reshape(g, fuse)
+    first = is_first.astype(jnp.int32).reshape(g)
+    act = active.astype(jnp.int32).reshape(g * fuse)
     kern = functools.partial(_chase_superstep_kernel, b_in=b_in, tw=tw,
                              fuse=fuse)
-    out_shape = [jax.ShapeDtypeStruct(blocks.shape, blocks.dtype)]
-    out_specs = [pl.BlockSpec((1, h, wk), lambda i: (i, 0, 0))]
+    slot = lambda i, _f, _a: (i, _I0, _I0)
+    dt = blocks.dtype
+    hc = h + wk - 1
+    revt = jnp.swapaxes(blocks[:, ::-1], 1, 2)             # (G, WK, H)
+    out_shape = [jax.ShapeDtypeStruct((g, wk, hc), dt)]
+    out_specs = [pl.BlockSpec((None, wk, hc), slot)]
     if with_tape:
-        out_shape += [
-            jax.ShapeDtypeStruct((g, fuse, 2, tw + 1), blocks.dtype),
-            jax.ShapeDtypeStruct((g, fuse, 2, 1), blocks.dtype)]
-        out_specs += [pl.BlockSpec((1, fuse, 2, tw + 1), lambda i: (i, 0, 0, 0)),
-                      pl.BlockSpec((1, fuse, 2, 1), lambda i: (i, 0, 0, 0))]
+        out_shape += [jax.ShapeDtypeStruct((g, 2 * fuse, tw + 1), dt),
+                      jax.ShapeDtypeStruct((g, 2 * fuse, 1), dt)]
+        out_specs += [pl.BlockSpec((None, 2 * fuse, tw + 1), slot),
+                      pl.BlockSpec((None, 2 * fuse, 1), slot)]
+    ell = _round_up(hc, 128)
     res = pl.pallas_call(
         kern,
         out_shape=tuple(out_shape),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),         # is_first scalar
-            pl.BlockSpec((1, fuse), lambda i: (i, 0)),      # active mask
-            pl.BlockSpec((1, h, wk), lambda i: (i, 0, 0)),  # band block in VMEM
-        ],
-        out_specs=tuple(out_specs),
-        input_output_aliases={2: 0},
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(g,),
+            in_specs=[pl.BlockSpec((None, wk, h), slot)],  # band block
+            out_specs=tuple(out_specs),
+            scratch_shapes=[pltpu.VMEM((ell, ell), dt),
+                            pltpu.VMEM((h, b_in + tw + 1), dt)]),
         interpret=interpret,
-    )(first, act, blocks)
+    )(first, act, revt)
+    # inverse shear: block[H-1-r, c] = sheared[c, r + c]
+    cc = jnp.arange(wk)[None, :]
+    out = res[0][:, cc, jnp.arange(h)[::-1, None] + cc]      # (G, H, WK)
     if with_tape:
-        out, vs, taus = res
-        return out, vs, taus[..., 0]
-    return res[0]
+        return (out, res[1].reshape(g, fuse, 2, tw + 1),
+                res[2].reshape(g, fuse, 2))
+    return out

@@ -28,8 +28,8 @@ vectors with one batched ``bidiag_svd`` call (two dispatches total — the
 values path, the B-heavy serve workload, is the one-dispatch tier).
 
 Reflectors use a *masked* variant of ``core.householder.make_reflector``:
-full-length (n,) vectors with support ``[lo, hi]`` selected by iota masks,
-so every loop iteration has static shapes (fori-able, Mosaic-friendly) and
+full-length (1, n) rows or (n, 1) columns with support ``[lo, hi]``
+selected by iota masks, so every loop iteration has static shapes and
 inactive cycles (pivot past the edge) degenerate to exact no-ops through
 the same ``tau = 0`` path that handles zero tails.
 
@@ -45,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 __all__ = ["fused_small_svd_pallas"]
@@ -52,27 +53,44 @@ __all__ = ["fused_small_svd_pallas"]
 
 # ---------------------------------------------------------------------------
 # masked reflector + structural fixes (static shapes, iota masks)
+#
+# Every value is at least 2-D — vectors are (1, n) rows or (n, 1) columns,
+# scalars (1, 1) — and every matrix-vector product is a broadcast multiply
+# plus a lane or sublane reduction, so Mosaic lowers the whole body to VPU
+# ops with no 1-D layouts, matvecs or relayouts.
 # ---------------------------------------------------------------------------
+
+def _fori(lo: int, hi: int, body, carry):
+    """``lax.fori_loop`` with an int32 counter whatever jax_enable_x64 says
+    (static bounds would otherwise become a scan over an i64 counter,
+    which Mosaic cannot lower)."""
+    def step(c):
+        return c[0] + 1, body(c[0], c[1])
+    return jax.lax.while_loop(lambda c: c[0] < hi, step,
+                              (np.int32(lo), carry))[1]
+
 
 def _masked_reflector(x, lo, hi, idx):
     """(v, tau, beta) for the reflector over ``x[lo:hi+1]`` (pivot ``lo``),
-    returned as a full-length masked vector: ``v[lo] = 1``, support-only
-    tail, zeros elsewhere.  Empty / out-of-range / zero-tail supports give
-    ``tau = 0`` — same formulas and guards as ``householder.make_reflector``.
+    returned as a full-length masked vector of ``x``'s orientation:
+    ``v[lo] = 1``, support-only tail, zeros elsewhere; ``tau``/``beta`` are
+    (1, 1).  Empty / out-of-range / zero-tail supports give ``tau = 0`` —
+    same formulas and guards as ``householder.make_reflector``.
     """
     dt = x.dtype
     acc = jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
+    axis = 1 if x.shape[0] == 1 else 0
     xa = x.astype(acc)
     tail = (idx > lo) & (idx <= hi)
-    alpha = jnp.sum(jnp.where(idx == lo, xa, 0))
+    alpha = jnp.sum(jnp.where(idx == lo, xa, 0), axis=axis, keepdims=True)
     x2 = jnp.where(tail, xa, 0)
-    sigma = jnp.sum(x2 * x2)
+    sigma = jnp.sum(x2 * x2, axis=axis, keepdims=True)
     mu = jnp.sqrt(alpha * alpha + sigma)
     beta = jnp.where(alpha >= 0, -mu, mu)
     safe = sigma > 0
     denom = jnp.where(safe, alpha - beta, 1.0)
     tau = jnp.where(safe, (beta - alpha) / beta, 0.0)
-    v = jnp.where(safe, x2 / denom, 0.0) + jnp.where(idx == lo, 1.0, 0.0)
+    v = jnp.where(safe, x2 / denom, 0.0) + (idx == lo).astype(acc)
     beta_out = jnp.where(safe, beta, alpha)
     return v.astype(dt), tau.astype(dt), beta_out.astype(dt)
 
@@ -104,38 +122,44 @@ def _fix_col(a, rows2, cols2, c, lo, hi, beta, tau):
 def _reduce_single(a, *, bw, compute_uv):
     """Phases 1+2 on one (n, n) matrix: returns ``(a, u, v, d, e)`` with
     ``a`` bidiagonal, ``u^T a_in v`` bidiagonal when ``compute_uv`` (else
-    ``u``/``v`` are (1, 1) dummies), and (d, e) in the e[0]-unused
-    convention of ``bidiag_singular_values``."""
+    ``u``/``v`` are (1, 1) dummies), and (d, e) as (1, n) rows in the
+    e[0]-unused convention of ``bidiag_singular_values``."""
     n = a.shape[0]
     dt = a.dtype
     rows2 = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     cols2 = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    idx = cols2[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    diag = rows2 == cols2
     zero = jnp.zeros_like(a)
     if compute_uv:
-        u = (rows2 == cols2).astype(dt)
-        v = (rows2 == cols2).astype(dt)
+        u = diag.astype(dt)
+        v = diag.astype(dt)
     else:
         u = v = jnp.zeros((1, 1), dt)
 
     def right(carry, r, lo, hi):
         a, u, v = carry
-        row = jnp.sum(jnp.where(rows2 == r, a, zero), axis=0)
-        vec, tau, beta = _masked_reflector(row, lo, hi, idx)
-        a = a - tau * jnp.outer(a @ vec, vec)
+        row = jnp.sum(jnp.where(rows2 == r, a, zero), axis=0, keepdims=True)
+        vec, tau, beta = _masked_reflector(row, lo, hi, lane)     # (1, n)
+        av = jnp.sum(a * vec, axis=1, keepdims=True)              # a @ vec
+        a = a - tau * (av * vec)
         a = _fix_row(a, rows2, cols2, r, lo, hi, beta, tau)
         if compute_uv:
-            v = v - tau * jnp.outer(v @ vec, vec)
+            v = v - tau * (jnp.sum(v * vec, axis=1, keepdims=True) * vec)
         return a, u, v
 
     def left(carry, lo, hi):
         a, u, v = carry
-        col = jnp.sum(jnp.where(cols2 == lo, a, zero), axis=1)
-        vec, tau, beta = _masked_reflector(col, lo, hi, idx)
-        a = a - tau * jnp.outer(vec, vec @ a)
+        col = jnp.sum(jnp.where(cols2 == lo, a, zero), axis=1, keepdims=True)
+        vec, tau, beta = _masked_reflector(col, lo, hi, sub)      # (n, 1)
+        va = jnp.sum(vec * a, axis=0, keepdims=True)              # vec @ a
+        a = a - tau * (vec * va)
         a = _fix_col(a, rows2, cols2, lo, lo, hi, beta, tau)
         if compute_uv:
-            u = u - tau * jnp.outer(u @ vec, vec)
+            # column -> row without a relayout: mask the diagonal, reduce
+            vrow = jnp.sum(jnp.where(diag, vec, zero), axis=0, keepdims=True)
+            u = u - tau * (jnp.sum(u * vrow, axis=1, keepdims=True) * vrow)
         return a, u, v
 
     # phase 1: dense -> upper-banded(bw).  Banded inputs: all tau = 0.
@@ -143,7 +167,7 @@ def _reduce_single(a, *, bw, compute_uv):
         carry = left(carry, j, n - 1)          # zero a[j+1:, j]
         return right(carry, j, j + bw, n - 1)  # zero a[j, j+bw+1:]
 
-    carry = jax.lax.fori_loop(0, max(n - 1, 0), p1, (a, u, v))
+    carry = _fori(0, max(n - 1, 0), p1, (a, u, v))
 
     # phase 2: one SBR stage b_in = bw, tw = bw - 1 (b_out = 1) — the
     # sweep/pivot walk of reference.reduce_stage_dense_ref, every cycle
@@ -159,24 +183,24 @@ def _reduce_single(a, *, bw, compute_uv):
             return left(carry, p, hi)          # re-zero the bulge column
 
         def sweep(R, carry):
-            return jax.lax.fori_loop(
-                0, ncyc, lambda jc, c: cyc(R, jc, c), carry)
+            return _fori(0, ncyc, lambda jc, c: cyc(R, jc, c), carry)
 
-        carry = jax.lax.fori_loop(0, n - 2, sweep, carry)
+        carry = _fori(0, n - 2, sweep, carry)
 
     a, u, v = carry
-    d = jnp.sum(jnp.where(rows2 == cols2, a, zero), axis=1)
-    e = jnp.sum(jnp.where(cols2 == rows2 + 1, a, zero), axis=0)
+    d = jnp.sum(jnp.where(diag, a, zero), axis=0, keepdims=True)
+    e = jnp.sum(jnp.where(cols2 == rows2 + 1, a, zero), axis=0, keepdims=True)
     return a, u, v, d, e
 
 
 def _sigma_from_bidiag(d, e, *, max_iter=None):
-    """In-kernel phase 3: ``bidiag_singular_values`` arithmetic, vectorized
-    over all n shift searches at once instead of vmapped (identical
-    per-element float ops: same z, same power-of-two prescale, same bound,
-    same Sturm recurrence and guards, same iteration count).
+    """In-kernel phase 3 on (1, n) rows (d, e): the arithmetic of
+    ``bidiag_singular_values``, vectorized over all n shift searches at once
+    instead of vmapped (identical per-element float ops: same z, same
+    power-of-two prescale, same bound, same Sturm recurrence and guards,
+    same iteration count).  Returns sigma descending as an (n, 1) column.
     ``max_iter=None`` picks the dtype default, mirroring the core path."""
-    n = d.shape[0]
+    n = d.shape[-1]
     dt = d.dtype
     if n == 1:
         return jnp.abs(d)
@@ -184,40 +208,50 @@ def _sigma_from_bidiag(d, e, *, max_iter=None):
     m = 2 * n - 1
     im = jax.lax.broadcasted_iota(jnp.int32, (m, n), 0)
     jn = jax.lax.broadcasted_iota(jnp.int32, (m, n), 1)
-    # z = (d_1, e_1, d_2, ..., e_{n-1}, d_n): gk_offdiag via one-hot masks.
+    # z = (d_1, e_1, d_2, ..., e_{n-1}, d_n) as an (m, 1) column, and its
+    # one-step shift z[i+1], both via one-hot masks (exact: one nonzero term).
     da = d.astype(acc)
     ea = e.astype(acc)
-    z = (jnp.sum(jnp.where(im == 2 * jn, da[None, :], 0), axis=1)
-         + jnp.sum(jnp.where(im == 2 * jn - 1, ea[None, :], 0), axis=1))
+
+    def interleave(shift):
+        return (jnp.sum(jnp.where(im + shift == 2 * jn, da, 0), axis=1,
+                        keepdims=True)
+                + jnp.sum(jnp.where(im + shift == 2 * jn - 1, ea, 0), axis=1,
+                          keepdims=True))
+
+    z = interleave(0)
     # Power-of-two prescale, mirroring core ``_gk_prescale``: keeps the
     # squared Sturm pivots in range for extreme input magnitudes while
     # changing no mantissa bits.
-    zmax = jnp.max(jnp.abs(z))
+    zmax = jnp.max(jnp.abs(z), axis=0, keepdims=True)
     sc = jnp.exp2(jnp.round(
         jnp.log2(jnp.where(zmax > 0, zmax, 1)))).astype(acc)
     z = z / sc
     az = jnp.abs(z)
+    az_next = jnp.abs(interleave(1) / sc)
+    idxm = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
     # Gershgorin bound == max(pad[:-1] + pad[1:]) + 1 with zero end-padding.
-    bound = jnp.maximum(jnp.max(az[:-1] + az[1:]),
-                        jnp.maximum(az[0], az[-1])) + jnp.asarray(1, acc)
+    pair = jnp.where(idxm < m - 1, az + az_next, 0)
+    ends = jnp.where((idxm == 0) | (idxm == m - 1), az, 0)
+    bound = jnp.max(jnp.maximum(pair, ends), axis=0,
+                    keepdims=True) + jnp.asarray(1, acc)
     if max_iter is None:
         max_iter = 60 if acc == jnp.float64 else 40
     tiny = jnp.asarray(jnp.finfo(acc).tiny * 4, acc)
-    idxm = im[:, 0]
-    ks = jn[0] + 1                                 # 1-indexed ascending
+    ks = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) + 1   # 1-indexed
 
-    def sturm_vec(lam):                            # lam: (n,) shifts
+    def sturm_vec(lam):                            # lam: (1, n) shifts
         def body(k, carry):
             t, cnt = carry
             t = jnp.where(jnp.abs(t) < tiny,
                           jnp.where(t < 0, -tiny, tiny), t)
-            zk = jnp.sum(jnp.where(idxm == k - 1, z, 0))
+            zk = jnp.sum(jnp.where(idxm == k - 1, z, 0), axis=0,
+                         keepdims=True)
             t_next = -lam - (zk * zk) / t
             return t_next, cnt + (t_next < 0)
 
         t0 = -lam
-        _, cnt = jax.lax.fori_loop(1, m + 1, body,
-                                   (t0, (t0 < 0).astype(jnp.int32)))
+        _, cnt = _fori(1, m + 1, body, (t0, (t0 < 0).astype(jnp.int32)))
         return cnt
 
     def bis(_, lohi):
@@ -226,12 +260,13 @@ def _sigma_from_bidiag(d, e, *, max_iter=None):
         ok = (sturm_vec(mid) - n) >= ks
         return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
 
-    lo, hi = jax.lax.fori_loop(0, max_iter, bis,
-                               (jnp.zeros((n,), acc),
-                                jnp.zeros((n,), acc) + bound))
+    lo, hi = _fori(0, max_iter, bis, (jnp.zeros((1, n), acc),
+                                      jnp.zeros((1, n), acc) + bound))
     sig = 0.5 * (lo + hi)
-    rev = (jn[0][:, None] + jn[0][None, :]) == (n - 1)
-    return (jnp.sum(jnp.where(rev, sig[None, :], 0), axis=1) * sc).astype(dt)
+    rev = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           + jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)) == (n - 1)
+    return (jnp.sum(jnp.where(rev, sig, 0), axis=1, keepdims=True)
+            * sc).astype(dt)
 
 
 # ---------------------------------------------------------------------------
@@ -239,18 +274,16 @@ def _sigma_from_bidiag(d, e, *, max_iter=None):
 # ---------------------------------------------------------------------------
 
 def _values_kernel(a_ref, sig_ref, *, bw, max_iter):
-    a = a_ref[0]
-    _, _, _, d, e = _reduce_single(a, bw=bw, compute_uv=False)
-    sig_ref[0] = _sigma_from_bidiag(d, e, max_iter=max_iter)
+    _, _, _, d, e = _reduce_single(a_ref[...], bw=bw, compute_uv=False)
+    sig_ref[...] = _sigma_from_bidiag(d, e, max_iter=max_iter)
 
 
 def _uv_kernel(a_ref, d_ref, e_ref, u_ref, vt_ref, *, bw):
-    a = a_ref[0]
-    _, u, v, d, e = _reduce_single(a, bw=bw, compute_uv=True)
-    d_ref[0] = d
-    e_ref[0] = e
-    u_ref[0] = u
-    vt_ref[0] = v.T
+    _, u, v, d, e = _reduce_single(a_ref[...], bw=bw, compute_uv=True)
+    d_ref[...] = d
+    e_ref[...] = e
+    u_ref[...] = u
+    vt_ref[...] = v.T
 
 
 def effective_bw(n: int, bw: int) -> int:
@@ -258,6 +291,9 @@ def effective_bw(n: int, bw: int) -> int:
     (bw = 0 requests mean "pick for me" and become 1; bw beyond n - 1 is
     structurally meaningless for an n x n matrix)."""
     return int(max(1, min(int(bw), max(int(n) - 1, 1))))
+
+
+_I0 = np.int32(0)   # int32 block index literal, whatever jax_enable_x64 says
 
 
 @functools.partial(jax.jit,
@@ -271,6 +307,8 @@ def fused_small_svd_pallas(mats, *, bw, compute_uv=False, interpret=False,
     ``compute_uv=True`` returns ``(d, e, u2, vt2)``; compose vectors with
     one batched ``bidiag_svd`` (see ``core.svd``).  ``max_iter=None`` picks
     the dtype-default bisection sweeps; an explicit value must be >= 1.
+    Per-matrix vector outputs are (1, n) / (n, 1) blocks of (B, 1, n) /
+    (B, n, 1) arrays — whole trailing dims, as the TPU tiling requires.
     """
     if max_iter is not None and max_iter < 1:
         raise ValueError(
@@ -279,27 +317,30 @@ def fused_small_svd_pallas(mats, *, bw, compute_uv=False, interpret=False,
     assert mats.ndim == 3 and mats.shape[-1] == mats.shape[-2], mats.shape
     b, n, _ = mats.shape
     bw_eff = effective_bw(n, bw)
-    in_specs = [pl.BlockSpec((1, n, n), lambda i: (i, 0, 0))]
+    one = lambda i: (i, _I0, _I0)
+    mat_spec = pl.BlockSpec((None, n, n), one)
+    row = jax.ShapeDtypeStruct((b, 1, n), mats.dtype)
     if compute_uv:
         kern = functools.partial(_uv_kernel, bw=bw_eff)
-        out_shape = (jax.ShapeDtypeStruct((b, n), mats.dtype),
-                     jax.ShapeDtypeStruct((b, n), mats.dtype),
-                     jax.ShapeDtypeStruct((b, n, n), mats.dtype),
-                     jax.ShapeDtypeStruct((b, n, n), mats.dtype))
-        out_specs = (pl.BlockSpec((1, n), lambda i: (i, 0)),
-                     pl.BlockSpec((1, n), lambda i: (i, 0)),
-                     pl.BlockSpec((1, n, n), lambda i: (i, 0, 0)),
-                     pl.BlockSpec((1, n, n), lambda i: (i, 0, 0)))
-    else:
-        kern = functools.partial(_values_kernel, bw=bw_eff,
-                                 max_iter=max_iter)
-        out_shape = jax.ShapeDtypeStruct((b, n), mats.dtype)
-        out_specs = pl.BlockSpec((1, n), lambda i: (i, 0))
-    return pl.pallas_call(
+        sq = jax.ShapeDtypeStruct((b, n, n), mats.dtype)
+        row_spec = pl.BlockSpec((None, 1, n), one)
+        d, e, u2, vt2 = pl.pallas_call(
+            kern,
+            out_shape=(row, row, sq, sq),
+            grid=(b,),
+            in_specs=[mat_spec],
+            out_specs=(row_spec, row_spec, mat_spec, mat_spec),
+            interpret=interpret,
+        )(mats)
+        return d[:, 0], e[:, 0], u2, vt2
+    kern = functools.partial(_values_kernel, bw=bw_eff, max_iter=max_iter)
+    shape = (b, 1, n) if n == 1 else (b, n, 1)
+    sig = pl.pallas_call(
         kern,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(shape, mats.dtype),
         grid=(b,),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[mat_spec],
+        out_specs=pl.BlockSpec((None,) + shape[1:], one),
         interpret=interpret,
     )(mats)
+    return sig.reshape(b, n)

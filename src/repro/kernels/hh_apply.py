@@ -24,6 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 __all__ = ["hh_block_apply_pallas", "tape_apply_pallas"]
@@ -44,12 +45,28 @@ def hh_block_apply_pallas(v: jax.Array, t: jax.Array, c: jax.Array, *,
 
 def _tape_kernel(v_ref, t_ref, c_ref, o_ref):
     acc = jnp.float32 if c_ref.dtype in (jnp.bfloat16, jnp.float16) else c_ref.dtype
-    v = v_ref[0].astype(acc)                               # (m, k)
-    t = t_ref[0].astype(acc)                               # (k, k)
-    c = c_ref[0].astype(acc)                               # (m, bc)
-    w1 = jnp.dot(v.T, c, preferred_element_type=acc)       # (k, bc)
-    w2 = jnp.dot(t, w1, preferred_element_type=acc)        # (k, bc)
-    o_ref[0] = (c - jnp.dot(v, w2, preferred_element_type=acc)).astype(o_ref.dtype)
+    # f32-exact MXU passes: the single bf16 pass TPUs default to would cost
+    # the replayed factors ~3 decimal digits.
+    hi = jax.lax.Precision.HIGHEST
+    v = v_ref[...].astype(acc)                             # (m, k)
+    t = t_ref[...].astype(acc)                             # (k, k)
+    c = c_ref[...].astype(acc)                             # (m, bc)
+    w1 = jax.lax.dot_general(v, c, (((0,), (0,)), ((), ())), precision=hi,
+                             preferred_element_type=acc)   # V^T C: (k, bc)
+    w2 = jnp.dot(t, w1, precision=hi, preferred_element_type=acc)
+    o_ref[...] = (c - jnp.dot(v, w2, precision=hi,
+                              preferred_element_type=acc)).astype(o_ref.dtype)
+
+
+_I0 = np.int32(0)   # int32 block index literal, whatever jax_enable_x64 says
+_C_BLOCK_BYTES = 2 ** 20   # one streamed C stripe; x4 with in/out double-buffering
+
+
+def _stripe_cols(m: int, w: int, block_cols: int, itemsize: int) -> int:
+    """Stripe width: ``block_cols`` capped so an (m, stripe) block stays
+    within ``_C_BLOCK_BYTES``, in whole 128-lane tiles (or all of ``w``)."""
+    cap = max(128, _C_BLOCK_BYTES // (m * itemsize) // 128 * 128)
+    return min(block_cols, w, cap)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_cols"))
@@ -59,11 +76,12 @@ def tape_apply_pallas(v: jax.Array, t: jax.Array, c: jax.Array, *,
     """Per-slot C[s] <- (I - V[s] T[s] V[s]^T) C[s].
 
     v: (S, m, k), t: (S, k, k), c: (S, m, w).  V/T are VMEM-resident per
-    slot; C streams in ``block_cols`` stripes, grid ``(S, stripes)``.
+    slot; C streams in column stripes of at most ``block_cols`` (fewer for
+    tall m, see ``_stripe_cols``), grid ``(S, stripes)``.
     """
     s, m, k = v.shape
     w = c.shape[-1]
-    bc = min(block_cols, w)
+    bc = _stripe_cols(m, w, block_cols, c.dtype.itemsize)
     pad = (-w) % bc
     cp = jnp.pad(c, ((0, 0), (0, 0), (0, pad))) if pad else c
     grid = (s, cp.shape[-1] // bc)
@@ -72,11 +90,11 @@ def tape_apply_pallas(v: jax.Array, t: jax.Array, c: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct(cp.shape, c.dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, m, k), lambda i, j: (i, 0, 0)),   # V per slot
-            pl.BlockSpec((1, k, k), lambda i, j: (i, 0, 0)),   # T per slot
-            pl.BlockSpec((1, m, bc), lambda i, j: (i, 0, j)),  # C streamed
+            pl.BlockSpec((None, m, k), lambda i, j: (i, _I0, _I0)),  # V per slot
+            pl.BlockSpec((None, k, k), lambda i, j: (i, _I0, _I0)),  # T per slot
+            pl.BlockSpec((None, m, bc), lambda i, j: (i, _I0, j)),   # C streamed
         ],
-        out_specs=pl.BlockSpec((1, m, bc), lambda i, j: (i, 0, j)),
+        out_specs=pl.BlockSpec((None, m, bc), lambda i, j: (i, _I0, j)),
         interpret=interpret,
     )(v, t, cp)
     return out[..., :w] if pad else out

@@ -9,8 +9,8 @@ touching call sites.  Built-ins:
   "pallas"    — Pallas TPU kernel; on CPU runs in interpret mode (correctness).
 
 ``resolve_backend`` turns the user-facing "auto" into a concrete registry key
-(pallas on TPU, ref elsewhere) and is the single place platform sniffing
-happens — ``tuning.PipelineConfig.resolve`` calls it so resolved configs never
+(pallas on TPU, ref elsewhere; ref for 64-bit data on TPU, where Pallas has
+no float64) and is the single place platform sniffing happens — ``tuning.PipelineConfig.resolve`` calls it so resolved configs never
 carry "auto".  Every wrapper also accepts ``config=`` (a resolved
 ``PipelineConfig``) as the preferred way to select a backend.
 """
@@ -21,11 +21,12 @@ import functools
 from typing import Callable
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
-__all__ = ["chase_cycle", "hh_block_apply", "tape_apply", "flash_attention",
-           "fused_svd", "register_backend", "resolve_backend",
+__all__ = ["chase_cycle", "hh_block_apply", "tape_apply", "fused_svd",
+           "register_backend", "resolve_backend",
            "backend_names"]
 
 
@@ -56,16 +57,31 @@ def backend_names() -> tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
-def resolve_backend(backend: str = "auto", interpret: bool | None = None
-                    ) -> tuple[str, bool]:
-    """("auto", None) -> a concrete (registry key, interpret flag)."""
+# Backends whose ops compile to Pallas TPU kernels off interpret mode.
+_PALLAS_BACKENDS = ("pallas", "fused_small")
+
+
+def resolve_backend(backend: str = "auto", interpret: bool | None = None,
+                    dtype=None) -> tuple[str, bool]:
+    """("auto", None) -> a concrete (registry key, interpret flag).
+
+    ``dtype`` is the working dtype when known.  Pallas on TPU has no
+    float64, so "auto" picks "ref" (plain XLA) for 64-bit data on a TPU,
+    and an explicit Pallas backend with 64-bit data there raises.
+    """
+    on_tpu = _platform() == "tpu"
+    wide = dtype is not None and jnp.dtype(dtype).itemsize == 8
     if backend == "auto":
-        backend = "pallas" if _platform() == "tpu" else "ref"
+        backend = "pallas" if on_tpu and not wide else "ref"
     if backend not in _REGISTRY:
         raise ValueError(
             f"unknown backend {backend!r}; registered: {backend_names()}")
     if interpret is None:
-        interpret = _platform() != "tpu"
+        interpret = not on_tpu
+    if wide and on_tpu and not interpret and backend in _PALLAS_BACKENDS:
+        raise ValueError(
+            f"backend {backend!r} has no {jnp.dtype(dtype).name} kernels on "
+            f"TPU; send float32 data or use backend='ref' or 'auto'")
     return backend, bool(interpret)
 
 
@@ -78,7 +94,8 @@ def _impl(op: str, backend: str) -> Callable:
     return table[op]
 
 
-def _resolve(backend: str, interpret: bool | None, config) -> tuple[str, bool]:
+def _resolve(backend: str, interpret: bool | None, config,
+             dtype=None) -> tuple[str, bool]:
     """Explicit kwargs win; the config fills whatever is still at its
     "auto"/None default (so a resolved config's interpret flag survives even
     when the caller passes the concrete backend name alongside it)."""
@@ -87,7 +104,7 @@ def _resolve(backend: str, interpret: bool | None, config) -> tuple[str, bool]:
             backend = config.backend
         if interpret is None:
             interpret = config.interpret
-    return resolve_backend(backend, interpret)
+    return resolve_backend(backend, interpret, dtype)
 
 
 # ---- built-in "ref" (pure jnp; interpret flag ignored) ---------------------
@@ -108,8 +125,6 @@ register_backend(
         _ref.hh_block_apply_ref(v, t, c),
     tape_apply=lambda v, t, c, *, block_cols, interpret:
         _ref.tape_apply_ref(v, t, c),
-    flash_attention=lambda q, k, v, *, block_q, block_k, interpret:
-        _ref.flash_attention_ref(q, k, v),
     fused_svd=lambda mats, *, bw, compute_uv, interpret:
         _ref.fused_small_svd_ref(mats, bw=bw, compute_uv=compute_uv),
 )
@@ -142,12 +157,6 @@ def _pallas_tape(v, t, c, *, block_cols, interpret):
                                       block_cols=block_cols)
 
 
-def _pallas_flash(q, k, v, *, block_q, block_k, interpret):
-    from repro.kernels import flash_attention as fa
-    return fa.flash_attention_pallas(q, k, v, block_q=block_q, block_k=block_k,
-                                     interpret=interpret)
-
-
 def _pallas_fused(mats, *, bw, compute_uv, interpret):
     from repro.kernels import fused_small
     return fused_small.fused_small_svd_pallas(mats, bw=bw,
@@ -156,8 +165,7 @@ def _pallas_fused(mats, *, bw, compute_uv, interpret):
 
 
 register_backend("pallas", chase_cycle=_pallas_chase, hh_block_apply=_pallas_hh,
-                 tape_apply=_pallas_tape, flash_attention=_pallas_flash,
-                 fused_svd=_pallas_fused)
+                 tape_apply=_pallas_tape, fused_svd=_pallas_fused)
 
 
 # ---- "fused_small" (DESIGN.md §13): the one-dispatch small-n SVD tier ------
@@ -181,7 +189,7 @@ def _fused_small_delegate(op: str) -> Callable:
 register_backend("fused_small",
                  **{op: _fused_small_delegate(op)
                     for op in ("chase_cycle", "hh_block_apply", "tape_apply",
-                               "flash_attention", "fused_svd")})
+                               "fused_svd")})
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +223,7 @@ def chase_cycle(windows: jax.Array, is_first: jax.Array, *, b_in: int, tw: int,
     ``(G, 2, tw+1)``/``(G, 2)`` at fuse=1 and ``(G, K, 2, tw+1)``/
     ``(G, K, 2)`` fused.
     """
-    backend, interpret = _resolve(backend, interpret, config)
+    backend, interpret = _resolve(backend, interpret, config, windows.dtype)
     return _impl("chase_cycle", backend)(windows, is_first, b_in=b_in, tw=tw,
                                          with_tape=with_tape, fuse=fuse,
                                          active=active, interpret=interpret)
@@ -233,7 +241,7 @@ def tape_apply(v: jax.Array, t: jax.Array, c: jax.Array, *,
     v: (S, m, k), t: (S, k, k), c: (S, m, w).  Chase-tape replay passes the
     rank-1 form (k = 1, t = tau); stage-1 panel replay passes k = nb blocks.
     """
-    backend, interpret = _resolve(backend, interpret, config)
+    backend, interpret = _resolve(backend, interpret, config, c.dtype)
     return _impl("tape_apply", backend)(v, t, c, block_cols=block_cols,
                                         interpret=interpret)
 
@@ -244,7 +252,7 @@ def hh_block_apply(v: jax.Array, t: jax.Array, c: jax.Array, *,
                    backend: str = "auto", interpret: bool | None = None,
                    block_cols: int = 512, config=None) -> jax.Array:
     """C <- (I - V T V^T) C — stage-1 WY blocked reflector apply."""
-    backend, interpret = _resolve(backend, interpret, config)
+    backend, interpret = _resolve(backend, interpret, config, c.dtype)
     return _impl("hh_block_apply", backend)(v, t, c, block_cols=block_cols,
                                             interpret=interpret)
 
@@ -263,19 +271,6 @@ def fused_svd(mats: jax.Array, *, bw: int, compute_uv: bool = False,
     platform default; ``"fused_small"`` platform-routes (Pallas kernel on
     TPU, jitted jnp twin elsewhere).
     """
-    backend, interpret = _resolve(backend, interpret, config)
+    backend, interpret = _resolve(backend, interpret, config, mats.dtype)
     return _impl("fused_svd", backend)(mats, bw=bw, compute_uv=compute_uv,
                                        interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("backend", "interpret",
-                                             "block_q", "block_k", "config"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    backend: str = "auto", interpret: bool | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    config=None) -> jax.Array:
-    """Causal attention (BH, S, D): O(s*d) HBM traffic on TPU (Pallas)."""
-    backend, interpret = _resolve(backend, interpret, config)
-    return _impl("flash_attention", backend)(q, k, v, block_q=block_q,
-                                             block_k=block_k,
-                                             interpret=interpret)

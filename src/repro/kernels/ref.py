@@ -21,11 +21,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.householder import exact_matmul as _mm
 from repro.core.householder import make_reflector
 
+_HI = jax.lax.Precision.HIGHEST       # as exact_matmul, for the einsums
+
 __all__ = ["chase_window_ref", "chase_cycle_ref", "chase_superstep_ref",
-           "hh_block_apply_ref", "tape_apply_ref", "flash_attention_ref",
-           "fused_small_svd_ref"]
+           "hh_block_apply_ref", "tape_apply_ref", "fused_small_svd_ref"]
 
 
 def _chase_window(window: jax.Array, is_first: jax.Array, *, b_in: int,
@@ -50,7 +52,7 @@ def _chase_window(window: jax.Array, is_first: jax.Array, *, b_in: int,
     x = jax.lax.dynamic_slice(window, (y_r, 0), (1, tw + 1))[0]
     v, tau, beta = make_reflector(x)
     blk = window[tw:, : tw + 1]                                   # rows [tw, H)
-    w_dot = blk @ v                                               # (H - tw,)
+    w_dot = _mm(blk, v)                                           # (H - tw,)
     blk = blk - tau * jnp.outer(w_dot, v)
     window = window.at[tw:, : tw + 1].set(blk.astype(dt))
     # structural zeros for the annihilated row (avoid round-off debris)
@@ -64,7 +66,7 @@ def _chase_window(window: jax.Array, is_first: jax.Array, *, b_in: int,
     xc = window[y0:, 0]
     v2, tau2, beta2 = make_reflector(xc)
     blk2 = window[y0:, :]                                         # (tw+1, W)
-    w2 = v2 @ blk2
+    w2 = _mm(v2, blk2)
     blk2 = blk2 - tau2 * jnp.outer(v2, w2)
     col_fix = jnp.zeros((tw + 1,), dt).at[0].set(beta2)
     col_fix = jnp.where(tau2 != 0, col_fix, blk2[:, 0].astype(dt))
@@ -201,23 +203,10 @@ def tape_apply_ref(v: jax.Array, t: jax.Array, c: jax.Array) -> jax.Array:
     """
     acc = jnp.float32 if c.dtype in (jnp.bfloat16, jnp.float16) else c.dtype
     vv, tt, cc = v.astype(acc), t.astype(acc), c.astype(acc)
-    w1 = jnp.einsum("smk,smw->skw", vv, cc)
-    out = cc - jnp.einsum("smk,skw->smw", vv, jnp.einsum("skj,sjw->skw", tt, w1))
+    w1 = jnp.einsum("smk,smw->skw", vv, cc, precision=_HI)
+    tw1 = jnp.einsum("skj,sjw->skw", tt, w1, precision=_HI)
+    out = cc - jnp.einsum("smk,skw->smw", vv, tw1, precision=_HI)
     return out.astype(c.dtype)
-
-
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Oracle for kernels/flash_attention.py: plain causal softmax attention.
-
-    q, k, v: (BH, S, D)."""
-    s_len = q.shape[1]
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    scores = jnp.einsum("bsd,btd->bst", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    mask = jnp.tril(jnp.ones((s_len, s_len), bool))
-    scores = jnp.where(mask[None], scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bst,btd->bsd", w, v.astype(jnp.float32)).astype(q.dtype)
 
 
 def fused_small_svd_ref(mats, *, bw: int, compute_uv: bool = False,
@@ -244,6 +233,7 @@ def fused_small_svd_ref(mats, *, bw: int, compute_uv: bool = False,
     red = jax.vmap(functools.partial(_fs._reduce_single, bw=bw_eff,
                                      compute_uv=compute_uv))
     _, u, v, d, e = red(mats)
+    d, e = d[:, 0], e[:, 0]                      # (B, 1, n) rows -> (B, n)
     if compute_uv:
         return d, e, u, jnp.swapaxes(v, -1, -2)
     return _s3.bidiag_singular_values(d, e, max_iter=max_iter)

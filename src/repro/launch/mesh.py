@@ -9,7 +9,6 @@ devices via its own XLA_FLAGS).
 from __future__ import annotations
 
 import os
-import warnings
 
 import jax
 
@@ -24,17 +23,16 @@ _DIST_INITIALIZED = False
 def init_distributed(*, coordinator: str | None = None,
                      num_processes: int | None = None,
                      process_id: int | None = None) -> bool:
-    """Best-effort multi-process JAX bootstrap (DESIGN.md §17).
+    """Multi-process JAX bootstrap (DESIGN.md §17).
 
     Calls ``jax.distributed.initialize`` with the given (or
     ``$REPRO_DIST_COORDINATOR`` / ``$REPRO_DIST_NUM_PROCESSES`` /
-    ``$REPRO_DIST_PROCESS_ID``) rendezvous parameters; returns True iff
-    the bootstrap ran.  Never raises: an unset/partial config returns
-    False (single-process operation is the default, not an error), and a
-    failed initialize warns and returns False — the serve fabric's
-    multi-processness lives at the socket level (``serve/router.py``),
-    so a worker that cannot join the XLA coordination service still
-    serves on its local devices.  Must run before the first device query
+    ``$REPRO_DIST_PROCESS_ID``) rendezvous parameters — all three, so JAX
+    looks nothing up — and returns True once the bootstrap ran.  An
+    unset/partial config returns False (single-process operation is the
+    default, not an error).  A configured bootstrap that fails raises: a
+    process told to join a coordination service must not quietly serve as
+    a single process instead.  Must run before the first device query
     locks the backend; idempotent (a second call is a no-op True).
     """
     global _DIST_INITIALIZED
@@ -48,13 +46,8 @@ def init_distributed(*, coordinator: str | None = None,
            else int(env("REPRO_DIST_PROCESS_ID", "-1") or -1))
     if not coordinator or nproc < 2 or pid < 0:
         return False
-    try:
-        jax.distributed.initialize(coordinator_address=coordinator,
-                                   num_processes=nproc, process_id=pid)
-    except Exception as exc:                 # noqa: BLE001 — best-effort
-        warnings.warn(f"jax.distributed.initialize failed "
-                      f"(serving single-process): {exc!r}", stacklevel=2)
-        return False
+    jax.distributed.initialize(coordinator_address=coordinator,
+                               num_processes=nproc, process_id=pid)
     _DIST_INITIALIZED = True
     return True
 
@@ -84,10 +77,8 @@ def serve_mesh(*, env_var: str = "REPRO_SERVE_MESH"):
     * ``"auto"``    — every visible device on one ``("data",)`` axis;
     * an integer    — that many devices (clamped to the visible count).
 
-    Returns ``None`` — engines then degrade gracefully to local dispatch —
-    when fewer than 2 devices would participate, or when the installed jax
-    predates the ``jax.shard_map``/``AxisType`` surface the sharded paths
-    target (the environment-gated seed condition, DESIGN.md §10).  A value
+    Returns ``None`` — engines then dispatch locally — when fewer than 2
+    devices would participate.  A value
     that parses as neither ``"auto"`` nor an integer raises — a typo'd
     explicit config should be loud, not silently single-device.  Like
     every mesh here this is a FUNCTION: importing the module never touches
@@ -103,8 +94,6 @@ def serve_mesh(*, env_var: str = "REPRO_SERVE_MESH"):
             raise ValueError(
                 f"${env_var}={spec!r}: expected unset, 'auto', or a device "
                 f"count") from None
-    if not (hasattr(jax, "shard_map") and hasattr(jax.sharding, "AxisType")):
-        return None
     # LOCAL devices only: the serve engines' sharded dispatch feeds host
     # arrays to this process's addressable devices.  Under multi-process
     # JAX (init_distributed) jax.device_count() is GLOBAL — building the

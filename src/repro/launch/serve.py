@@ -19,6 +19,7 @@ import numpy as np
 import jax
 
 from repro.configs.base import get_config, smoke_of
+from repro.launch import compile_cache
 from repro.models import build
 from repro.serve import Engine, Request, ServeConfig
 
@@ -55,6 +56,7 @@ def main(argv=None):
                          "and route through repro.serve.SVDRouter "
                          "(DESIGN.md §17)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.svd and args.hosts >= 2:
         return main_svd_multihost(args)
     if args.svd:
@@ -84,14 +86,15 @@ def main(argv=None):
 
 
 def main_svd(args):
-    """Open-loop async SVD serving demo (DESIGN.md §12)."""
-    jax.config.update("jax_enable_x64", True)
+    """Open-loop async SVD serving demo (DESIGN.md §12), in float32 — the
+    precision the chip computes in."""
     from repro.launch.mesh import serve_mesh
     from repro.serve import AsyncSVDEngine, SVDRequest
 
     mesh = serve_mesh()
     n, bw = args.svd_n, args.svd_bw
     rng = np.random.default_rng(0)
+    matrix = lambda: rng.standard_normal((n, n)).astype(np.float32)
     eng = AsyncSVDEngine(
         backend="auto", autotune=args.autotune, mesh=mesh,
         default_timeout_s=(args.timeout_ms / 1e3 or None))
@@ -103,8 +106,8 @@ def main_svd(args):
         print(f"metrics endpoint: {mserver.url}")
     # Warm the bucket (one compile) outside the timed window — never under
     # the engine's default deadline (compiles take seconds).
-    eng.submit(SVDRequest(uid=-1, matrix=rng.standard_normal((n, n)),
-                          bw=bw), timeout_s=float("inf")).result()
+    eng.submit(SVDRequest(uid=-1, matrix=matrix(), bw=bw),
+               timeout_s=float("inf")).result()
     # Hand-rolled open loop rather than benchmarks/serve_load.py's
     # poisson_run on purpose: src/ must stay importable with PYTHONPATH=src
     # alone (benchmarks/ lives outside the package).  The harness over
@@ -125,7 +128,7 @@ def main_svd(args):
     t0 = time.time()
     for uid in range(args.requests):
         time.sleep(gaps[uid])
-        r = SVDRequest(uid=uid, matrix=rng.standard_normal((n, n)), bw=bw)
+        r = SVDRequest(uid=uid, matrix=matrix(), bw=bw)
         f = eng.submit(r)
         f.add_done_callback(_stamp(r))
         futs.append(f)
@@ -164,10 +167,12 @@ def main_svd_multihost(args):
     is ``benchmarks/serve_load.py --hosts N``; this is the demo."""
     from repro.serve import SVDRequest
     from repro.serve.router import SVDRouter
-    from repro.serve.worker import spawn_worker_process
+    from repro.serve.worker import check_fleet_fits, spawn_worker_process
 
+    check_fleet_fits(args.hosts)
     n, bw = args.svd_n, args.svd_bw
     rng = np.random.default_rng(0)
+    matrix = lambda: rng.standard_normal((n, n)).astype(np.float32)
     router = SVDRouter(
         default_timeout_s=(args.timeout_ms / 1e3 or None))
     procs = [spawn_worker_process(router.address, f"w{i}", backend="auto")
@@ -186,15 +191,13 @@ def main_svd_multihost(args):
                 "fleet", lambda: render_fleet_metrics(router.fleet()))
             print(f"metrics endpoint: {mserver.url}")
         # Warm every host's bucket compile outside the timed window.
-        router.warm([SVDRequest(uid=-1,
-                                matrix=rng.standard_normal((n, n)), bw=bw)])
+        router.warm([SVDRequest(uid=-1, matrix=matrix(), bw=bw)])
         gaps = rng.exponential(1.0 / args.rate, args.requests)
         futs, lat = [], []
         t0 = time.time()
         for uid in range(args.requests):
             time.sleep(gaps[uid])
-            r = SVDRequest(uid=uid, matrix=rng.standard_normal((n, n)),
-                           bw=bw)
+            r = SVDRequest(uid=uid, matrix=matrix(), bw=bw)
             futs.append((r, router.submit(r)))
         for r, f in futs:
             try:

@@ -17,7 +17,7 @@ Two integration rules keep the tracer zero-cost and jit-safe:
   tracer is active.  Production paths pay one dict lookup.
 * **Inside jit tracing → no-op.**  Host spans make no sense while JAX is
   abstractly tracing a function (the "times" would be trace times of
-  symbolic values).  :func:`span` checks ``jax.core.trace_state_clean()``
+  symbolic values).  :func:`span` checks ``jax.core.trace_ctx.is_top_level()``
   and degrades to the null span under tracing; device-side attribution
   inside jitted code uses ``jax.named_scope`` instead (§16).
 
@@ -61,10 +61,7 @@ _ids = itertools.count(1)
 
 def _host_clean() -> bool:
     """True when we are NOT inside jax tracing (host spans are meaningful)."""
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 class Span:

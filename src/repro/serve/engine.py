@@ -276,6 +276,11 @@ class SVDEngine:
         from repro.core import tuning
         if config is None:
             config = tuning.PipelineConfig.resolve(backend=backend)
+            # Buckets re-resolve "auto" against their own dtype: float64 on
+            # a TPU takes the ref backend (Pallas has no float64).
+            self._backend = backend
+        else:
+            self._backend = config.backend
         if max_batch is not None:
             config = dataclasses.replace(config, max_batch=max_batch)
         self.config = config
@@ -429,7 +434,7 @@ class SVDEngine:
             except ValueError:
                 cfg = None
         if cfg is None:
-            cfg = resolve(self.config.backend)
+            cfg = resolve(self._backend)
         self.metrics.set_bucket_tier(key, self._tier_of(cfg, n), n=n,
                                      backend=cfg.backend)
         self._cfg_memo[key] = cfg

@@ -31,6 +31,7 @@ Three entry points:
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import socket
 import subprocess
@@ -41,7 +42,8 @@ import numpy as np
 
 from repro.serve.wire import WireClosed, recv_msg, send_msg
 
-__all__ = ["ServeWorker", "start_inprocess_worker", "spawn_worker_process"]
+__all__ = ["ServeWorker", "start_inprocess_worker", "spawn_worker_process",
+           "check_fleet_fits", "tpu_attached"]
 
 
 class ServeWorker:
@@ -180,7 +182,34 @@ def start_inprocess_worker(address, host_id: str, *,
     return worker, thread
 
 
-def spawn_worker_process(address, host_id: str, *, backend: str = "ref",
+def tpu_attached() -> bool:
+    """Whether this host has TPU chips, read from the device files so the
+    caller's own JAX backend stays uninitialised."""
+    return bool(glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_fleet_fits(hosts: int, env: dict | None = None) -> None:
+    """Refuse ``hosts >= 2`` worker processes on a TPU host.
+
+    A chip belongs to one process at a time and every worker's JAX would
+    open all of the host's chips, so the second worker could not start.
+    Workers forced off the TPU (``JAX_PLATFORMS`` without ``tpu``) are
+    fine; so is one worker, which drives every local chip itself.  Checked
+    before spawning, from the device files: the parent must not open the
+    chip either.
+    """
+    env = os.environ if env is None else env
+    platforms = env.get("JAX_PLATFORMS", "").strip().lower()
+    off_tpu = bool(platforms) and "tpu" not in platforms.split(",")
+    if hosts >= 2 and not off_tpu and tpu_attached():
+        raise RuntimeError(
+            f"{hosts} worker processes on a TPU host: each would open every "
+            f"chip and only the first can; run one worker per host (it "
+            f"drives all local chips) or set JAX_PLATFORMS=cpu for the "
+            f"workers")
+
+
+def spawn_worker_process(address, host_id: str, *, backend: str = "auto",
                          window_ms: float = 5.0, devices: int = 0,
                          coordinator: str = "", num_processes: int = 0,
                          process_id: int = -1,
@@ -191,7 +220,8 @@ def spawn_worker_process(address, host_id: str, *, backend: str = "ref",
     child (the SNIPPETS.md multi-process idiom); ``coordinator`` opts the
     child in to ``jax.distributed`` bootstrap.  The child inherits this
     interpreter and ``PYTHONPATH`` — callers outside ``src`` (the
-    benchmark driver, CI) need no extra wiring."""
+    benchmark harness, CI) need no extra wiring.  Callers spawning more
+    than one worker call :func:`check_fleet_fits` first."""
     host, port = address
     # `-c` entry rather than `-m repro.serve.worker`: the package __init__
     # already imports this module, so runpy would warn about (and shadow)
@@ -223,7 +253,7 @@ def main(argv=None) -> int:
     ap.add_argument("--connect", required=True, metavar="HOST:PORT",
                     help="router listen address to dial")
     ap.add_argument("--host-id", required=True)
-    ap.add_argument("--backend", default="ref")
+    ap.add_argument("--backend", default="auto")
     ap.add_argument("--window-ms", type=float, default=5.0,
                     help="engine micro-batch window")
     ap.add_argument("--max-pending", type=int, default=4096)
@@ -242,8 +272,13 @@ def main(argv=None) -> int:
                          num_processes=args.num_processes,
                          process_id=args.process_id)
     import jax
+    # fp64 requests need x64.  On a TPU their buckets resolve to the ref
+    # backend (Pallas has no float64); the Pallas kernels keep their
+    # operands and indices 32-bit either way, so f32 traffic is unaffected.
     jax.config.update("jax_enable_x64", True)
+    from repro.launch import compile_cache
     from repro.launch.mesh import serve_mesh
+    compile_cache.enable()
 
     host, _, port = args.connect.rpartition(":")
     sock = socket.create_connection((host, int(port)), timeout=60)

@@ -116,8 +116,11 @@ class TestCostModel:
         assert at_model.profile_for("TPU v5e").device_kind == "tpu v5e"
         assert at_model.profile_for("TPU v5 litepod-16") \
             .device_kind == "tpu v5e"
-        assert at_model.profile_for("NVIDIA H100").device_kind == "gpu"
-        assert at_model.profile_for("weird-accelerator").device_kind == "cpu"
+        assert at_model.profile_for("TPU v4").device_kind == "tpu v4"
+        # No fallback: a kind without a row is an error, not a guess.
+        for unknown in ("NVIDIA H100", "weird-accelerator", "TPU v6 lite"):
+            with pytest.raises(ValueError, match="no device profile"):
+                at_model.profile_for(unknown)
         # The live device resolves to something in the table.
         assert at_model.profile_for() in at_model.PROFILES.values()
 

@@ -292,6 +292,45 @@ def test_registry_resolution_and_errors():
                         b_in=3, tw=2, backend="nope")
 
 
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Resolve backends as on a TPU host (resolution only: nothing runs)."""
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+
+
+def test_float64_on_tpu_resolves_to_ref(on_tpu):
+    """Pallas on TPU has no float64: "auto" picks ref for 64-bit data by
+    platform, and an explicit Pallas backend refuses it at resolution."""
+    assert ops.resolve_backend("auto", dtype=jnp.float32) == ("pallas", False)
+    assert ops.resolve_backend("auto", dtype=np.float64)[0] == "ref"
+    cfg = PipelineConfig.resolve(bw=16, dtype=np.float64, n=64)
+    assert cfg.backend == "ref" and cfg.dtype == "float64"
+    for backend in ("pallas", "fused_small"):
+        with pytest.raises(ValueError, match="float64"):
+            PipelineConfig.resolve(bw=16, backend=backend, dtype=np.float64)
+    # interpret mode runs the kernel body as XLA ops, which have float64
+    assert PipelineConfig.resolve(bw=16, backend="pallas", interpret=True,
+                                  dtype=np.float64).backend == "pallas"
+
+
+def test_engine_float64_bucket_on_tpu_takes_ref_tier(on_tpu):
+    """An "auto" engine on a TPU resolves float64 buckets to the ref
+    backend up front, never by catching a failed Pallas compile."""
+    eng = SVDEngine(backend="auto")
+    f32 = eng._cfg_for((64, 8, "float32", False, False))
+    f64 = eng._cfg_for((64, 8, "float64", False, False))
+    big = eng._cfg_for((512, 16, "float64", False, True))
+    assert f32.backend == "fused_small" and not f32.interpret
+    assert f64.backend == "ref" and big.backend == "ref"
+    tiers = eng.metrics.snapshot()["bucket_tiers"]
+    assert {row["backend"] for row in tiers.values()} == {"fused_small",
+                                                          "ref"}
+    # an engine pinned to Pallas fails such a bucket with a clear error
+    pinned = SVDEngine(PipelineConfig.resolve(bw=8, dtype=jnp.float32))
+    with pytest.raises(ValueError, match="float64"):
+        pinned._cfg_for((512, 8, "float64", False, False))
+
+
 def test_default_bucket_batch_fills_wavefront():
     for n, bw in [(24, 4), (32, 8), (256, 32), (4096, 32)]:
         B = tuning.default_bucket_batch(n, bw)

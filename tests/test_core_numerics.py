@@ -2,6 +2,7 @@
 packed band storage, and the Golub-Kahan stage-3 bisection."""
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
@@ -126,3 +127,35 @@ def test_bidiag_sv_fp32():
     s_ref = np.linalg.svd(B, compute_uv=False)
     s = np.asarray(bidiag_singular_values(jnp.asarray(d), jnp.asarray(e)))
     np.testing.assert_allclose(s, s_ref, rtol=2e-5, atol=2e-6 * s_ref[0])
+
+
+def test_sigma_error_is_normwise_against_fp64():
+    from repro.core.reference import sigma_error
+    rng = np.random.default_rng(5)
+    a = rng.standard_normal((32, 32))
+    s = np.linalg.svd(a, compute_uv=False)
+    assert sigma_error(s, a) <= 1e-14
+    bumped = s.copy()
+    bumped[-1] += 1e-3 * s[0]        # an error on the smallest sigma counts
+    np.testing.assert_allclose(sigma_error(bumped, a), 1e-3, rtol=1e-9)
+    # float32 inputs are judged against the fp64 spectrum of that input
+    a32 = a.astype(np.float32)
+    s32 = np.linalg.svd(a32, compute_uv=False)
+    assert 0 < sigma_error(s32, a32) <= 10 * 32 * np.finfo(np.float32).eps
+
+
+@pytest.mark.parametrize("entry,backend", [
+    ("singular_values", "ref"), ("singular_values", "pallas"),
+    ("svd", "ref"), ("banded_svd", "ref"), ("svd", "fused_small")])
+def test_pipeline_dots_ask_for_full_precision(entry, backend):
+    """Every dot the pipeline issues carries HIGHEST precision itself: a
+    TPU runs an f32 dot as one bf16 pass by default, and no caller should
+    have to wrap the pipeline in a precision context to get f32 answers."""
+    from repro.core import svd as svdmod
+    fn = getattr(svdmod, entry)
+    a = jax.ShapeDtypeStruct((2, 24, 24), jnp.float32)
+    text = jax.jit(lambda m: fn(m, bw=4, backend=backend)).lower(a).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert dots
+    assert all("HIGHEST" in ln for ln in dots), [
+        ln for ln in dots if "HIGHEST" not in ln][:3]

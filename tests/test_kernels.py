@@ -1,7 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -76,56 +75,8 @@ def test_ops_unknown_backend_raises():
 
 
 # ---------------------------------------------------------------------------
-# flash attention (A4 kernel) + stage-1 pallas integration
+# stage-1 pallas integration
 # ---------------------------------------------------------------------------
-
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ref import flash_attention_ref
-
-# Known seed failure (DESIGN.md §10): jax < 0.5 pallas interpret mode cannot
-# discharge the flash kernel's masked loads (`_load_discharge_rule` receives a
-# plain int index -> AttributeError: 'int' object has no attribute 'shape').
-# The chase kernels never hit this path; the flash tests xfail (non-strict, so
-# a jax upgrade that fixes interpret mode turns them back on silently).
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:3]
-                     if p.isdigit())
-flash_interpret_xfail = pytest.mark.xfail(
-    _JAX_VERSION < (0, 5), strict=False,
-    reason="jax<0.5 pallas interpret bug: masked-load discharge fails "
-           "(pre-existing seed failure, DESIGN.md §10)")
-
-FLASH_SHAPES = [(4, 256, 64, 64, 64), (2, 128, 32, 32, 64),
-                (2, 256, 64, 128, 32), (1, 64, 16, 64, 64),
-                (3, 192, 64, 64, 32)]
-
-
-@flash_interpret_xfail
-@pytest.mark.parametrize("bh,s,d,bq,bk", FLASH_SHAPES)
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-6), (jnp.bfloat16, 3e-2)])
-def test_flash_attention_matches_ref(bh, s, d, bq, bk, dtype, tol):
-    rng = np.random.default_rng(s + d)
-    q, k, v = (jnp.asarray(rng.standard_normal((bh, s, d)), dtype)
-               for _ in range(3))
-    a = flash_attention_ref(q, k, v)
-    b = flash_attention_pallas(q, k, v, block_q=bq, block_k=bk, interpret=True)
-    err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
-    assert err < tol, err
-
-
-@flash_interpret_xfail
-def test_flash_attention_is_causal():
-    """Perturbing future tokens must not change earlier outputs."""
-    rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.standard_normal((1, 128, 32)), jnp.float32)
-               for _ in range(3))
-    o1 = flash_attention_pallas(q, k, v, block_q=32, block_k=32, interpret=True)
-    k2 = k.at[:, 96:].add(5.0)
-    v2 = v.at[:, 96:].add(5.0)
-    o2 = flash_attention_pallas(q, k2, v2, block_q=32, block_k=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(o1[:, :96]), np.asarray(o2[:, :96]),
-                               atol=1e-6)
-    assert float(jnp.max(jnp.abs(o1[:, 96:] - o2[:, 96:]))) > 1e-3
-
 
 def test_stage1_pallas_backend_bit_exact():
     from repro.core.stage1 import band_reduce

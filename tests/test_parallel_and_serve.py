@@ -14,16 +14,6 @@ from repro.parallel.sharding import AxisRules, _SINGLE, _MULTI
 from repro.roofline.hlo_parse import parse_module
 from repro.serve import Engine, Request, ServeConfig
 
-# Known seed failure (DESIGN.md §10): the mesh construction used by the
-# multi-device paths (launch/mesh.py and the subprocess snippets below)
-# targets the jax.sharding.AxisType / jax.shard_map API surface, which the
-# pinned jax 0.4.37 does not have.  Condition-based so a jax upgrade turns
-# the tests back on without edits.
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable on this jax "
-           "(pre-existing seed failure, DESIGN.md §10)")
-
 
 # ---------------------------------------------------------------------------
 # sharding rules
@@ -44,7 +34,6 @@ def test_multipod_rules_batch_axes():
     assert r.spec(("batch",)) == P(("pod", "data"))
 
 
-@needs_axis_type
 def test_prune_spec_divisibility():
     from repro.launch.dryrun import _prune_spec
     mesh = jax.make_mesh((1,), ("model",),
@@ -167,7 +156,6 @@ def test_engine_continuous_batching_refills():
 # subprocess integration: sharded trainer + production-mesh dry-run
 # ---------------------------------------------------------------------------
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_sharded_train_step_8dev(subproc):
     code = """
@@ -197,7 +185,6 @@ print("SHARDED_OK", float(m["loss"]))
     assert "SHARDED_OK" in r.stdout, r.stderr[-2000:]
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_compressed_train_step_8dev(subproc):
     code = """
@@ -233,7 +220,6 @@ print("COMPRESS_OK", float(m["compression_ratio"]))
     assert "COMPRESS_OK" in r.stdout, r.stderr[-2000:]
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_dryrun_cell_production_mesh(subproc):
     """One real cell through the actual 512-device dry-run path."""
@@ -270,7 +256,6 @@ def test_engine_whisper_cross_attention():
     assert done[0].output != done[1].output
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_elastic_reshard_restore(subproc, tmp_path):
     """Checkpoint written on 1 device restores onto an 8-device mesh with
@@ -313,7 +298,6 @@ print("ELASTIC_OK", float(m["loss"]))
     assert "ELASTIC_OK" in r.stdout, (r.stdout[-400:], r.stderr[-2000:])
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_distributed_halo_chase_8dev(subproc):
     """Beyond-paper: single-matrix bulge chase sharded column-wise over 8

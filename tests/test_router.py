@@ -315,8 +315,8 @@ def test_metrics_server_fleet_provider():
 
 
 # ---------------------------------------------------------------------------
-# serve_mesh: local-devices fix (unit-level — installed jax may predate
-# shard_map/AxisType, and multi-process init needs real peers)
+# serve_mesh: local-devices fix (unit-level — multi-process init needs
+# real peers)
 # ---------------------------------------------------------------------------
 
 def test_serve_mesh_builds_from_local_devices(monkeypatch):
@@ -326,16 +326,10 @@ def test_serve_mesh_builds_from_local_devices(monkeypatch):
     local = [object(), object()]
     calls = {}
 
-    class FakeAxisType:
-        Auto = "auto"
-
     def fake_make_mesh(shape, axes, devices=None, axis_types=None):
         calls.update(shape=shape, axes=axes, devices=devices)
         return "MESH"
 
-    monkeypatch.setattr(jax, "shard_map", object(), raising=False)
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
     # The multi-process regime the fix targets: 2 local, 4 global.
     monkeypatch.setattr(jax, "local_devices", lambda: list(local))
     monkeypatch.setattr(jax, "device_count", lambda: 4)
@@ -350,6 +344,18 @@ def test_serve_mesh_builds_from_local_devices(monkeypatch):
     monkeypatch.setenv("REPRO_SERVE_MESH", "8")   # clamped to local count
     meshmod.serve_mesh()
     assert calls["shape"] == (2,)
+
+
+def test_fleet_refuses_several_workers_on_a_tpu_host(monkeypatch):
+    from repro.serve import worker as workermod
+
+    monkeypatch.setattr(workermod, "tpu_attached", lambda: True)
+    with pytest.raises(RuntimeError, match="TPU host"):
+        workermod.check_fleet_fits(2, env={})
+    workermod.check_fleet_fits(1, env={})              # one worker: all chips
+    workermod.check_fleet_fits(4, env={"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setattr(workermod, "tpu_attached", lambda: False)
+    workermod.check_fleet_fits(4, env={})              # CPU host
 
 
 def test_init_distributed_unconfigured_is_noop(monkeypatch):

@@ -7,17 +7,11 @@ import threading
 import time
 
 import numpy as np
-import jax
 import pytest
 
 from repro.core.tuning import PipelineConfig
 from repro.serve import (AsyncSVDEngine, QueueFullError, SVDEngine,
                          SVDRequest)
-
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable on this jax "
-           "(pre-existing seed failure, DESIGN.md §10)")
 
 
 def cfg4(max_batch=4):
@@ -267,7 +261,7 @@ def test_serve_mesh_unset_env_is_none(monkeypatch):
 
 
 def test_serve_mesh_single_device_degrades_to_none(monkeypatch):
-    # On a 1-device host (or a pre-AxisType jax) the sharded path is
+    # On a 1-device host the sharded path is
     # unreachable; the engine must get None and serve locally.
     from repro.launch.mesh import serve_mesh
     monkeypatch.setenv("REPRO_SERVE_MESH", "1")
@@ -276,7 +270,6 @@ def test_serve_mesh_single_device_degrades_to_none(monkeypatch):
     assert serve_mesh() is None
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_async_sharded_dispatch_8dev(subproc):
     """Full buckets batch-shard across 8 (fake) devices: results match the

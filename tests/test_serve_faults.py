@@ -6,7 +6,6 @@ the sharded shard-loss re-dispatch (bitwise-identical recovery)."""
 import time
 
 import numpy as np
-import jax
 import pytest
 
 from repro.core import svd as svdmod
@@ -15,11 +14,6 @@ from repro.core.tuning import PipelineConfig
 from repro.serve import (AsyncSVDEngine, BucketQuarantine, FaultPlan,
                          InjectedDispatchError, RetryPolicy, SVDEngine,
                          SVDRequest)
-
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable on this jax "
-           "(pre-existing seed failure, DESIGN.md §10)")
 
 
 def cfg4(max_batch=4):
@@ -371,7 +365,6 @@ def test_async_deadline_rechecked_at_completion():
 # sharded dispatch: shard loss -> bitwise-identical re-dispatch
 # ---------------------------------------------------------------------------
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_sharded_shard_loss_redispatch_bitwise_identical(subproc):
     code = """
@@ -404,7 +397,6 @@ print("SHARD_LOSS_BITWISE_OK")
                                                  r.stderr[-2000:])
 
 
-@needs_axis_type
 @pytest.mark.distributed
 def test_async_sharded_engine_survives_shard_loss(subproc):
     """End-to-end: the async engine on a mesh, with per-shard losses

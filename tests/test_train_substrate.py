@@ -17,15 +17,6 @@ from repro.train import (AdamWConfig, DataConfig, FailureInjector,
 from repro.train.optimizer import cosine_lr, global_norm
 from repro.train.spectral import SpectralMonitor, SpectralMonitorConfig, spectral_metrics
 
-# Known seed failure (DESIGN.md §10): the gradient-compression loop shards
-# through jax.shard_map over a mesh built with jax.sharding.AxisType — API
-# surface the pinned jax 0.4.37 does not have.  Condition-based so a jax
-# upgrade turns the tests back on without edits.
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable on this jax "
-           "(pre-existing seed failure, DESIGN.md §10)")
-
 
 # ---------------------------------------------------------------------------
 # optimizer
@@ -243,7 +234,6 @@ def _compress_loop(g, rank, iters):
     return total / iters, stats
 
 
-@needs_axis_type
 def test_compression_recovers_low_rank_gradient():
     """Warm-started subspace iteration locks onto a rank-4 gradient: the
     reconstruction becomes near-exact and the telescoped EF residual -> 0."""
@@ -256,7 +246,6 @@ def test_compression_recovers_low_rank_gradient():
     assert stats["compression_ratio"] > 5
 
 
-@needs_axis_type
 def test_compression_error_feedback_telescopes():
     """Full-rank (white-noise) gradient: the time-averaged compressed signal
     still drifts toward g (EF telescoping), even though per-step rank-4
