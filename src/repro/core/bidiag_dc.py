@@ -51,6 +51,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from .householder import exact_matmul as _mm
 from .bidiag_svd import (_gk_prescale, _vectors_from_sigma,
                          bidiag_singular_values, bidiag_svd,
@@ -737,6 +739,7 @@ def _merge_pair(d1, f1, l1, d2, f2, l2, rho_b, *, newton_iters: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("leaf_n", "newton_iters", "inv_iters"))
+@obs.scope("stage3")
 def bidiag_dc_singular_values(d: jax.Array, e: jax.Array, *,
                               leaf_n: int = DEFAULT_DC_LEAF_N,
                               newton_iters: int = 30,
@@ -831,6 +834,7 @@ def bidiag_dc_singular_values(d: jax.Array, e: jax.Array, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("leaf_n", "newton_iters", "inv_iters"))
+@obs.scope("stage3")
 def bidiag_dc_svd(d: jax.Array, e: jax.Array, *,
                   leaf_n: int = DEFAULT_DC_LEAF_N,
                   newton_iters: int = 30,

@@ -27,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.householder import exact_matmul as _mm
 
 __all__ = ["gk_offdiag", "sturm_count", "bidiag_singular_values",
@@ -96,6 +97,7 @@ def _gk_prescale(z: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("max_iter",))
+@obs.scope("stage3")
 def bidiag_singular_values(d: jax.Array, e: jax.Array, *,
                            max_iter: int | None = None) -> jax.Array:
     """All singular values of the bidiagonal (d, e), descending.
@@ -238,6 +240,7 @@ def _vectors_from_sigma(d: jax.Array, e: jax.Array, sig: jax.Array, *,
 
 
 @functools.partial(jax.jit, static_argnames=("max_iter", "inv_iters"))
+@obs.scope("stage3")
 def bidiag_svd(d: jax.Array, e: jax.Array, *, max_iter: int | None = None,
                inv_iters: int = 2):
     """Full SVD of the upper bidiagonal (d, e): returns (U, sigma, V^T).

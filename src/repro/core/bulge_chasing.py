@@ -144,6 +144,7 @@ def chase_cycle_indices(t, g, n: int, b_in: int, tw: int, fuse: int = 1):
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
                                              "unroll", "config", "tape",
                                              "fuse"))
+@obs.scope("stage2")
 def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
                         backend: str = "auto", unroll: int | None = None,
                         config=None, tape: bool = False,
@@ -381,40 +382,39 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
     if not plan:
         h = band.shape[-2]
         tw0 = (h - 2) // 2 if h > 2 else 0
-        d = bandmod.band_extract_diag(band, tw0, 0, n)
-        e = (bandmod.band_extract_diag(band, tw0, 1, n) if bw >= 1
-             else jnp.zeros(band.shape[:-2] + (n,), band.dtype))
+        with obs.span("extract"):
+            d = bandmod.band_extract_diag(band, tw0, 0, n)
+            e = (bandmod.band_extract_diag(band, tw0, 1, n) if bw >= 1
+                 else jnp.zeros(band.shape[:-2] + (n,), band.dtype))
         return (d, e, []) if tape else (d, e)
     cur = band
     tw_cur = plan[0][1]
     assert cur.shape[-2] == plan[0][0] + 2 * tw_cur + 1, (cur.shape, plan[0])
     tapes = []
     for b_in, twi in plan:
-        # re-slice so exactly twi sub rows remain above the diagonal row
-        h_i = b_in + 2 * twi + 1
-        start = tw_cur - twi
-        if start != 0 or cur.shape[-2] != h_i:
-            cur = jax.lax.slice_in_dim(cur, start, start + h_i, axis=-2)
-        # Span per stage of the tile-width plan (DESIGN.md §16): no-op
-        # unless an ambient tracer is active AND we're outside jit tracing
-        # (inside `_three_stage` this whole loop is traced symbolically).
-        with obs.span("chase_stage", n=n, b_in=b_in, tw=twi, fuse=fuse,
-                      tape=tape) as sp:
+        # One span per stage of the tile-width plan (DESIGN.md §16); inside
+        # `_three_stage` this loop is traced and the spans are no-ops.
+        with obs.span("stage2", n=n, b_in=b_in, tw=twi, fuse=fuse,
+                      tape=tape):
+            # re-slice so exactly twi sub rows remain above the diagonal row
+            h_i = b_in + 2 * twi + 1
+            start = tw_cur - twi
+            if start != 0 or cur.shape[-2] != h_i:
+                cur = jax.lax.slice_in_dim(cur, start, start + h_i, axis=-2)
             if tape:
-                cur, tv, tt = obs.traced_jit_call(
-                    "chase_stage", reduce_stage_packed, cur, n=n, b_in=b_in,
-                    tw=twi, backend=backend, config=config, tape=True,
-                    fuse=fuse)
+                cur, tv, tt = reduce_stage_packed(
+                    cur, n=n, b_in=b_in, tw=twi, backend=backend,
+                    config=config, tape=True, fuse=fuse)
                 tapes.append(transforms.ChaseTape(n=n, b_in=b_in, tw=twi,
                                                   v=tv, tau=tt, fuse=fuse))
             else:
-                cur = obs.traced_jit_call(
-                    "chase_stage", reduce_stage_packed, cur, n=n, b_in=b_in,
-                    tw=twi, backend=backend, config=config, fuse=fuse)
-            sp.fence(cur)
+                cur = reduce_stage_packed(cur, n=n, b_in=b_in, tw=twi,
+                                          backend=backend, config=config,
+                                          fuse=fuse)
         tw_cur = twi
-    d = bandmod.band_extract_diag(cur, tw_cur, 0, n)
-    e = bandmod.band_extract_diag(cur, tw_cur, 1, n)
+    with obs.span("extract"):
+        d = bandmod.band_extract_diag(cur, tw_cur, 0, n)
+        e = bandmod.band_extract_diag(cur, tw_cur, 1, n)
     return (d, e, tapes) if tape else (d, e)
 
 
@@ -427,6 +427,7 @@ def bidiagonalize(a: jax.Array, *, bw: int, tw: int, backend: str = "auto",
     cycles per kernel dispatch (see :func:`bidiagonalize_packed`)."""
     n = a.shape[-1]
     tw0 = min(tw, max(bw - 1, 1))
-    packed = bandmod.pack(a, bw, tw0)
+    with obs.span("pack", bw=bw, tw=tw0):
+        packed = bandmod.pack(a, bw, tw0)
     return bidiagonalize_packed(packed, n=n, bw=bw, tw=tw, backend=backend,
                                 config=config, tape=tape, fuse=fuse)

@@ -180,9 +180,8 @@ def sharded_pipeline_dispatch(mats: jax.Array, mesh: Mesh, *, config,
             # Re-dispatch the whole batch unsharded (same pipeline body).
             if on_shard_retry is not None:
                 on_shard_retry(shards)
-            with obs.span("sharded_fallback_unsharded", shards=shards) as sp:
+            with obs.span("sharded_fallback_unsharded", shards=shards):
                 out = local(mats)
-                sp.fence(out)
             dsp.set(fallback="unsharded")
         else:
             lost = faults.lost_shards(shards) if faults is not None else []
@@ -197,9 +196,8 @@ def sharded_pipeline_dispatch(mats: jax.Array, mesh: Mesh, *, config,
                     # shard j sees exactly the bytes it saw in the clean run
                     # -> bitwise-identical recovery.
                     reps = (shards,) + (1,) * (mats.ndim - 1)
-                    with obs.span("shard_retry", shard=j) as sp:
+                    with obs.span("shard_retry", shard=j):
                         rout = fn(jnp.tile(mats[sl], reps))
-                        sp.fence(rout)
                     rparts = list(rout) if compute_uv else [rout]
                     for i, (arr, rarr) in enumerate(zip(parts, rparts)):
                         voided = arr.at[sl].set(jnp.nan)
@@ -207,7 +205,6 @@ def sharded_pipeline_dispatch(mats: jax.Array, mesh: Mesh, *, config,
                     if on_shard_retry is not None:
                         on_shard_retry(1)
                 out = tuple(parts) if compute_uv else parts[0]
-        dsp.fence(out)
     if compute_uv:
         u, sig, vt = out
         return u[:b0], sig[:b0], vt[:b0]

@@ -25,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.householder import exact_matmul as _mm
 
 __all__ = ["band_reduce", "wy_t_factor"]
@@ -73,6 +74,7 @@ def wy_t_factor(v: jax.Array, taus: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("nb", "backend", "config",
                                              "tape"))
+@obs.scope("stage1")
 def band_reduce(a: jax.Array, *, nb: int, backend: str | None = None,
                 config=None, tape: bool = False):
     """Reduce dense (..., n, n) to upper-banded form with bandwidth ``nb``.

@@ -35,6 +35,7 @@ internally; passing a kwarg that conflicts with a supplied config raises:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -150,27 +151,44 @@ def _stage3_values(d: jax.Array, e: jax.Array,
     divide-and-conquer solve, "auto" collapsing per problem size through
     ``stage3_for``.  Both accept leading batch axes and agree on sigma to
     ~1e-12 relative (gated by tests/test_bidiag_dc.py)."""
-    if cfg.stage3_for(d.shape[-1]) == "dc":
-        return s3dc.bidiag_dc_singular_values(d, e, leaf_n=cfg.dc_leaf_n)
-    return s3.bidiag_singular_values(d, e)
+    solver = cfg.stage3_for(d.shape[-1])
+    with obs.span("stage3", solver=solver):
+        if solver == "dc":
+            return s3dc.bidiag_dc_singular_values(d, e, leaf_n=cfg.dc_leaf_n)
+        return s3.bidiag_singular_values(d, e)
 
 
 def _stage3_svd(d: jax.Array, e: jax.Array, cfg: tuning.PipelineConfig):
     """Full-SVD stage-3 dispatch; both solvers share the inverse-iteration
     vector machinery, so (U, V^T) quality is policy-independent."""
-    if cfg.stage3_for(d.shape[-1]) == "dc":
-        return s3dc.bidiag_dc_svd(d, e, leaf_n=cfg.dc_leaf_n)
-    return s3.bidiag_svd(d, e)
+    solver = cfg.stage3_for(d.shape[-1])
+    with obs.span("stage3", solver=solver, compute_uv=True):
+        if solver == "dc":
+            return s3dc.bidiag_dc_svd(d, e, leaf_n=cfg.dc_leaf_n)
+        return s3.bidiag_svd(d, e)
 
 
-def _resolve_tracer(trace):
-    """The tracer for this call: an explicit ``trace=`` wins, else the
-    ambient one (``repro.obs.current()``), else None.  Host spans are only
-    meaningful outside jax tracing (DESIGN.md §16)."""
-    tr = trace if trace is not None else obs.current()
-    if tr is None or not jax.core.trace_ctx.is_top_level():
-        return None
-    return tr
+@contextlib.contextmanager
+def _entry(name: str, trace):
+    """The root span of one entry-point call (DESIGN.md §16), recorded into
+    ``trace`` when one is given, else into the ambient tracer, if any.  The
+    tracer only chooses where spans are recorded: the code path is the
+    same with or without one."""
+    with obs.activated(trace), obs.span(name) as root:
+        yield root
+
+
+def _config(a, config, **legacy) -> tuning.PipelineConfig:
+    with obs.span("config"):
+        return tuning.PipelineConfig.of(config, dtype=a.dtype, n=a.shape[-1],
+                                        **legacy)
+
+
+def _validated(sig):
+    """``sig`` after the post-solve health guard (:func:`validate_sigma`)."""
+    with obs.span("validate"):
+        validate_sigma(sig)
+    return sig
 
 
 def _span_attrs(a, cfg: tuning.PipelineConfig, **extra) -> dict:
@@ -181,54 +199,6 @@ def _span_attrs(a, cfg: tuning.PipelineConfig, **extra) -> dict:
     return dict(n=int(a.shape[-1]), bw=cfg.bw, tw=cfg.tw, fuse=cfg.fuse,
                 dtype=str(a.dtype), backend=cfg.backend, batch=batch,
                 **extra)
-
-
-def _stage3_values_traced(d: jax.Array, e: jax.Array,
-                          cfg: tuning.PipelineConfig) -> jax.Array:
-    """Values-mode stage 3 under an ambient tracer: same solver dispatch as
-    :func:`_stage3_values`, but inside a ``stage3`` span with compile/run
-    split and device fencing."""
-    solver = cfg.stage3_for(d.shape[-1])
-    with obs.span("stage3", solver=solver, n=int(d.shape[-1])) as sp:
-        if solver == "dc":
-            sig = obs.traced_jit_call("stage3_dc",
-                                      s3dc.bidiag_dc_singular_values, d, e,
-                                      leaf_n=cfg.dc_leaf_n)
-        else:
-            sig = obs.traced_jit_call("stage3_bisect",
-                                      s3.bidiag_singular_values, d, e)
-        sp.fence(sig)
-    return sig
-
-
-def _stage3_svd_traced(d: jax.Array, e: jax.Array,
-                       cfg: tuning.PipelineConfig):
-    solver = cfg.stage3_for(d.shape[-1])
-    with obs.span("stage3", solver=solver, n=int(d.shape[-1]),
-                  compute_uv=True) as sp:
-        if solver == "dc":
-            out = obs.traced_jit_call("stage3_dc_svd", s3dc.bidiag_dc_svd,
-                                      d, e, leaf_n=cfg.dc_leaf_n)
-        else:
-            out = obs.traced_jit_call("stage3_svd", s3.bidiag_svd, d, e)
-        sp.fence(out)
-    return out
-
-
-def _three_stage_traced(a: jax.Array, cfg: tuning.PipelineConfig
-                        ) -> jax.Array:
-    """Traced values path: the SAME per-stage jitted functions
-    ``_three_stage`` composes, run eagerly so each stage gets its own
-    fenced span (and its own compile-vs-run attribution).  Sigma is
-    unchanged — the stage boundaries are already jit boundaries inside
-    ``_three_stage``; only the outer fusion wrapper is dropped."""
-    with obs.span("stage1", **_span_attrs(a, cfg)) as sp:
-        banded = sp.fence(obs.traced_jit_call(
-            "stage1", s1.band_reduce, a, nb=cfg.bw, config=cfg))
-    with obs.span("stage2", **_span_attrs(a, cfg)) as sp:
-        d, e = bc.bidiagonalize(banded, bw=cfg.bw, tw=cfg.tw, config=cfg)
-        sp.fence((d, e))
-    return _stage3_values_traced(d, e, cfg)
 
 
 def _fused_path(a: jax.Array, cfg: tuning.PipelineConfig, *,
@@ -246,14 +216,17 @@ def _fused_path(a: jax.Array, cfg: tuning.PipelineConfig, *,
     n = a.shape[-1]
     mats = a.reshape((-1,) + a.shape[-2:])
     if not compute_uv:
-        sig = ops.fused_svd(mats, bw=cfg.bw, compute_uv=False, config=cfg)
+        with obs.span("fused"):
+            sig = ops.fused_svd(mats, bw=cfg.bw, compute_uv=False, config=cfg)
         return sig.reshape(lead + (n,))
-    d, e, u2, vt2 = ops.fused_svd(mats, bw=cfg.bw, compute_uv=True,
-                                  config=cfg)
+    with obs.span("fused", compute_uv=True):
+        d, e, u2, vt2 = ops.fused_svd(mats, bw=cfg.bw, compute_uv=True,
+                                      config=cfg)
     ub, sig, vtb = _stage3_svd(d, e, cfg)
     # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
-    u = exact_matmul(u2, ub)
-    vt = exact_matmul(vtb, vt2)
+    with obs.span("compose"):
+        u = exact_matmul(u2, ub)
+        vt = exact_matmul(vtb, vt2)
     return (u.reshape(lead + (n, n)), sig.reshape(lead + (n,)),
             vt.reshape(lead + (n, n)))
 
@@ -279,34 +252,21 @@ def banded_singular_values(a: jax.Array, *, bw: int | None = None,
     of returning garbage when a chase went numerically bad.  It forces a
     host sync, so leave it off inside jit-hot loops.
 
-    ``trace=`` takes a :class:`repro.obs.Tracer` (DESIGN.md §16): stages
-    run under fenced spans with per-stage compile/run attribution.  An
-    ambient tracer (``obs.activated``/``obs.install``) traces too.
+    ``trace=`` takes a :class:`repro.obs.Tracer` (DESIGN.md §16) to record
+    this call's spans into (``config``, ``pack``, one ``stage2`` per stage
+    of the tile-width plan, ``extract``, ``stage3``, ``validate``); an
+    ambient tracer (``obs.activated``/``obs.install``) records them too.
+    Either way the call runs the same executables.
     """
-    cfg = tuning.PipelineConfig.of(config, bw=bw, tw=tw, backend=backend,
-                                   dtype=a.dtype, n=a.shape[-1])
-    tr = _resolve_tracer(trace)
-    if tr is not None:
-        with obs.activated(tr), tr.span(
-                "banded_singular_values", **_span_attrs(a, cfg)) as root:
-            if cfg.backend == "fused_small":
-                with obs.span("fused") as sp:
-                    sig = sp.fence(_fused_path(a, cfg, compute_uv=False))
-            else:
-                with obs.span("stage2", **_span_attrs(a, cfg)) as sp:
-                    d, e = bc.bidiagonalize(a, bw=cfg.bw, tw=cfg.tw,
-                                            config=cfg)
-                    sp.fence((d, e))
-                sig = _stage3_values_traced(d, e, cfg)
-            root.fence(sig)
-    elif cfg.backend == "fused_small":
-        sig = _fused_path(a, cfg, compute_uv=False)
-    else:
-        d, e = bidiagonal_of(a, config=cfg)
-        sig = _stage3_values(d, e, cfg)
-    if check:
-        validate_sigma(sig)
-    return sig
+    with _entry("banded_singular_values", trace) as root:
+        cfg = _config(a, config, bw=bw, tw=tw, backend=backend)
+        root.set(**_span_attrs(a, cfg))
+        if cfg.backend == "fused_small":
+            sig = _fused_path(a, cfg, compute_uv=False)
+        else:
+            d, e = bc.bidiagonalize(a, bw=cfg.bw, tw=cfg.tw, config=cfg)
+            sig = _stage3_values(d, e, cfg)
+        return _validated(sig) if check else sig
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -332,31 +292,20 @@ def singular_values(a: jax.Array, *, bw: int | None = None,
     descending — :func:`validate_sigma`) and raises
     :class:`NumericalFault` on violation (DESIGN.md §15).
 
-    ``trace=`` (or an ambient ``repro.obs`` tracer) records a fenced span
-    tree — stage1/stage2/stage3 children under one root, compile time
-    split out on first dispatch (DESIGN.md §16).  The traced path runs
-    the same per-stage jitted stages eagerly instead of the one fused
-    ``_three_stage`` jit, so each stage is individually attributable.
+    ``trace=`` (or an ambient ``repro.obs`` tracer) records this call's
+    host spans (DESIGN.md §16).  The three stages run as one jitted
+    executable, so they have no host spans of their own: their device time
+    is found in a profiler trace by the ``repro.stage1``/``repro.stage2``/
+    ``repro.stage3`` scopes in each op's ``op_name`` (``obs.scope``).
     """
-    cfg = tuning.PipelineConfig.of(config, bw=bw, tw=tw, backend=backend,
-                                   dtype=a.dtype, n=a.shape[-1])
-    tr = _resolve_tracer(trace)
-    if tr is not None:
-        with obs.activated(tr), tr.span(
-                "singular_values", **_span_attrs(a, cfg)) as root:
-            if cfg.backend == "fused_small":
-                with obs.span("fused") as sp:
-                    sig = sp.fence(_fused_path(a, cfg, compute_uv=False))
-            else:
-                sig = _three_stage_traced(a, cfg)
-            root.fence(sig)
-    elif cfg.backend == "fused_small":
-        sig = _fused_path(a, cfg, compute_uv=False)
-    else:
-        sig = _three_stage(a, config=cfg)
-    if check:
-        validate_sigma(sig)
-    return sig
+    with _entry("singular_values", trace) as root:
+        cfg = _config(a, config, bw=bw, tw=tw, backend=backend)
+        root.set(**_span_attrs(a, cfg))
+        if cfg.backend == "fused_small":
+            sig = _fused_path(a, cfg, compute_uv=False)
+        else:
+            sig = _three_stage(a, config=cfg)
+        return _validated(sig) if check else sig
 
 
 def batched_singular_values(mats: jax.Array, *, bw: int | None = None,
@@ -416,27 +365,20 @@ def _uv_pipeline(a: jax.Array, *, config: tuning.PipelineConfig,
         s1_tape = None
         band_in = a
     else:
-        with obs.span("stage1", **_span_attrs(a, config, tape=True)) as sp:
-            band_in, s1_tape = obs.traced_jit_call(
-                "stage1_tape", s1.band_reduce, a, nb=config.bw,
-                config=config, tape=True)
-            sp.fence((band_in, s1_tape))
-    with obs.span("stage2", **_span_attrs(a, config, tape=True)) as sp:
-        d, e, chase_tapes = bc.bidiagonalize(band_in, bw=config.bw,
-                                             tw=config.tw, config=config,
-                                             tape=True)
-        sp.fence((d, e))
-    with obs.span("replay", n=int(n)) as sp:
+        with obs.span("stage1", **_span_attrs(a, config, tape=True)):
+            band_in, s1_tape = s1.band_reduce(a, nb=config.bw, config=config,
+                                              tape=True)
+    d, e, chase_tapes = bc.bidiagonalize(band_in, bw=config.bw, tw=config.tw,
+                                         config=config, tape=True)
+    with obs.span("replay", n=int(n)):
         u2, vt2 = transforms.accumulate_transforms(
             n, s1_tape=s1_tape, chase_tapes=chase_tapes, lead=lead,
             dtype=a.dtype, config=config)
-        sp.fence((u2, vt2))
-    ub, sig, vtb = _stage3_svd_traced(d, e, config)
+    ub, sig, vtb = _stage3_svd(d, e, config)
     # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
-    with obs.span("compose") as sp:
+    with obs.span("compose"):
         u = exact_matmul(u2, ub)
         vt = exact_matmul(vtb, vt2)
-        sp.fence((u, vt))
     return u, sig, vt
 
 
@@ -447,10 +389,23 @@ def _checked_uv(a, out, *, check: bool):
     spectrum) are trustworthy."""
     if check:
         u, sig, vt = out
-        validate_sigma(sig)
-        validate_uv(u, vt)
-        spot_check_svd(a, u, sig, vt)
+        with obs.span("validate"):
+            validate_sigma(sig)
+            validate_uv(u, vt)
+            spot_check_svd(a, u, sig, vt)
     return out
+
+
+def _full_svd(name: str, a: jax.Array, *, banded: bool, bw, tw, backend,
+              config, check: bool, trace):
+    with _entry(name, trace) as root:
+        cfg = _config(a, config, bw=bw, tw=tw, backend=backend)
+        root.set(**_span_attrs(a, cfg, compute_uv=True))
+        if cfg.backend == "fused_small":
+            out = _fused_path(a, cfg, compute_uv=True)
+        else:
+            out = _uv_pipeline(a, config=cfg, banded=banded)
+        return _checked_uv(a, out, check=check)
 
 
 def svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
@@ -466,28 +421,14 @@ def svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
 
     ``check=True`` (DESIGN.md §15) validates sigma, checks U/V^T
     finiteness, and residual-spot-checks the first matrix; violations
-    raise :class:`NumericalFault`.
+    raise :class:`NumericalFault`.  ``trace=`` as in
+    :func:`banded_singular_values`.
     """
-    cfg = tuning.PipelineConfig.of(config, bw=bw, tw=tw, backend=backend,
-                                   dtype=a.dtype, n=a.shape[-1])
     if not compute_uv:
-        return singular_values(a, config=cfg, check=check, trace=trace)
-    tr = _resolve_tracer(trace)
-    if tr is not None:
-        with obs.activated(tr), tr.span(
-                "svd", **_span_attrs(a, cfg, compute_uv=True)) as root:
-            if cfg.backend == "fused_small":
-                with obs.span("fused") as sp:
-                    out = sp.fence(_fused_path(a, cfg, compute_uv=True))
-            else:
-                out = _uv_pipeline(a, config=cfg, banded=False)
-            root.fence(out)
-        return _checked_uv(a, out, check=check)
-    if cfg.backend == "fused_small":
-        return _checked_uv(a, _fused_path(a, cfg, compute_uv=True),
-                           check=check)
-    return _checked_uv(a, _uv_pipeline(a, config=cfg, banded=False),
-                       check=check)
+        return singular_values(a, bw=bw, tw=tw, backend=backend,
+                               config=config, check=check, trace=trace)
+    return _full_svd("svd", a, banded=False, bw=bw, tw=tw, backend=backend,
+                     config=config, check=check, trace=trace)
 
 
 def banded_svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
@@ -495,25 +436,11 @@ def banded_svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
                config: tuning.PipelineConfig | None = None,
                compute_uv: bool = True, check: bool = False, trace=None):
     """Full SVD of upper-banded (..., n, n) (stages 2+3 only); ``check=``
-    as in :func:`svd`, ``trace=`` as in :func:`singular_values`."""
-    cfg = tuning.PipelineConfig.of(config, bw=bw, tw=tw, backend=backend,
-                                   dtype=a.dtype, n=a.shape[-1])
+    as in :func:`svd`, ``trace=`` as in :func:`banded_singular_values`."""
     if not compute_uv:
-        return banded_singular_values(a, config=cfg, check=check,
+        return banded_singular_values(a, bw=bw, tw=tw, backend=backend,
+                                      config=config, check=check,
                                       trace=trace)
-    tr = _resolve_tracer(trace)
-    if tr is not None:
-        with obs.activated(tr), tr.span(
-                "banded_svd", **_span_attrs(a, cfg, compute_uv=True)) as root:
-            if cfg.backend == "fused_small":
-                with obs.span("fused") as sp:
-                    out = sp.fence(_fused_path(a, cfg, compute_uv=True))
-            else:
-                out = _uv_pipeline(a, config=cfg, banded=True)
-            root.fence(out)
-        return _checked_uv(a, out, check=check)
-    if cfg.backend == "fused_small":
-        return _checked_uv(a, _fused_path(a, cfg, compute_uv=True),
-                           check=check)
-    return _checked_uv(a, _uv_pipeline(a, config=cfg, banded=True),
-                       check=check)
+    return _full_svd("banded_svd", a, banded=True, bw=bw, tw=tw,
+                     backend=backend, config=config, check=check,
+                     trace=trace)

@@ -64,6 +64,7 @@ def _acc_dtype(dt):
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
+@obs.scope("replay")
 def replay_stage1(ut: jax.Array, vt: jax.Array, tape, *, config=None):
     """Replay the stage-1 panel tape into the transposed accumulators.
 
@@ -91,6 +92,7 @@ def replay_stage1(ut: jax.Array, vt: jax.Array, tape, *, config=None):
 
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "config",
                                              "fuse"))
+@obs.scope("replay")
 def replay_chase(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
                  tape_tau: jax.Array, *, n: int, b_in: int, tw: int,
                  config=None, fuse: int = 1):
@@ -168,19 +170,15 @@ def accumulate_transforms(n: int, *, s1_tape=None, chase_tapes=(),
     if s1_tape is not None:
         flat = tuple(x.reshape((b,) + x.shape[len(lead):]).astype(acc)
                      for x in s1_tape)
-        with obs.span("replay_stage1", n=int(n), batch=b) as sp:
-            ut, vt = obs.traced_jit_call("replay_stage1", replay_stage1,
-                                         ut, vt, flat, config=config)
-            sp.fence((ut, vt))
+        with obs.span("replay_stage1", n=int(n), batch=b):
+            ut, vt = replay_stage1(ut, vt, flat, config=config)
     for tape in chase_tapes:
         tv = tape.v.reshape((b,) + tape.v.shape[len(lead):]).astype(acc)
         tt = tape.tau.reshape((b,) + tape.tau.shape[len(lead):]).astype(acc)
         with obs.span("replay_chase", n=tape.n, b_in=tape.b_in, tw=tape.tw,
-                      fuse=tape.fuse) as sp:
-            ut, vt = obs.traced_jit_call(
-                "replay_chase", replay_chase, ut, vt, tv, tt, n=tape.n,
-                b_in=tape.b_in, tw=tape.tw, config=config, fuse=tape.fuse)
-            sp.fence((ut, vt))
+                      fuse=tape.fuse):
+            ut, vt = replay_chase(ut, vt, tv, tt, n=tape.n, b_in=tape.b_in,
+                                  tw=tape.tw, config=config, fuse=tape.fuse)
     u = jnp.swapaxes(ut, -1, -2)
     out_dt = jnp.dtype(dtype)
     return (u.reshape(lead + (n, n)).astype(out_dt),

@@ -179,6 +179,7 @@ def chase_cycle_pallas(windows: jax.Array, is_first: jax.Array, *, b_in: int,
             out_specs=tuple(out_specs)),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="chase_cycle",
     )(first, windows)
     if with_tape:
         out, vs, taus = res
@@ -293,6 +294,7 @@ def chase_superstep_pallas(blocks: jax.Array, is_first: jax.Array,
             scratch_shapes=[pltpu.VMEM((ell, ell), dt),
                             pltpu.VMEM((h, b_in + tw + 1), dt)]),
         interpret=interpret,
+        name="chase_superstep",
     )(first, act, revt)
     # inverse shear: block[H-1-r, c] = sheared[c, r + c]
     cc = jnp.arange(wk)[None, :]

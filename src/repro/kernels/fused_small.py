@@ -331,6 +331,7 @@ def fused_small_svd_pallas(mats, *, bw, compute_uv=False, interpret=False,
             in_specs=[mat_spec],
             out_specs=(row_spec, row_spec, mat_spec, mat_spec),
             interpret=interpret,
+            name="fused_small_uv",
         )(mats)
         return d[:, 0], e[:, 0], u2, vt2
     kern = functools.partial(_values_kernel, bw=bw_eff, max_iter=max_iter)
@@ -342,5 +343,6 @@ def fused_small_svd_pallas(mats, *, bw, compute_uv=False, interpret=False,
         in_specs=[mat_spec],
         out_specs=pl.BlockSpec((None,) + shape[1:], one),
         interpret=interpret,
+        name="fused_small_values",
     )(mats)
     return sig.reshape(b, n)

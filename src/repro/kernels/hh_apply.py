@@ -96,5 +96,6 @@ def tape_apply_pallas(v: jax.Array, t: jax.Array, c: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((None, m, bc), lambda i, j: (i, _I0, j)),
         interpret=interpret,
+        name="tape_apply",
     )(v, t, cp)
     return out[..., :w] if pad else out

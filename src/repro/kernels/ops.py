@@ -23,6 +23,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref as _ref
 
 __all__ = ["chase_cycle", "hh_block_apply", "tape_apply", "fused_svd",
@@ -259,6 +260,7 @@ def hh_block_apply(v: jax.Array, t: jax.Array, c: jax.Array, *,
 
 @functools.partial(jax.jit, static_argnames=("bw", "compute_uv", "backend",
                                              "interpret", "config"))
+@obs.scope("fused")
 def fused_svd(mats: jax.Array, *, bw: int, compute_uv: bool = False,
               backend: str = "auto", interpret: bool | None = None,
               config=None):

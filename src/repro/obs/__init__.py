@@ -2,29 +2,34 @@
 
 Three pieces, importable from this package root:
 
-* :class:`Tracer` / :class:`Span` — thread-safe structured span tracing
-  with ``block_until_ready`` fencing and compile-vs-run attribution
-  (``trace.py``).  Ambient-tracer helpers: :func:`current`,
-  :func:`activated`, :func:`install`, :func:`span`,
-  :func:`traced_jit_call`.
+* :func:`span` / :class:`Span` / :class:`Tracer` — always-on program spans
+  that land on the profiler's clock as ``repro/<name>`` annotations, are
+  recorded into a tracer only when one is active, and never change what
+  runs; plus the program's own compile counter, :func:`compile_counts`
+  (``trace.py``); :func:`scope` names a stage's jitted body
+  ``repro.<stage>`` for the device trace.  Ambient-tracer helpers: :func:`current`,
+  :func:`activated`, :func:`install`.
 * :class:`StreamingHistogram` — mergeable fixed-log-bucket latency
   histograms with bounded memory (``hist.py``).
-* :class:`MetricsServer` / :func:`render_serve_metrics` — Prometheus
-  text exposition over stdlib http.server (``prom.py``); JSONL span
-  export/round-trip in ``export.py``.
+* :class:`MetricsServer` / :func:`render_serve_metrics` /
+  :func:`render_compile_metrics` — Prometheus text exposition over stdlib
+  http.server (``prom.py``); JSONL span export/round-trip in
+  ``export.py``.
 """
 
 from .export import JsonlExporter, SpanRecord, load_jsonl
 from .hist import StreamingHistogram
-from .prom import MetricsServer, render_fleet_metrics, render_serve_metrics
+from .prom import (MetricsServer, render_compile_metrics,
+                   render_fleet_metrics, render_serve_metrics)
 from .trace import (
     Span,
     Tracer,
     activated,
+    compile_counts,
     current,
     install,
+    scope,
     span,
-    traced_jit_call,
 )
 
 __all__ = [
@@ -33,13 +38,15 @@ __all__ = [
     "load_jsonl",
     "StreamingHistogram",
     "MetricsServer",
+    "render_compile_metrics",
     "render_fleet_metrics",
     "render_serve_metrics",
     "Span",
     "Tracer",
     "activated",
+    "compile_counts",
     "current",
     "install",
+    "scope",
     "span",
-    "traced_jit_call",
 ]

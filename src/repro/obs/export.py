@@ -43,6 +43,8 @@ class JsonlExporter:
             "t0": sp.t0,
             "dur_s": sp.dur_s,
             "thread": sp.thread,
+            "compiles": sp.compiles,
+            "cache_loads": sp.cache_loads,
             "attrs": {k: _jsonable(v) for k, v in sp.attrs.items()},
         }
         line = json.dumps(rec, separators=(",", ":"))
@@ -62,7 +64,7 @@ class SpanRecord:
     """A span rebuilt from JSONL: same tree-shape API as a live Span."""
 
     __slots__ = ("span_id", "parent_id", "name", "t0", "dur_s", "thread",
-                 "attrs", "children")
+                 "compiles", "cache_loads", "attrs", "children")
 
     def __init__(self, rec: dict) -> None:
         self.span_id = rec["span_id"]
@@ -71,6 +73,8 @@ class SpanRecord:
         self.t0 = rec["t0"]
         self.dur_s = rec["dur_s"]
         self.thread = rec.get("thread")
+        self.compiles = rec.get("compiles", 0)
+        self.cache_loads = rec.get("cache_loads", 0)
         self.attrs = dict(rec.get("attrs", {}))
         self.children: list[SpanRecord] = []
 

@@ -7,8 +7,11 @@ and trivial to emit — ``# HELP`` / ``# TYPE`` comments, then
 turns one :class:`~repro.serve.metrics.ServeMetrics` into exposition
 text (counters, gauges, per-tier dispatch slices, and the per-tier /
 per-bucket latency histograms as cumulative ``_bucket{le=...}`` series);
-:class:`MetricsServer` serves any number of registered metrics objects
-at ``GET /metrics`` from a daemon thread — opt-in via
+:func:`render_compile_metrics` renders the program's compile counter
+(``repro_compiles_total{span=...}``, ``obs.trace``);
+:class:`MetricsServer` serves any number of registered metrics objects,
+and the compile counter once, at ``GET /metrics`` from a daemon thread —
+opt-in via
 ``launch/serve.py --svd --metrics-port`` or
 ``benchmarks.serve_load --metrics-port``.
 """
@@ -19,8 +22,8 @@ import math
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-__all__ = ["render_serve_metrics", "render_fleet_metrics", "MetricsServer",
-           "escape_label"]
+__all__ = ["render_serve_metrics", "render_fleet_metrics",
+           "render_compile_metrics", "MetricsServer", "escape_label"]
 
 _PREFIX = "repro_serve"
 _FLEET = "repro_fleet"
@@ -175,13 +178,35 @@ def render_fleet_metrics(fleet: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
+def render_compile_metrics() -> str:
+    """Exposition text for the program's compile counter: executables
+    compiled or loaded from the persistent cache, and the loads among them,
+    by the innermost program span open when each happened."""
+    from repro.obs.trace import compile_counts
+
+    counts = compile_counts()
+    lines: list[str] = []
+    for metric, key, help_ in (
+            ("repro_compiles_total", "compiles",
+             "Executables compiled or loaded from the persistent cache, "
+             "by innermost program span."),
+            ("repro_compile_cache_loads_total", "cache_loads",
+             "Executables loaded from the persistent compilation cache, "
+             "by innermost program span.")):
+        lines.append(f"# HELP {metric} {help_}")
+        lines.append(f"# TYPE {metric} counter")
+        for span, n in sorted(counts[key].items()):
+            lines.append(_sample(metric, {"span": span}, int(n)))
+    return "\n".join(lines) + "\n"
+
+
 class MetricsServer:
     """Tiny /metrics endpoint on stdlib ``ThreadingHTTPServer``.
 
     ``port=0`` binds an ephemeral port (read back via ``.port`` — used by
     tests and the CI smoke, which scrape in-process).  ``register`` any
     number of (engine_name, ServeMetrics) pairs; every scrape re-renders
-    from live metrics.  The server thread is a daemon: it never blocks
+    from live metrics and ends with the process's compile counter.  The server thread is a daemon: it never blocks
     interpreter exit, but call :meth:`stop` for deterministic shutdown.
     """
 
@@ -210,6 +235,7 @@ class MetricsServer:
                                  f"{escape_label(exc)}\n")  # provider must
                 if not items and not provs:      # not kill the scrape
                     body = "# no metrics registered\n"
+                body += render_compile_metrics()
                 data = body.encode("utf-8")
                 self.send_response(200)
                 self.send_header("Content-Type",

@@ -1,48 +1,60 @@
-"""Structured span tracer for the SVD pipeline (DESIGN.md §16).
+"""Program spans for the SVD pipeline (DESIGN.md §16).
 
-A :class:`Span` is a named, attributed interval on the host monotonic
-clock (``time.perf_counter``).  Spans nest per-thread (a thread-local
-stack), carry arbitrary key/value attributes (``n``, ``bw``, ``dtype``,
-``fuse``, ``backend``, ``tier``, ...), and — critically for an async
-device runtime — **fence** at close: any JAX arrays registered on the
-span are ``block_until_ready``'d before the closing timestamp is taken,
-so device work launched inside the span is actually attributed to it
-instead of leaking into whichever span happens to call ``np.asarray``
-first.
+A span marks one layer boundary of the program on the host: an entry
+point, the configuration, packing, one stage, validation, a serve
+dispatch.  Spans are always on and path-neutral: opening one never changes
+what runs, only what is recorded about it.
 
-Two integration rules keep the tracer zero-cost and jit-safe:
+* Every span opens a ``jax.profiler.TraceAnnotation`` named
+  ``repro/<name>``.  A profiler capture puts these on its own clock, the
+  clock of the device planes, so every device gap lines up with the span
+  the host was in.  Attributes go into the annotation only while a capture
+  is running; otherwise the annotation costs one native call.
+* A thread-local stack of the open spans is kept always (a list push and
+  pop).  The compile counter below reads its top.
+* A span is recorded into a :class:`Tracer` only when one is active: passed
+  as ``trace=`` to a ``core.svd`` entry point, given to a serve engine as
+  ``tracer=``, activated with :func:`activated` or installed process-wide
+  with :func:`install`.  A recorded span's duration is host time; device
+  time comes from the profiler trace, by the ``repro.*`` named scopes that
+  each stage's jitted body opens.
+* Inside jit tracing a span is a shared no-op object: the host times of
+  symbolic values mean nothing, and the named scopes carry the stage names
+  into each op's HLO ``op_name`` instead.
 
-* **No ambient tracer → no-op.**  Instrumented code calls
-  :func:`repro.obs.span`, which returns a singleton null context when no
-  tracer is active.  Production paths pay one dict lookup.
-* **Inside jit tracing → no-op.**  Host spans make no sense while JAX is
-  abstractly tracing a function (the "times" would be trace times of
-  symbolic values).  :func:`span` checks ``jax.core.trace_ctx.is_top_level()``
-  and degrades to the null span under tracing; device-side attribution
-  inside jitted code uses ``jax.named_scope`` instead (§16).
+Stage scopes: :func:`scope` traces a stage's jitted body as a nested
+jitted call named ``repro.<stage>`` (``stage1``, ``stage2``, ``stage3``,
+``replay``, ``fused``).  The name lands in the ``op_name`` metadata of
+every op of the stage (``.../jit(repro.stage3)/...``), whether the stage
+runs as its own executable or is inlined into a larger one, which is how
+a profiler trace's device time is split by stage.  A ``jax.named_scope``
+would put the same name into ``op_name`` only: JAX's persistent
+compilation cache hashes the module without its debug information, so a
+build without the scopes and one with them share cache entries, and a
+cache hit returns an executable whose ops carry no stage name.  The nested
+call adds a ``repro.<stage>`` function to the module itself, which the
+cache key sees; XLA inlines it, so the compiled code is unchanged.
 
-Compile-vs-run attribution: JAX hides compilation inside the first call
-of a jitted function.  :meth:`Tracer.jit_call` splits it — the first
-dispatch per (name, static args, input avals) lowers and compiles under
-an explicit ``<name>/compile`` child span, then executes the compiled
-object under ``<name>/run``.  The compiled executable is memoized on the
-tracer because (measured on jax 0.4.37) the AOT ``lower().compile()``
-path does NOT populate the regular jit call cache — without the memo a
-traced run would compile everything twice.
-
-Each span also opens a ``jax.profiler.TraceAnnotation`` for its
-duration, so host spans line up with device profiler traces when a
-``jax.profiler.trace`` capture is active (DESIGN.md §16).
+Compile counter: one ``jax.monitoring`` listener, registered when this
+module is imported, counts JAX's ``backend_compile_duration`` events (one
+per executable compiled or loaded from the persistent cache) and its
+``cache_retrieval_time_sec`` events (the loads among them) against the
+innermost span open on the compiling thread.  The counts are on each
+recorded span (``compiles``, ``cache_loads``), in its JSONL export, and
+process-wide in :func:`compile_counts`, which ``obs.prom`` renders as
+``repro_compiles_total{span=...}``.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import functools
 import itertools
 import threading
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
 
@@ -53,10 +65,28 @@ __all__ = [
     "activated",
     "install",
     "span",
-    "traced_jit_call",
+    "compile_counts",
+    "scope",
 ]
 
+PREFIX = "repro/"            # profiler annotation name = PREFIX + span name
+SCOPE_PREFIX = "repro."      # named scope of a stage's jitted body
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+NO_SPAN = "none"             # compile counter key outside every span
+
 _ids = itertools.count(1)
+_local = threading.local()
+_capturing = jax.profiler.TraceAnnotation.is_enabled   # a capture is running
+
+
+def _stack() -> list:
+    """The calling thread's open spans, innermost last."""
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
 
 
 def _host_clean() -> bool:
@@ -65,17 +95,16 @@ def _host_clean() -> bool:
 
 
 class Span:
-    """One timed interval.  Use via ``tracer.span(...)`` as a context
-    manager; closing fences registered device values, records duration,
-    tags errors, and attaches the span to its parent (or the tracer's
-    root list)."""
+    """One program span.  Use as a context manager (from :func:`span` or
+    :meth:`Tracer.span`); closing it records its host duration, tags an
+    error, and hands it to its tracer, if it has one."""
 
     __slots__ = ("name", "attrs", "children", "span_id", "parent_id",
-                 "thread", "t0", "dur_s", "_tracer", "_fence",
-                 "_annotation")
+                 "thread", "t0", "dur_s", "compiles", "cache_loads",
+                 "_tracer", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str,
-                 attrs: dict[str, Any]) -> None:
+    def __init__(self, name: str, attrs: dict[str, Any],
+                 tracer: Optional["Tracer"] = None) -> None:
         self.name = name
         self.attrs = attrs
         self.children: list[Span] = []
@@ -84,56 +113,43 @@ class Span:
         self.thread = threading.get_ident()
         self.t0 = 0.0
         self.dur_s = 0.0
+        self.compiles = 0
+        self.cache_loads = 0
         self._tracer = tracer
-        self._fence: list[Any] = []
         self._annotation = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach/overwrite attributes mid-span."""
         self.attrs.update(attrs)
+        if self._annotation is not None and _capturing():
+            self._annotation.set_metadata(**attrs)
         return self
 
-    def fence(self, value: Any) -> Any:
-        """Register a (pytree of) JAX array(s) to block on at span close,
-        so its device work is attributed to THIS span.  Returns value."""
-        if value is not None:
-            self._fence.append(value)
-        return value
-
     def __enter__(self) -> "Span":
-        stack = self._tracer._stack()
-        if stack:
-            self.parent_id = stack[-1].span_id
-        stack.append(self)
-        try:
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
-            self._annotation.__enter__()
-        except Exception:
-            self._annotation = None
+        _stack().append(self)
+        name = PREFIX + self.name
+        self._annotation = (jax.profiler.TraceAnnotation(name, **self.attrs)
+                            if self.attrs and _capturing()
+                            else jax.profiler.TraceAnnotation(name))
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        try:
-            if self._fence and exc_type is None:
-                jax.block_until_ready(self._fence)
-        except Exception:
-            pass
         self.dur_s = time.perf_counter() - self.t0
-        if self._annotation is not None:
-            try:
-                self._annotation.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
-        if exc_type is not None:
-            self.attrs["error"] = repr(exc)
-        stack = self._tracer._stack()
+        self._annotation.__exit__(exc_type, exc, tb)
+        stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
         elif self in stack:          # defensive: unwind mis-nested exits
             stack.remove(self)
-        parent = stack[-1] if stack else None
-        self._tracer._record(self, parent)
+        tracer = self._tracer
+        if tracer is not None:
+            if exc_type is not None:
+                self.attrs["error"] = repr(exc)
+            parent = next((s for s in reversed(stack)
+                           if s._tracer is tracer), None)
+            tracer._record(self, parent)
         return False                 # never swallow exceptions
 
     # ------------------------------------------------------------------
@@ -156,15 +172,19 @@ class Span:
             "t0": self.t0,
             "dur_s": self.dur_s,
             "thread": self.thread,
+            "compiles": self.compiles,
+            "cache_loads": self.cache_loads,
             "attrs": dict(self.attrs),
             "children": [c.to_dict() for c in self.children],
         }
 
     def format(self, indent: int = 0, *, min_ms: float = 0.0) -> str:
-        """Human-readable tree: name, duration, attrs — one line per span."""
+        """Human-readable tree: name, duration, compiles, attrs."""
         pad = "  " * indent
         attrs = " ".join(f"{k}={v}" for k, v in self.attrs.items())
         line = f"{pad}{self.name:<24s} {self.dur_s * 1e3:9.3f} ms"
+        if self.compiles:
+            line += f"  compiles={self.compiles}"
         if attrs:
             line += f"  [{attrs}]"
         lines = [line]
@@ -175,9 +195,7 @@ class Span:
 
 
 class _NullSpan:
-    """Shared no-op span: returned when no tracer is active or jax is
-    tracing.  Every method is a cheap no-op so instrumented code never
-    branches on tracer presence."""
+    """Shared no-op span, returned while jax is tracing."""
 
     __slots__ = ()
 
@@ -190,9 +208,6 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
-    def fence(self, value):
-        return value
-
 
 _NULL_SPAN = _NullSpan()
 
@@ -201,34 +216,24 @@ class Tracer:
     """Collects span trees (thread-safe) and optionally streams each
     closed span as one JSONL line.
 
-    ``tracer.roots`` holds completed top-level spans (one tree per
-    traced entry-point call, plus one per spans opened on threads with
-    an empty stack — e.g. serve dispatcher threads).
+    ``tracer.roots`` holds completed top-level spans: one tree per traced
+    entry-point call, plus one per span opened with no recorded span of
+    this tracer open on its thread (a serve dispatcher thread, say).
     """
 
     def __init__(self, name: str = "trace",
                  jsonl: Optional[str] = None) -> None:
         self.name = name
         self.roots: list[Span] = []
-        self._local = threading.local()
         self._lock = threading.Lock()
-        self._compiled: dict[Any, Any] = {}   # AOT executable memo
-        self._jsonl_path = jsonl
         self._jsonl_file = None
         if jsonl is not None:
             from .export import JsonlExporter
             self._jsonl_file = JsonlExporter(jsonl)
 
-    # ------------------------------------------------------------------
-
-    def _stack(self) -> list[Span]:
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = self._local.stack = []
-        return stack
-
     def _record(self, sp: Span, parent: Optional[Span]) -> None:
         if parent is not None:
+            sp.parent_id = parent.span_id
             parent.children.append(sp)
         else:
             with self._lock:
@@ -237,60 +242,10 @@ class Tracer:
             self._jsonl_file.write_span(sp)
 
     def span(self, name: str, **attrs: Any):
-        """Open a child span of the current thread's innermost span (or a
-        new root).  Returns the no-op span while jax is tracing."""
+        """A span recorded into this tracer, whatever the ambient one."""
         if not _host_clean():
             return _NULL_SPAN
-        return Span(self, name, attrs)
-
-    # ------------------------------------------------------------------
-    # compile-vs-run attribution
-
-    @staticmethod
-    def _aval_key(x: Any):
-        if hasattr(x, "shape") and hasattr(x, "dtype"):
-            return ("aval", tuple(x.shape), str(x.dtype))
-        if isinstance(x, (tuple, list)):
-            return ("seq", tuple(Tracer._aval_key(v) for v in x))
-        return ("lit", x)
-
-    def jit_call(self, name: str, fn: Callable, *args: Any,
-                 **static_kwargs: Any) -> Any:
-        """Call a jitted ``fn(*args, **static_kwargs)`` with compile/run
-        split.  First dispatch per (name, statics, arg avals) lowers and
-        compiles under a ``<name>/compile`` child span and memoizes the
-        executable (jax's AOT cache is separate from the call cache);
-        later dispatches run the memoized executable directly.  Falls
-        back to a plain call when ``fn`` has no AOT path.
-        """
-        if not _host_clean():
-            return fn(*args, **static_kwargs)
-        try:
-            key = (name, tuple(sorted(static_kwargs.items(), key=str)),
-                   tuple(self._aval_key(a) for a in args))
-            hash(key)
-        except TypeError:
-            return fn(*args, **static_kwargs)
-        compiled = self._compiled.get(key)
-        if compiled is None:
-            lower = getattr(fn, "lower", None)
-            if lower is None:
-                # Not a jit entry point — run plainly, mark the parent.
-                stack = self._stack()
-                if stack:
-                    stack[-1].set(compile="unsplit")
-                return fn(*args, **static_kwargs)
-            try:
-                with self.span(f"{name}/compile"):
-                    compiled = lower(*args, **static_kwargs).compile()
-            except Exception:
-                return fn(*args, **static_kwargs)
-            self._compiled[key] = compiled
-            with self.span(f"{name}/run") as sp:
-                return sp.fence(compiled(*args))
-        return compiled(*args)
-
-    # ------------------------------------------------------------------
+        return Span(name, attrs, self)
 
     def format(self, *, min_ms: float = 0.0) -> str:
         with self._lock:
@@ -340,19 +295,61 @@ def install(tracer: Optional[Tracer]) -> Optional[Tracer]:
 
 
 def span(name: str, **attrs: Any):
-    """Module-level convenience: a span on the ambient tracer, or the
-    shared no-op span when none is active (or jax is tracing)."""
-    tr = current()
-    if tr is None:
+    """A program span ``repro/<name>``, recorded into the ambient tracer
+    when one is active; the shared no-op span while jax is tracing."""
+    if not _host_clean():
         return _NULL_SPAN
-    return tr.span(name, **attrs)
+    return Span(name, attrs, current())
 
 
-def traced_jit_call(name: str, fn: Callable, *args: Any,
-                    **static_kwargs: Any) -> Any:
-    """Module-level convenience: compile/run-split call on the ambient
-    tracer, or a plain call when none is active."""
-    tr = current()
-    if tr is None:
-        return fn(*args, **static_kwargs)
-    return tr.jit_call(name, fn, *args, **static_kwargs)
+def scope(stage: str):
+    """Decorator: trace the function's body as a nested jitted call named
+    ``repro.<stage>``.  Put it under ``jax.jit`` (whose static arguments are
+    then bound here as Python values); positional arguments are the
+    stage's arrays."""
+    name = SCOPE_PREFIX + stage
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            body = functools.partial(fn, **kwargs)
+            body.__name__ = name
+            return jax.jit(body)(*args)
+        return scoped
+    return wrap
+
+
+# ----------------------------------------------------------------------
+# compile counter
+
+_compile_lock = threading.Lock()
+_compiles: collections.Counter = collections.Counter()
+_cache_loads: collections.Counter = collections.Counter()
+
+
+def _on_duration_event(event: str, _secs: float, **_kw) -> None:
+    if event == COMPILE_EVENT:
+        field, counter = "compiles", _compiles
+    elif event == CACHE_LOAD_EVENT:
+        field, counter = "cache_loads", _cache_loads
+    else:
+        return
+    stack = _stack()
+    if stack:
+        top = stack[-1]
+        setattr(top, field, getattr(top, field) + 1)
+    with _compile_lock:
+        counter[stack[-1].name if stack else NO_SPAN] += 1
+
+
+def compile_counts() -> dict[str, dict[str, int]]:
+    """Process-wide counts by innermost span name (``"none"`` outside every
+    span): ``{"compiles": {...}, "cache_loads": {...}}``, where a compile
+    is an executable the backend compiled or loaded from the persistent
+    cache, and a cache load is one of the latter."""
+    with _compile_lock:
+        return {"compiles": dict(_compiles),
+                "cache_loads": dict(_cache_loads)}
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)

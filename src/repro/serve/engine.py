@@ -303,16 +303,12 @@ class SVDEngine:
         self._cfg_memo: dict[tuple, object] = {}  # bucket key -> resolved cfg
         self._degraded_memo: dict[tuple, object] = {}  # key -> ref-tier cfg
 
-    def _resolve_tracer(self):
-        return self.tracer if self.tracer is not None else obs.current()
-
     def _span(self, name: str, **attrs):
-        """A span on the engine's tracer (explicit or ambient) — the
-        shared no-op span when neither exists (DESIGN.md §16)."""
-        tr = self._resolve_tracer()
-        if tr is None:
-            return obs.span(name, **attrs)       # -> null span
-        return tr.span(name, **attrs)
+        """A program span, recorded into the engine's tracer if it has one,
+        else into the ambient tracer, if any (DESIGN.md §16)."""
+        if self.tracer is None:
+            return obs.span(name, **attrs)
+        return self.tracer.span(name, **attrs)
 
     def submit(self, req: SVDRequest) -> None:
         assert req.matrix.ndim == 2 and req.matrix.shape[0] == req.matrix.shape[1]
@@ -515,15 +511,15 @@ class SVDEngine:
         (primary path only — degraded dispatches pass ``inject=False``),
         the plan may delay/raise before dispatch and corrupt the sigma
         block after it.  Every result — injected or not — then passes the
-        numerical-health guard, raising ``NumericalFault`` on garbage."""
-        tr = self._resolve_tracer()
-        if tr is None:
-            return self._pipeline_call_inner(key, cfg, mats, tier=tier,
-                                             inject=inject)
-        # Dispatch span (DESIGN.md §16): activating the tracer lets the
-        # pipeline's own stage spans nest under this one — the engine
-        # needs no per-call trace= plumbing into core.
-        with obs.activated(tr), tr.span(
+        numerical-health guard, raising ``NumericalFault`` on garbage.
+
+        Spans (DESIGN.md §16): ``serve/dispatch`` with the children
+        ``serve/pad``, ``serve/pipeline`` (under which the pipeline's own
+        spans nest), ``serve/copy_back`` and ``serve/validate``.  The
+        engine's ``tracer``, if any, is activated for the call, so the
+        pipeline's spans are recorded into it too; it changes nothing
+        that runs."""
+        with obs.activated(self.tracer), obs.span(
                 "serve/dispatch", bucket=bucket_key_str(key),
                 tier=tier or self._tier_of(cfg, key[0]), n=key[0],
                 batch=len(mats), backend=cfg.backend, inject=inject):
@@ -531,40 +527,44 @@ class SVDEngine:
                                              inject=inject)
 
     def _pipeline_call_inner(self, key: tuple, cfg, mats: list[np.ndarray],
-                             *, tier: str | None = None, inject: bool = True):
+                             *, tier: str | None, inject: bool):
         from repro.core import svd as svdmod
         n, _bw, dtype, banded, compute_uv = key
         faults = self.faults if inject else None
         if faults is not None:
             faults.before_dispatch(key)          # may sleep and/or raise
-        batch = np.zeros((cfg.max_batch, n, n), dtype)       # pad: zero matrices
-        for i, m in enumerate(mats):
-            batch[i] = m
-        stacked = jnp.asarray(batch)
+        with obs.span("serve/pad"):
+            batch = np.zeros((cfg.max_batch, n, n), dtype)   # pad: zero matrices
+            for i, m in enumerate(mats):
+                batch[i] = m
+            stacked = jnp.asarray(batch)
         if stacked.dtype != np.dtype(dtype):
             # jax_enable_x64 is off: fp64 requests are silently downcast by
             # jnp.asarray — serve at the effective precision instead of
             # tripping the config/input dtype-conflict check.
             cfg = dataclasses.replace(cfg, dtype=jnp.dtype(stacked.dtype).name)
         u = vt = None
-        if self.mesh is not None:
-            from repro.core import distributed
-            out = distributed.sharded_pipeline_dispatch(
-                stacked, self.mesh, config=cfg, banded=banded,
-                compute_uv=compute_uv, faults=faults,
-                on_shard_retry=lambda k_: self.metrics.add(sharded_retries=k_))
-            if compute_uv:
-                u, sig, vt = out
+        with obs.span("serve/pipeline"):
+            if self.mesh is not None:
+                from repro.core import distributed
+                out = distributed.sharded_pipeline_dispatch(
+                    stacked, self.mesh, config=cfg, banded=banded,
+                    compute_uv=compute_uv, faults=faults,
+                    on_shard_retry=lambda k_: self.metrics.add(
+                        sharded_retries=k_))
+                if compute_uv:
+                    u, sig, vt = out
+                else:
+                    sig = out
+                self.metrics.add(sharded_batches=1)
+            elif compute_uv:
+                fn = svdmod.banded_svd if banded else svdmod.svd
+                u, sig, vt = fn(stacked, config=cfg, compute_uv=True)
+            elif banded:
+                sig = svdmod.banded_singular_values(stacked, bw=cfg.bw,
+                                                    config=cfg)
             else:
-                sig = out
-            self.metrics.add(sharded_batches=1)
-        elif compute_uv:
-            fn = svdmod.banded_svd if banded else svdmod.svd
-            u, sig, vt = fn(stacked, config=cfg, compute_uv=True)
-        elif banded:
-            sig = svdmod.banded_singular_values(stacked, bw=cfg.bw, config=cfg)
-        else:
-            sig = svdmod.svd_batched(stacked, config=cfg)
+                sig = svdmod.svd_batched(stacked, config=cfg)
         self.calls += 1
         self.metrics.add(batches=1, served_slots=len(mats),
                          padded_slots=cfg.max_batch - len(mats))
@@ -572,18 +572,20 @@ class SVDEngine:
             tier or self._tier_of(cfg, n), batches=1, served_slots=len(mats),
             padded_slots=cfg.max_batch - len(mats))
         k = len(mats)
-        sig = np.asarray(sig)[:k]
-        if compute_uv:
-            u, vt = np.asarray(u)[:k], np.asarray(vt)[:k]
+        with obs.span("serve/copy_back"):
+            sig = np.asarray(sig)[:k]
+            if compute_uv:
+                u, vt = np.asarray(u)[:k], np.asarray(vt)[:k]
         if faults is not None:
             sig = faults.corrupt_sigma(sig)
         # Numerical-health guard (§15): a NaN/Inf/garbage sigma must raise
         # NumericalFault here — never reach a caller as a silent answer.
-        svdmod.validate_sigma(sig)
-        if compute_uv:
-            svdmod.validate_uv(u, vt)
-            if self.residual_check:
-                svdmod.spot_check_svd(batch[:k], u, sig, vt)
+        with obs.span("serve/validate"):
+            svdmod.validate_sigma(sig)
+            if compute_uv:
+                svdmod.validate_uv(u, vt)
+                if self.residual_check:
+                    svdmod.spot_check_svd(batch[:k], u, sig, vt)
         return sig, u, vt
 
     # ------------------------------------------------------------------

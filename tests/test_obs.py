@@ -1,9 +1,13 @@
-"""Observability layer tests (DESIGN.md §16): span tracer semantics,
-streaming histogram fidelity/merge/serialization, JSONL trace round-trip,
-the Prometheus exposition endpoint, compile-vs-run attribution, the
-acceptance-bar span coverage of one traced ``svd_batched`` call, and the
-bounded-memory property of the serve-tier latency histograms."""
+"""Observability layer tests (DESIGN.md §16): span semantics, the
+profiler annotations every span opens with or without a tracer, the
+``repro.*`` stage scopes in the compiled HLO of every entry point, the
+program's compile counter, streaming histogram fidelity/merge/
+serialization, JSONL trace round-trip, the Prometheus exposition endpoint,
+that a tracer changes neither what compiles nor what is returned, the
+serve dispatch's span tree, and the bounded-memory property of the
+serve-tier latency histograms."""
 
+import glob
 import json
 import re
 import threading
@@ -226,39 +230,30 @@ def test_jsonl_exporter_threaded(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# compile-vs-run attribution
+# compile counter
 # ---------------------------------------------------------------------------
 
-def test_jit_call_compile_split_on_fresh_jit():
-    calls = {"n": 0}
-
-    @jax.jit
-    def f(x):
-        calls["n"] += 1                  # python body runs only on compile
-        return (x * x).sum()
-
-    tr = Tracer("t")
-    x = jnp.arange(8, dtype=jnp.float32)
-    with tr.span("outer"):
-        out1 = tr.jit_call("f", f, x)
-    with tr.span("outer"):
-        out2 = tr.jit_call("f", f, x)
-    np.testing.assert_allclose(out1, out2)
-    first, second = tr.roots
-    assert [c.name for c in first.children] == ["f/compile", "f/run"]
-    # steady state reuses the memoized executable with zero span overhead
-    assert second.children == []
-    assert calls["n"] == 1               # python body ran only at compile
-    (compile_sp,) = first.find("f/compile")
-    assert compile_sp.dur_s > 0
-
-
-def test_traced_jit_call_falls_back_without_lower():
-    tr = Tracer("t")
-    with tr.span("outer") as sp:
-        out = tr.jit_call("plain", lambda x: x + 1, 2)
-    assert out == 3
-    assert sp.attrs.get("compile") == "unsplit"
+def test_fresh_shape_compile_counted_against_innermost_span(tmp_path):
+    """A fresh shape's compile lands on the innermost open span (not its
+    parent), in the process-wide counter, the JSONL export and the
+    Prometheus rendering; a cached call compiles nothing."""
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = jnp.ones(37, jnp.float32)
+    before = obs.compile_counts()["compiles"].get("inner_fresh", 0)
+    tr = Tracer("c", jsonl=str(tmp_path / "c.jsonl"))
+    with tr.span("outer_fresh") as outer:
+        with tr.span("inner_fresh") as inner:
+            f(x).block_until_ready()
+        with tr.span("inner_cached") as cached:
+            f(x).block_until_ready()
+    tr.close()
+    assert inner.compiles == 1
+    assert outer.compiles == 0 and cached.compiles == 0
+    assert obs.compile_counts()["compiles"]["inner_fresh"] == before + 1
+    (rec,) = load_jsonl(str(tmp_path / "c.jsonl"))
+    assert [c.compiles for c in rec.children] == [1, 0]
+    assert re.search(r'^repro_compiles_total\{span="inner_fresh"\} \d+$',
+                     obs.render_compile_metrics(), re.M)
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +301,7 @@ def test_metrics_server_scrape():
         assert counts == sorted(counts), series
     assert ('repro_serve_latency_seconds_count{engine="svd",tier="fused"} 3'
             in text)
+    assert "# TYPE repro_compiles_total counter" in text
 
 
 def test_render_matches_histogram_counts():
@@ -318,45 +314,34 @@ def test_render_matches_histogram_counts():
 
 
 # ---------------------------------------------------------------------------
-# pipeline acceptance: traced svd_batched span coverage
+# pipeline spans, scopes and compiles
 # ---------------------------------------------------------------------------
 
 def test_svd_batched_trace_coverage_and_compile_split():
-    """The ISSUE acceptance bar: one traced svd_batched call yields a span
-    tree whose stage children account for >= 90%% of the root duration,
-    with compile time attributed separately on the first dispatch — and
-    the traced path returns bit-identical sigma to the untraced one."""
-    cfg = PipelineConfig.resolve(n=24, bw=4, tw=3, backend="ref",
+    """One traced svd_batched call records a root with its config child,
+    counts the fused pipeline's compile on the root the first time and
+    none the second, and returns bit-identical sigma to the untraced
+    call: the tracer does not change the path."""
+    cfg = PipelineConfig.resolve(n=26, bw=4, tw=3, backend="ref",
                                  dtype=np.float64)
     rng = np.random.default_rng(0)
-    mats = jnp.asarray(rng.standard_normal((3, 24, 24)))
-    ref = np.asarray(svdmod.svd_batched(mats, config=cfg))
+    mats = jnp.asarray(rng.standard_normal((3, 26, 26)))
 
     tr = Tracer("svd")
     sig = np.asarray(svdmod.svd_batched(mats, config=cfg, trace=tr))
+    ref = np.asarray(svdmod.svd_batched(mats, config=cfg))
     np.testing.assert_array_equal(sig, ref)
-
-    (root,) = tr.roots
-    # svd_batched delegates to singular_values, which opens the root span
-    assert root.name == "singular_values"
-    assert root.attrs["n"] == 24 and root.attrs["batch"] == 3
-    stages = [c.name for c in root.children]
-    assert stages == ["stage1", "stage2", "stage3"]
-    coverage = root.total_child_seconds() / root.dur_s
-    assert coverage >= 0.90, f"stage spans cover {coverage:.1%} of root"
-    # first dispatch: compile attributed separately somewhere in the tree
-    assert root.find("stage1/compile")
-    assert root.find("stage1/run")
-
-    # steady state: second call with the AOT memo shared — no fresh
-    # compile spans, coverage still holds
-    tr2 = Tracer("svd2")
-    tr2._compiled = tr._compiled
-    sig2 = np.asarray(svdmod.svd_batched(mats, config=cfg, trace=tr2))
+    sig2 = np.asarray(svdmod.svd_batched(mats, config=cfg, trace=tr))
     np.testing.assert_array_equal(sig2, ref)
-    (root2,) = tr2.roots
-    assert not root2.find("stage1/compile")
-    assert root2.total_child_seconds() / root2.dur_s >= 0.90
+
+    first, second = tr.roots
+    # svd_batched delegates to singular_values, which opens the root span
+    assert first.name == second.name == "singular_values"
+    assert first.attrs["n"] == 26 and first.attrs["batch"] == 3
+    assert [c.name for c in first.children] == ["config"]
+    assert first.compiles >= 1          # the one _three_stage executable
+    assert second.compiles == 0
+    assert first.dur_s >= first.total_child_seconds() > 0.0
 
 
 def test_svd_uv_trace_has_replay_children():
@@ -365,16 +350,125 @@ def test_svd_uv_trace_has_replay_children():
     rng = np.random.default_rng(1)
     a = jnp.asarray(rng.standard_normal((16, 16)))
     tr = Tracer("uv")
-    u, s, vt = svdmod.svd(a, config=cfg, compute_uv=True, trace=tr)
+    u, s, vt = svdmod.svd(a, config=cfg, compute_uv=True, trace=tr,
+                          check=True)
     np.testing.assert_allclose(
         np.asarray(u) @ np.diag(np.asarray(s)) @ np.asarray(vt),
         np.asarray(a), atol=1e-8)
     (root,) = tr.roots
     names = [c.name for c in root.children]
-    for expected in ("stage1", "stage2", "replay", "compose"):
-        assert expected in names, names
+    assert names == ["config", "stage1", "pack", "stage2", "extract",
+                     "replay", "stage3", "compose", "validate"], names
     (replay,) = root.find("replay")
     assert replay.find("replay_stage1")
+
+
+def _band(n, bw, seed=0, batch=None):
+    rng = np.random.default_rng(seed)
+    shape = (n, n) if batch is None else (batch, n, n)
+    a = np.triu(rng.standard_normal(shape))
+    return jnp.asarray(a - np.triu(a, bw + 1))
+
+
+_ENTRIES = {
+    # entry point -> (call on (a, cfg), input is banded, batch, scopes)
+    "singular_values": (lambda a, c: svdmod.singular_values(a, config=c),
+                        False, None, {"stage1", "stage2", "stage3"}),
+    "banded_singular_values": (
+        lambda a, c: svdmod.banded_singular_values(a, config=c),
+        True, None, {"stage2", "stage3"}),
+    "svd_batched": (lambda a, c: svdmod.svd_batched(a, c), False, 2,
+                    {"stage1", "stage2", "stage3"}),
+    "svd": (lambda a, c: svdmod.svd(a, config=c), False, None,
+            {"stage1", "stage2", "replay", "stage3"}),
+    "banded_svd": (lambda a, c: svdmod.banded_svd(a, config=c), True, None,
+                   {"stage2", "replay", "stage3"}),
+    "fused_values": (lambda a, c: svdmod.singular_values(a, config=c),
+                     False, None, {"fused"}),
+    "fused_uv": (lambda a, c: svdmod.svd(a, config=c), False, None,
+                 {"fused", "stage3"}),
+}
+
+
+def _entry_case(name, n=16):
+    call, banded, batch, scopes = _ENTRIES[name]
+    backend = "fused_small" if name.startswith("fused") else "ref"
+    cfg = PipelineConfig.resolve(n=n, bw=4, tw=3, backend=backend,
+                                 dtype=np.float64)
+    a = _band(n, 4, batch=batch) if banded else jnp.asarray(
+        np.random.default_rng(2).standard_normal(
+            ((batch,) if batch else ()) + (n, n)))
+    return call, cfg, a, scopes
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRIES))
+def test_stage_scopes_in_compiled_hlo(entry):
+    """Each stage's jitted body is traced as a call named repro.<stage>,
+    so the name is in the op_name of the compiled ops whatever entry point
+    (and whatever enclosing jit) runs the stage, and in the module text
+    without debug information, which the persistent compilation cache
+    hashes: a build without the scopes cannot share its entries."""
+    call, cfg, a, scopes = _entry_case(entry)
+    lowered = jax.jit(lambda x: call(x, cfg)).lower(a)
+    text = lowered.compile().as_text()
+    found = set(re.findall(r'op_name="[^"]*?repro\.(\w+)', text))
+    assert scopes <= found, (entry, found)
+    keyed = set(re.findall(r"@repro\.(\w+)", lowered.as_text(debug_info=False)))
+    assert scopes <= keyed, (entry, keyed)
+
+
+@pytest.mark.parametrize("entry", ["banded_singular_values", "svd",
+                                   "singular_values", "fused_uv"])
+def test_tracer_adds_no_compiles_and_changes_no_values(entry):
+    """The same call with and without an active Tracer compiles the same
+    number of executables (the program's own counter, caches cleared
+    before each) and returns identical values."""
+    call, cfg, a, _ = _entry_case(entry, n=20)
+
+    def total():
+        return sum(obs.compile_counts()["compiles"].values())
+
+    jax.clear_caches()
+    c0 = total()
+    plain = jax.tree.map(np.asarray, call(a, cfg))
+    untraced = total() - c0
+    jax.clear_caches()
+    c0 = total()
+    tr = Tracer("same")
+    with obs.activated(tr):
+        traced = jax.tree.map(np.asarray, call(a, cfg))
+    assert total() - c0 == untraced > 0
+    jax.tree.map(np.testing.assert_array_equal, traced, plain)
+    (root,) = tr.roots
+    assert root.find("config")
+
+
+def test_annotations_on_host_plane_without_tracer(tmp_path):
+    """With no Tracer active, a profiler capture still sees every span of
+    the band path as a repro/<name> annotation on the host plane, with
+    its attributes as stats."""
+    assert obs.current() is None
+    a = _band(16, 4)
+    cfg = PipelineConfig.resolve(n=16, bw=4, tw=3, backend="ref",
+                                 dtype=np.float64)
+    svdmod.banded_singular_values(a, config=cfg).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svdmod.banded_singular_values(a, config=cfg,
+                                      check=True).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    prof = jax.profiler.ProfileData.from_file(path)
+    events = {ev.name: dict(ev.stats)
+              for plane in prof.planes if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("repro/")}
+    assert {"repro/banded_singular_values", "repro/config", "repro/pack",
+            "repro/stage2", "repro/extract", "repro/stage3",
+            "repro/validate"} <= set(events), sorted(events)
+    assert events["repro/stage2"]["b_in"] == 4
+    assert events["repro/banded_singular_values"]["n"] == 16
 
 
 # ---------------------------------------------------------------------------
@@ -399,3 +493,20 @@ def test_engine_dispatch_spans_and_latency_histograms():
     assert sum(row["count"]
                for row in snap["latency"]["tiers"].values()) == 4
     assert snap["latency"]["queue_age"]["count"] == 4
+
+
+def test_serve_spans_nest_under_dispatch():
+    """Every serve span of one dispatch is a child of serve/dispatch, and
+    the pipeline's own spans nest under serve/pipeline."""
+    tr = Tracer("serve")
+    eng = SVDEngine(backend="ref", tracer=tr)
+    rng = np.random.default_rng(4)
+    for i in range(3):
+        eng.submit(SVDRequest(uid=i, matrix=rng.standard_normal((16, 16)),
+                              bw=4))
+    assert all(r.error is None for r in eng.run())
+    (disp,) = [r for r in tr.roots if r.name == "serve/dispatch"]
+    assert [c.name for c in disp.children] == [
+        "serve/pad", "serve/pipeline", "serve/copy_back", "serve/validate"]
+    (pipe,) = disp.find("serve/pipeline")
+    assert [c.name for c in pipe.children] == ["singular_values"]
