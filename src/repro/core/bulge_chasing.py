@@ -79,6 +79,7 @@ __all__ = [
     "bidiagonalize_packed",
     "bidiagonalize",
     "chase_cycle_indices",
+    "stage_path",
     "stage_schedule",
 ]
 
@@ -137,6 +138,25 @@ def chase_cycle_indices(t, g, n: int, b_in: int, tw: int, fuse: int = 1):
     return R, j, p, active, (j == 0)
 
 
+def stage_path(dtype, *, n: int, b_in: int, tw: int, backend: str = "auto",
+               config=None, tape: bool = False) -> str:
+    """``"resident"`` or ``"streamed"``: which implementation
+    :func:`reduce_stage_packed` runs for one stage, from what the call can
+    observe (DESIGN.md §9).  Resident iff no tape is recorded, the
+    resolved backend is "pallas" (interpret mode off the TPU), the data is
+    32-bit and one matrix's band fits ``tuning.VMEM_BUDGET_BYTES`` as
+    ``tuning.resident_band_bytes`` counts it."""
+    from repro.core import tuning
+    from repro.kernels import ops
+    if tape or jnp.dtype(dtype).itemsize != 4:
+        return "streamed"
+    if ops.resolved_backend(backend, config, dtype) != "pallas":
+        return "streamed"
+    fits = (tuning.resident_band_bytes(n, b_in, tw, dtype)
+            <= tuning.VMEM_BUDGET_BYTES)
+    return "resident" if fits else "streamed"
+
+
 # ---------------------------------------------------------------------------
 # Packed wavefront stage (JAX)
 # ---------------------------------------------------------------------------
@@ -153,7 +173,16 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
 
     band: (..., b_in + 2*tw + 1, >= n) — any leading batch axes (flattened to
     one B internally).  Returns same-shape storage with bandwidth reduced to
-    ``b_in - tw`` (bulge space zeroed).  All B problems advance on one
+    ``b_in - tw`` (bulge space zeroed).
+
+    Two implementations, chosen by :func:`stage_path` from the input alone
+    (DESIGN.md §9).  The *resident* path — values only, Pallas backend,
+    32-bit data, a band that fits fast memory — is one ``ops.chase_stage``
+    kernel per stage that keeps each matrix's band in VMEM and runs the
+    whole wavefront loop inside; ``fuse`` and ``unroll`` do not apply.
+    Everything else takes the *streamed* path
+    (:func:`_reduce_stage_streamed`), and the two give the same band bit
+    for bit.  On the streamed path all B problems advance on one
     wavefront clock: per global cycle the (B, G, H, W) window gather is
     flattened into ONE fused kernel call over B*G slots, so independent
     problems fill wavefront slots a single small matrix leaves idle.
@@ -183,9 +212,33 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
     Explicit ``backend=``/``unroll=``/``fuse=`` kwargs win over ``config``;
     the config fills whatever was left at its default ("auto" / None).
     Backend/interpret resolution itself is delegated to the kernel registry
-    (ops._resolve) at the ``chase_cycle`` call — this function only resolves
+    (ops._resolve) at the kernel call — this function only resolves
     ``unroll`` and ``fuse``.
     """
+    if stage_path(band.dtype, n=n, b_in=b_in, tw=tw, backend=backend,
+                  config=config, tape=tape) == "resident":
+        from repro.kernels import ops
+        band3 = band.reshape((-1,) + band.shape[-2:])
+        out = ops.chase_stage(band3, n=n, b_in=b_in, tw=tw, backend=backend,
+                              config=config)
+        return out.reshape(band.shape)
+    return _reduce_stage_streamed(band, n=n, b_in=b_in, tw=tw,
+                                  backend=backend, unroll=unroll,
+                                  config=config, tape=tape, fuse=fuse)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
+                                             "unroll", "config", "tape",
+                                             "fuse"))
+def _reduce_stage_streamed(band: jax.Array, *, n: int, b_in: int, tw: int,
+                           backend: str = "auto", unroll: int | None = None,
+                           config=None, tape: bool = False,
+                           fuse: int | None = None):
+    """The streamed stage (the K = 1 wavefront, or fuse-K super-steps): per
+    (super-)cycle, gather every slot's window or block from the band in
+    HBM, chase it with one ``ops.chase_cycle`` call, scatter it back.  Same
+    arguments and results as :func:`reduce_stage_packed`, which calls it
+    for every input the resident path does not take."""
     from repro.kernels import ops  # local import to avoid cycles
 
     if unroll is None:
@@ -366,8 +419,12 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
     With ``tape=True`` returns ``(diag, superdiag, tapes)`` where ``tapes``
     is a static-length list of :class:`repro.core.transforms.ChaseTape`,
     one per stage of the tile-width plan, in execution order.  ``fuse=K``
-    (explicit kwarg or ``config.fuse``) runs every stage in K-cycle
-    super-steps; the tapes carry the fuse depth for replay.
+    (explicit kwarg or ``config.fuse``) runs every streamed stage in
+    K-cycle super-steps; the tapes carry the fuse depth for replay.
+
+    Each stage runs on the path :func:`stage_path` picks; its ``stage2``
+    span carries ``path=``, and ``obs.count_chase_stage`` counts it here —
+    per call when this runs eagerly, per trace inside a jitted pipeline.
 
     Storage layout invariant entering each stage (b_in, tw_i):
       tw_i sub rows | diag row | b_in + tw_i sup rows  ==  b_in + 2*tw_i + 1.
@@ -392,10 +449,13 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
     assert cur.shape[-2] == plan[0][0] + 2 * tw_cur + 1, (cur.shape, plan[0])
     tapes = []
     for b_in, twi in plan:
+        path = stage_path(cur.dtype, n=n, b_in=b_in, tw=twi, backend=backend,
+                          config=config, tape=tape)
+        obs.count_chase_stage(path)
         # One span per stage of the tile-width plan (DESIGN.md §16); inside
         # `_three_stage` this loop is traced and the spans are no-ops.
         with obs.span("stage2", n=n, b_in=b_in, tw=twi, fuse=fuse,
-                      tape=tape):
+                      tape=tape, path=path):
             # re-slice so exactly twi sub rows remain above the diagonal row
             h_i = b_in + 2 * twi + 1
             start = tw_cur - twi

@@ -31,6 +31,7 @@ __all__ = [
     "max_concurrent_sweeps", "occupancy_matrix_size",
     "vmem_working_set_bytes", "default_fuse_depth", "check_vmem_budget",
     "fused_working_set_bytes", "check_fused_vmem_budget",
+    "resident_band_layout", "resident_band_bytes",
     "DEFAULT_FUSED_CROSSOVER", "STAGE3_CHOICES",
     "stage_plan", "default_bucket_batch", "ChaseConfig", "PipelineConfig",
 ]
@@ -198,6 +199,32 @@ def check_vmem_budget(b_in: int, tw: int, dtype=jnp.float32, *,
             f"window H x W = (b_in + 2*tw + 1) x (b_in + tw + 1)) or "
             f"raise budget_bytes")
     return need
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def resident_band_layout(n: int, b_in: int, tw: int) -> tuple[int, int]:
+    """(rows, lanes) of the band-resident stage kernel's VMEM band
+    (DESIGN.md §9): the band, transposed and row-reversed — the n + W
+    columns a window can touch, sublane-aligned, by the H stored diagonals
+    padded to the lane width.  Its shear workspace is (lanes, lanes)."""
+    w = b_in + tw + 1
+    return _round_up(n + w, SUBLANE), _round_up(b_in + 2 * tw + 1, LANE)
+
+
+def resident_band_bytes(n: int, b_in: int, tw: int, dtype=jnp.float32) -> int:
+    """VMEM bytes the band-resident stage kernel allocates for one matrix:
+    one copy of the band (copied in and out by hand, not double-buffered),
+    the shear workspace, and the (H, W) window, each at its tiled size.
+    ``reduce_stage_packed`` takes the resident path only when this fits
+    ``VMEM_BUDGET_BYTES``."""
+    rows, lanes = resident_band_layout(n, b_in, tw)
+    h, w = b_in + 2 * tw + 1, b_in + tw + 1
+    words = (rows * lanes + lanes * lanes
+             + _round_up(h, SUBLANE) * _round_up(w, LANE))
+    return words * _bytes(dtype)
 
 
 # Default fused-vs-staged crossover (DESIGN.md §13): the ROADMAP names

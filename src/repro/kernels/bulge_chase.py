@@ -6,7 +6,9 @@ Memory mapping (GPU -> TPU, DESIGN.md §2):
 * reflector in shared memory (L1)   -> reflector in VMEM-resident window block
 * TPB rows held in registers        -> row tiles materialized into VREGs from
                                        the VMEM window by the vector unit
-* kernel-launch sync between cycles -> one ``pallas_call`` per K-cycle
+* kernel-launch sync between cycles -> values only: one ``pallas_call``
+                                       per stage (``chase_stage_pallas``);
+                                       with a tape, one per K-cycle
                                        super-step (``chase_superstep_pallas``;
                                        K=1 is ``chase_cycle_pallas``)
 
@@ -28,10 +30,16 @@ block load per K cycles; the kernel hands back the sheared rows and the
 wrapper un-shears them in the store (Mosaic has no roll by minus the row
 index).
 
-The kernel is batch-oblivious: a window neither knows nor cares which matrix
-it came from, so the batch-native pipeline (DESIGN.md §4) simply flattens a
-(B, G, H, W) wavefront into grid (B·G,) — independent problems widen the
-wavefront that a single small matrix cannot fill (paper Eq. 1).
+Band-resident stage (DESIGN.md §9): on the values-only path a grid step
+owns one matrix's whole band for a whole stage, copied into VMEM once, and
+runs the K = 1 wavefront loop inside the kernel, every window through the
+same ``_chase_window_vmem``; HBM sees the band once in and once out.
+
+The window kernels are batch-oblivious: a window neither knows nor cares
+which matrix it came from, so the batch-native pipeline (DESIGN.md §4)
+simply flattens a (B, G, H, W) wavefront into grid (B·G,) — independent
+problems widen the wavefront that a single small matrix cannot fill (paper
+Eq. 1).
 
 The kernel is data-precision-agnostic (fp32/bf16; accumulation in fp32),
 mirroring the paper's precision-agnostic single-source claim.
@@ -47,7 +55,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["chase_cycle_pallas", "chase_superstep_pallas"]
+__all__ = ["chase_cycle_pallas", "chase_superstep_pallas", "chase_stage_pallas"]
 
 
 def _reflector_in_kernel(x, pos, axis, acc):
@@ -302,4 +310,120 @@ def chase_superstep_pallas(blocks: jax.Array, is_first: jax.Array,
     if with_tape:
         return (out, res[1].reshape(g, fuse, 2, tw + 1),
                 res[2].reshape(g, fuse, 2))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Band-resident stage (values only)
+# ---------------------------------------------------------------------------
+
+def _loop32(count: int, body) -> None:
+    """``for i in range(count): body(i)`` with an int32 counter whatever
+    jax_enable_x64 says (``fori_loop`` with static bounds counts in the
+    default int width, which Mosaic refuses at 64 bits)."""
+    def step(i):
+        body(i)
+        return i + 1
+    jax.lax.while_loop(lambda i: i < count, step, _I0)
+
+
+def _chase_stage_kernel(band_hbm, out_hbm, rev_ref, ws_ref, win_ref, sem, *,
+                        n: int, b_in: int, tw: int, T: int, G: int):
+    """One matrix's whole stage.  ``rev_ref[c, r] = band[H-1-r, c]``: a
+    window's W band columns are W consecutive rows at the dynamic sublane
+    offset p, and its dense cells ``win[r + w, w] = rev[p + w, r]`` sit at
+    a static lane offset w per column, so shear and un-shear are W static
+    row rolls around one transpose each way of the (lanes, lanes)
+    workspace ``ws_ref``."""
+    h, w = b_in + 2 * tw + 1, b_in + tw + 1
+    b_out = b_in - tw
+    b = pl.program_id(0)
+    copy = pltpu.make_async_copy(band_hbm.at[b], rev_ref, sem)
+    copy.start()
+    copy.wait()
+
+    lanes = rev_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+
+    def slot(t3, r, g):
+        # chase_cycle_indices at fuse = 1 for cycle t = 3*t3 + r, without the
+        # scalar floor division Mosaic cannot lower under jax_enable_x64
+        sweep, j = t3 - g, r + 3 * g
+        p = sweep + b_out + j * b_in
+        active = (sweep >= 0) & (sweep < n - 1 - b_out) & (p <= n - 1)
+        first = j == 0
+
+        @pl.when(active)
+        def _():
+            # shear: window column c holds the H - c cells of band column
+            # p + c that map into storage; every other cell reads as 0
+            ws_ref[...] = jnp.zeros(ws_ref.shape, ws_ref.dtype)
+            for c in range(w):
+                row = _roll(rev_ref[pl.ds(p + c, 1), :], c, 1)
+                ws_ref[c:c + 1, :] = jnp.where((lane >= c) & (lane < h), row, 0)
+            ws_ref[...] = ws_ref[...].T
+            win_ref[...] = ws_ref[0:h, 0:w]
+            _chase_window_vmem(win_ref, first, b_in=b_in, tw=tw)
+            ws_ref[0:h, 0:w] = win_ref[...]
+            ws_ref[...] = ws_ref[...].T
+            # un-shear, writing back only the cells that map into storage
+            for c in range(w):
+                old = rev_ref[pl.ds(p + c, 1), :]
+                new = _roll(ws_ref[c:c + 1, :], (lanes - c) % lanes, 1)
+                rev_ref[pl.ds(p + c, 1), :] = jnp.where(lane < h - c, new, old)
+
+    # all slots of cycle t before cycle t + 1, as the streamed wavefront;
+    # cycles past T have no active slot
+    _loop32(-(-T // 3), lambda t3: _loop32(3, lambda r: _loop32(
+        G, lambda g: slot(t3, r, g))))
+    copy = pltpu.make_async_copy(rev_ref, out_hbm.at[b], sem)
+    copy.start()
+    copy.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "interpret"))
+def chase_stage_pallas(band: jax.Array, *, n: int, b_in: int, tw: int,
+                       interpret: bool = False):
+    """band: (B, H, ncols) packed storage, ncols >= n.  Runs one whole
+    stage (bandwidth b_in -> b_in - tw) with each matrix's band resident in
+    VMEM, in the streamed K = 1 wavefront order, so the output is the same
+    band bit for bit.  Values only: no reflector tape.
+
+    Grid (B,): step b copies matrix b's band in once, chases every cycle of
+    the stage on it, and copies it back; the output aliases the input.  The
+    transpose into the resident layout (``tuning.resident_band_layout``)
+    and back is one XLA transpose each way here."""
+    from repro.core import tuning
+    from repro.core.bulge_chasing import stage_schedule
+    bsz, h, ncols0 = band.shape
+    assert b_in - tw >= 1, (b_in, tw)
+    assert h == b_in + 2 * tw + 1 and ncols0 >= n, (band.shape, b_in, tw)
+    _, T, G = stage_schedule(n, b_in, tw)
+    rows, lanes = tuning.resident_band_layout(n, b_in, tw)
+    dt = band.dtype
+    cols = min(ncols0, rows)          # windows never touch columns >= rows
+    revt = jnp.swapaxes(band[:, ::-1, :cols], 1, 2)
+    revt = jnp.pad(revt, ((0, 0), (0, rows - cols), (0, lanes - h)))
+    kern = functools.partial(_chase_stage_kernel, n=n, b_in=b_in, tw=tw,
+                             T=T, G=G)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    revt = pl.pallas_call(
+        kern,
+        out_shape=jax.ShapeDtypeStruct(revt.shape, dt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(bsz,),
+            in_specs=[any_],
+            out_specs=any_,
+            scratch_shapes=[pltpu.VMEM((rows, lanes), dt),
+                            pltpu.VMEM((lanes, lanes), dt),   # W < H lanes
+                            pltpu.VMEM((h, b_in + tw + 1), dt),
+                            pltpu.SemaphoreType.DMA]),
+        input_output_aliases={0: 0},
+        interpret=interpret,
+        name="chase_stage",
+    )(revt)
+    out = jnp.swapaxes(revt[:, :cols, :h], 1, 2)[:, ::-1]
+    if cols < ncols0:
+        out = jnp.concatenate([out, band[:, :, cols:]], axis=-1)
     return out

@@ -26,9 +26,9 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels import ref as _ref
 
-__all__ = ["chase_cycle", "hh_block_apply", "tape_apply", "fused_svd",
-           "register_backend", "resolve_backend",
-           "backend_names"]
+__all__ = ["chase_cycle", "chase_stage", "hh_block_apply", "tape_apply",
+           "fused_svd", "register_backend", "resolve_backend",
+           "resolved_backend", "backend_names"]
 
 
 def _platform() -> str:
@@ -108,6 +108,12 @@ def _resolve(backend: str, interpret: bool | None, config,
     return resolve_backend(backend, interpret, dtype)
 
 
+def resolved_backend(backend: str = "auto", config=None, dtype=None) -> str:
+    """The registry key a wrapper called with ``backend=``/``config=``
+    dispatches to for ``dtype`` data."""
+    return _resolve(backend, None, config, dtype)[0]
+
+
 # ---- built-in "ref" (pure jnp; interpret flag ignored) ---------------------
 
 def _ref_chase(windows, is_first, *, b_in, tw, with_tape, interpret, fuse=1,
@@ -146,6 +152,12 @@ def _pallas_chase(windows, is_first, *, b_in, tw, with_tape, interpret,
                                               with_tape=with_tape)
 
 
+def _pallas_chase_stage(band, *, n, b_in, tw, interpret):
+    from repro.kernels import bulge_chase
+    return bulge_chase.chase_stage_pallas(band, n=n, b_in=b_in, tw=tw,
+                                          interpret=interpret)
+
+
 def _pallas_hh(v, t, c, *, block_cols, interpret):
     from repro.kernels import hh_apply
     return hh_apply.hh_block_apply_pallas(v, t, c, interpret=interpret,
@@ -165,7 +177,8 @@ def _pallas_fused(mats, *, bw, compute_uv, interpret):
                                               interpret=interpret)
 
 
-register_backend("pallas", chase_cycle=_pallas_chase, hh_block_apply=_pallas_hh,
+register_backend("pallas", chase_cycle=_pallas_chase,
+                 chase_stage=_pallas_chase_stage, hh_block_apply=_pallas_hh,
                  tape_apply=_pallas_tape, fused_svd=_pallas_fused)
 
 
@@ -228,6 +241,20 @@ def chase_cycle(windows: jax.Array, is_first: jax.Array, *, b_in: int, tw: int,
     return _impl("chase_cycle", backend)(windows, is_first, b_in=b_in, tw=tw,
                                          with_tape=with_tape, fuse=fuse,
                                          active=active, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
+                                             "interpret", "config"))
+def chase_stage(band: jax.Array, *, n: int, b_in: int, tw: int,
+                backend: str = "auto", interpret: bool | None = None,
+                config=None) -> jax.Array:
+    """One whole values-only stage (bandwidth b_in -> b_in - tw) on packed
+    storage ``(B, H, ncols)``, each matrix's band resident in fast memory
+    for the stage (DESIGN.md §9).  Only the "pallas" backend implements
+    it; ``core.bulge_chasing.stage_path`` says when it is used."""
+    backend, interpret = _resolve(backend, interpret, config, band.dtype)
+    return _impl("chase_stage", backend)(band, n=n, b_in=b_in, tw=tw,
+                                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "interpret",

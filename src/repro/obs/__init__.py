@@ -5,7 +5,8 @@ Three pieces, importable from this package root:
 * :func:`span` / :class:`Span` / :class:`Tracer` — always-on program spans
   that land on the profiler's clock as ``repro/<name>`` annotations, are
   recorded into a tracer only when one is active, and never change what
-  runs; plus the program's own compile counter, :func:`compile_counts`
+  runs; plus the program's own compile counter, :func:`compile_counts`,
+  and the stage-2 chase-path counter, :func:`chase_stage_counts`
   (``trace.py``); :func:`scope` names a stage's jitted body
   ``repro.<stage>`` for the device trace.  Ambient-tracer helpers: :func:`current`,
   :func:`activated`, :func:`install`.
@@ -25,7 +26,9 @@ from .trace import (
     Span,
     Tracer,
     activated,
+    chase_stage_counts,
     compile_counts,
+    count_chase_stage,
     current,
     install,
     scope,
@@ -44,7 +47,9 @@ __all__ = [
     "Span",
     "Tracer",
     "activated",
+    "chase_stage_counts",
     "compile_counts",
+    "count_chase_stage",
     "current",
     "install",
     "scope",

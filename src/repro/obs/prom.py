@@ -181,8 +181,9 @@ def render_fleet_metrics(fleet: dict) -> str:
 def render_compile_metrics() -> str:
     """Exposition text for the program's compile counter: executables
     compiled or loaded from the persistent cache, and the loads among them,
-    by the innermost program span open when each happened."""
-    from repro.obs.trace import compile_counts
+    by the innermost program span open when each happened; then the
+    stage-2 stages by chase path."""
+    from repro.obs.trace import chase_stage_counts, compile_counts
 
     counts = compile_counts()
     lines: list[str] = []
@@ -197,6 +198,12 @@ def render_compile_metrics() -> str:
         lines.append(f"# TYPE {metric} counter")
         for span, n in sorted(counts[key].items()):
             lines.append(_sample(metric, {"span": span}, int(n)))
+    lines.append("# HELP repro_chase_stages_total Stage-2 stages by chase "
+                 "path, per eager call or per trace of a jitted pipeline.")
+    lines.append("# TYPE repro_chase_stages_total counter")
+    for path, n in sorted(chase_stage_counts().items()):
+        lines.append(_sample("repro_chase_stages_total", {"path": path},
+                             int(n)))
     return "\n".join(lines) + "\n"
 
 

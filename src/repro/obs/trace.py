@@ -66,6 +66,8 @@ __all__ = [
     "install",
     "span",
     "compile_counts",
+    "count_chase_stage",
+    "chase_stage_counts",
     "scope",
 ]
 
@@ -353,3 +355,24 @@ def compile_counts() -> dict[str, dict[str, int]]:
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+
+
+# ----------------------------------------------------------------------
+# stage-2 path counter
+
+_chase_stages: collections.Counter = collections.Counter()
+
+
+def count_chase_stage(path: str) -> None:
+    """Count one stage-2 stage of the tile-width plan by the chase path that
+    runs it (``"resident"`` or ``"streamed"``, DESIGN.md §9).  Counted where
+    the path is chosen: once per call where the pipeline runs eagerly, once
+    per trace inside a jitted pipeline."""
+    with _compile_lock:
+        _chase_stages[path] += 1
+
+
+def chase_stage_counts() -> dict[str, int]:
+    """Process-wide stage-2 stage counts by chase path."""
+    with _compile_lock:
+        return dict(_chase_stages)

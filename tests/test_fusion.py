@@ -115,13 +115,16 @@ def test_fused_stage_matches_unfused(backend, batched, tape):
     if tape:
         base, v1, t1 = bc.reduce_stage_packed(packed, tape=True, **kw)
     else:
-        base = bc.reduce_stage_packed(packed, **kw)
+        # values only: the streamed stage itself, which the resident path
+        # (tests/test_resident_stage.py) would otherwise replace for 32-bit
+        # Pallas data, so the K = 1 and super-step kernels stay covered
+        base = bc._reduce_stage_streamed(packed, **kw)
     for K in (1, 2, 4):
         if tape:
             out, vK, tK = bc.reduce_stage_packed(packed, tape=True, fuse=K,
                                                  **kw)
         else:
-            out = bc.reduce_stage_packed(packed, fuse=K, **kw)
+            out = bc._reduce_stage_streamed(packed, fuse=K, **kw)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    rtol=0, atol=1e-12)
         if not tape:
@@ -298,8 +301,9 @@ def test_fused_stage_matches_unfused_randomized(n, bw, data, fuse, batched):
     lead = (2,) if batched else ()
     mats = banded_random(n, bw, seed=seed, lead=lead)
     packed = bandmod.pack(jnp.asarray(mats), bw, tw)
-    base = bc.reduce_stage_packed(packed, n=n, b_in=bw, tw=tw, backend="ref")
-    out = bc.reduce_stage_packed(packed, n=n, b_in=bw, tw=tw, backend="ref",
-                                 fuse=fuse)
+    base = bc._reduce_stage_streamed(packed, n=n, b_in=bw, tw=tw,
+                                     backend="ref")
+    out = bc._reduce_stage_streamed(packed, n=n, b_in=bw, tw=tw,
+                                    backend="ref", fuse=fuse)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                rtol=0, atol=1e-12)

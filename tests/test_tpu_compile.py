@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import bulge_chasing as bc
 from repro.core import svd, tuning
 from repro.kernels import bulge_chase, fused_small, hh_apply
 
@@ -89,6 +90,28 @@ def test_superstep_kernel_compiles(one_chip, x64, tape):
              _spec(one_chip, (g, fuse), jnp.bool_))
 
 
+def _largest_resident_n(b_in, tw):
+    lo, hi = 1, 1 << 20
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        fits = (tuning.resident_band_bytes(mid, b_in, tw)
+                <= tuning.VMEM_BUDGET_BYTES)
+        lo, hi = (mid, hi) if fits else (lo, mid - 1)
+    return lo
+
+
+# the benchmark's stage (n = 1024, the f32 default plan at bw = 32), and the
+# largest band the resident path admits at that plan
+@pytest.mark.parametrize("n", [1024, _largest_resident_n(32, 31)])
+def test_chase_stage_kernel_compiles(one_chip, x64, n):
+    b_in, tw = 32, 31
+    assert tuning.resident_band_bytes(n, b_in, tw) <= tuning.VMEM_BUDGET_BYTES
+    compiled = _compile(lambda band: bulge_chase.chase_stage_pallas(
+                            band, n=n, b_in=b_in, tw=tw),
+                        _spec(one_chip, (1, b_in + 2 * tw + 1, n)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # stage-1 panel replay at m = 4096 (k = nb = 32) and chase-tape replay
 # (k = 1 over tw + 1 rows, one slot per wavefront window).
 @pytest.mark.parametrize("s,m,k,w", [(1, 4096, 32, 4096),
@@ -109,7 +132,8 @@ def test_fused_small_kernel_compiles(one_chip, n, compute_uv):
 @pytest.mark.parametrize("n,batch", [(4096, 1), (256, 8)])
 def test_pallas_pipeline_compiles(one_chip, n, batch):
     """The values pipeline the chip runs (stage 1 -> chase -> bisection),
-    with the compiled Pallas kernels in it, not interpret mode."""
+    with the compiled Pallas kernels in it, not interpret mode; its stage 2
+    is the band-resident ``chase_stage`` kernel."""
     cfg = tuning.PipelineConfig.resolve(bw=32, backend="pallas",
                                         interpret=False, dtype=jnp.float32,
                                         n=n).kernel()
@@ -118,3 +142,6 @@ def test_pallas_pipeline_compiles(one_chip, n, batch):
                                       config=cfg).compile()
     # the stage-1 WY apply and the chase
     assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert bc.stage_path(jnp.float32, n=n, b_in=32, tw=cfg.tw,
+                         config=cfg) == "resident"
+    assert "chase_stage" in compiled.as_text()
