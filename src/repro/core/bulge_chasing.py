@@ -424,7 +424,8 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
 
     Each stage runs on the path :func:`stage_path` picks; its ``stage2``
     span carries ``path=``, and ``obs.count_chase_stage`` counts it here —
-    per call when this runs eagerly, per trace inside a jitted pipeline.
+    per call when this runs eagerly, per trace inside a jitted pipeline;
+    ``obs.count_tape_bytes`` counts each recorded tape the same way.
 
     Storage layout invariant entering each stage (b_in, tw_i):
       tw_i sub rows | diag row | b_in + tw_i sup rows  ==  b_in + 2*tw_i + 1.
@@ -467,6 +468,7 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
                     config=config, tape=True, fuse=fuse)
                 tapes.append(transforms.ChaseTape(n=n, b_in=b_in, tw=twi,
                                                   v=tv, tau=tt, fuse=fuse))
+                obs.count_tape_bytes("stage2", tapes[-1].nbytes)
             else:
                 cur = reduce_stage_packed(cur, n=n, b_in=b_in, tw=twi,
                                           backend=backend, config=config,

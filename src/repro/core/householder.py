@@ -15,8 +15,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["make_reflector", "apply_left", "apply_right", "reflector_matrix",
-           "exact_matmul"]
+__all__ = ["make_reflector", "reflector_parts", "apply_left", "apply_right",
+           "reflector_matrix", "exact_matmul"]
 
 
 def exact_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -31,6 +31,51 @@ def exact_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 
+# A vector whose squared norm is below _TINY_SQ may have entries whose
+# squares underflowed (float32's smallest normal is 2^-126); summed that way,
+# sigma drifts from the tail that v is divided out of, and tau * ||v||^2
+# leaves 2: the reflector is no longer orthogonal.  Such a vector is lifted
+# by the exact power of two _LIFT before the norm is taken.  Every normal
+# float32 then squares to a normal, and tau and v, which do not depend on
+# x's scale, come out as for any other x; every other x takes the unlifted
+# formulas bit for bit.
+_TINY_SQ = 2.0 ** -60
+_LIFT = 2.0 ** 64
+
+
+def reflector_parts(alpha: jax.Array, tail: jax.Array, axis=None):
+    """LAPACK ``larfg`` on ``x = [alpha, tail]``: ``(tau, v_tail, beta)``.
+
+    The one copy of the reflector formula every implementation calls: the
+    chase kernels (``kernels/ref.py``, ``kernels/bulge_chase.py``), stage 1
+    and the fused tier.  ``tail`` holds x's other entries, zero wherever
+    it is not part of x, reduced over ``axis`` (all axes when None; kept
+    as a length-1 axis otherwise, as Mosaic wants); ``alpha`` broadcasts
+    against the reduction.  ``v_tail`` is the tail of ``v`` (``v[0] = 1``
+    is the caller's), zero where ``tail`` is; a tail whose squares sum to
+    zero gives ``tau = 0`` (an identity, whatever ``v``) and ``beta =
+    alpha``.  Tiny vectors are lifted first (``_TINY_SQ``).
+    """
+    keep = axis is not None
+    sigma = jnp.sum(tail * tail, axis=axis, keepdims=keep)
+    up = tail * _LIFT
+    sigma_up = jnp.sum(up * up, axis=axis, keepdims=keep)
+    small = alpha * alpha + sigma < _TINY_SQ
+    a = jnp.where(small, alpha * _LIFT, alpha)
+    sigma = jnp.where(small, sigma_up, sigma)
+    mu = jnp.sqrt(a * a + sigma)
+    # beta gets the sign opposite to alpha (avoids cancellation).
+    beta = jnp.where(a >= 0, -mu, mu)
+    safe = sigma > 0
+    tau = jnp.where(safe, (beta - a) / jnp.where(safe, beta, 1.0), 0.0)
+
+    def unlift(y):          # back to x's own scale, exactly
+        return jnp.where(small, y * (1.0 / _LIFT), y)
+
+    v_tail = tail / jnp.where(safe, unlift(a - beta), 1.0)
+    return tau, v_tail, jnp.where(safe, unlift(beta), alpha)
+
+
 def make_reflector(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Compute (v, tau, beta) for a length-L vector x (L static).
 
@@ -40,20 +85,9 @@ def make_reflector(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     dt = x.dtype
     # Accumulate norms in f32 at minimum (bf16 sums are too lossy).
     acc = jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
-    alpha = x[0].astype(acc)
-    x2 = x[1:].astype(acc)
-    sigma = jnp.sum(x2 * x2)
-    mu = jnp.sqrt(alpha * alpha + sigma)
-    # beta gets the sign opposite to alpha (avoids cancellation).
-    beta = jnp.where(alpha >= 0, -mu, mu)
-    denom = alpha - beta
-    safe = sigma > 0
-    denom = jnp.where(safe, denom, 1.0)
-    tau = jnp.where(safe, (beta - alpha) / beta, 0.0)
-    v2 = jnp.where(safe, x2 / denom, 0.0)
+    tau, v2, beta = reflector_parts(x[0].astype(acc), x[1:].astype(acc))
     v = jnp.concatenate([jnp.ones((1,), acc), v2])
-    beta_out = jnp.where(safe, beta, alpha)
-    return v.astype(dt), tau.astype(dt), beta_out.astype(dt)
+    return v.astype(dt), tau.astype(dt), beta.astype(dt)
 
 
 def apply_left(v: jax.Array, tau: jax.Array, c: jax.Array) -> jax.Array:

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.householder import exact_matmul as _mm
+from repro.core.householder import reflector_parts
 
 __all__ = ["band_reduce", "wy_t_factor"]
 
@@ -44,18 +45,9 @@ def _masked_reflector(col: jax.Array, pivot: jax.Array):
     m = col.shape[0]
     idx = jnp.arange(m)
     piv = jnp.clip(pivot, 0, m - 1)
-    alpha = col[piv]
-    tail = jnp.where(idx > pivot, col, 0)
-    sigma = jnp.sum(tail * tail)
-    mu = jnp.sqrt(alpha * alpha + sigma)
-    beta = jnp.where(alpha >= 0, -mu, mu)
-    safe = sigma > 0
-    denom = jnp.where(safe, alpha - beta, 1)
-    tau = jnp.where(safe, (beta - alpha) / jnp.where(beta == 0, 1, beta), 0)
-    v = jnp.where(idx > pivot, col / denom, 0)
+    tau, v, beta = reflector_parts(col[piv], jnp.where(idx > pivot, col, 0))
     v = v.at[piv].set(jnp.where(pivot < m, 1.0, 0.0))
-    beta_out = jnp.where(safe, beta, alpha)
-    return v, tau, beta_out
+    return v, tau, beta
 
 
 def wy_t_factor(v: jax.Array, taus: jax.Array) -> jax.Array:

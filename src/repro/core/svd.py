@@ -124,11 +124,14 @@ def validate_uv(u, vt, *, name: str = "uv") -> None:
 
 
 def spot_check_svd(a, u, sig, vt, *, rtol: float | None = None) -> None:
-    """Residual spot-check ``||A - U diag(s) V^T||_F / ||A||_F`` on the
-    FIRST matrix of a (possibly batched) full-SVD result — one small
-    matmul, not a per-matrix sweep.  Raises :class:`NumericalFault` when
-    the relative residual exceeds ``rtol`` (default: ``50 * n * eps`` of
-    the working dtype, loose enough for every healthy backend)."""
+    """Residual and orthogonality spot-check on the FIRST matrix of a
+    (possibly batched) full-SVD result — three matmuls of one matrix, not
+    a per-matrix sweep.  Raises :class:`NumericalFault` when the relative
+    residual ``||A - U diag(s) V^T||_F / ||A||_F`` or the orthogonality
+    ``max(||U^T U - I||_F, ||V^T V - I||_F) / sqrt(n)`` exceeds ``rtol``
+    (default: ``50 * n * eps`` of the working dtype, loose enough for every
+    healthy backend).  A residual alone passes factors that are not
+    orthogonal but still multiply back to A."""
     a = np.asarray(a).reshape((-1,) + np.asarray(a).shape[-2:])[0]
     u0 = np.asarray(u).reshape((-1,) + np.asarray(u).shape[-2:])[0]
     vt0 = np.asarray(vt).reshape((-1,) + np.asarray(vt).shape[-2:])[0]
@@ -142,6 +145,15 @@ def spot_check_svd(a, u, sig, vt, *, rtol: float | None = None) -> None:
         raise NumericalFault(
             f"residual spot-check failed: ||A - USV^T||/||A|| = "
             f"{resid:.3e} > {rtol:.1e} (n={n})")
+    u0, vt0 = (x.astype(np.promote_types(x.dtype, np.float32))
+               for x in (u0, vt0))
+    eye = np.eye(n, dtype=u0.dtype)
+    orth = max(float(np.linalg.norm(u0.T @ u0 - eye)),
+               float(np.linalg.norm(vt0 @ vt0.T - eye))) / np.sqrt(n)
+    if not np.isfinite(orth) or orth > rtol:
+        raise NumericalFault(
+            f"orthogonality spot-check failed: ||U^T U - I||/sqrt(n) or "
+            f"||V^T V - I||/sqrt(n) = {orth:.3e} > {rtol:.1e} (n={n})")
 
 
 def _stage3_values(d: jax.Array, e: jax.Array,
@@ -201,6 +213,15 @@ def _span_attrs(a, cfg: tuning.PipelineConfig, **extra) -> dict:
                 **extra)
 
 
+@jax.jit
+@obs.scope("compose")
+def _compose(u2, ub, vtb, vt2):
+    """The singular vectors of A from the reduction's transforms and the
+    bidiagonal's: A = U2 B V2^T and B = Ub S Vb^T, so U = U2 Ub and
+    V^T = Vb^T V2^T.  Its own ``repro.compose`` device scope."""
+    return exact_matmul(u2, ub), exact_matmul(vtb, vt2)
+
+
 def _fused_path(a: jax.Array, cfg: tuning.PipelineConfig, *,
                 compute_uv: bool):
     """DESIGN.md §13: the one-dispatch fused small-n tier.
@@ -223,10 +244,8 @@ def _fused_path(a: jax.Array, cfg: tuning.PipelineConfig, *,
         d, e, u2, vt2 = ops.fused_svd(mats, bw=cfg.bw, compute_uv=True,
                                       config=cfg)
     ub, sig, vtb = _stage3_svd(d, e, cfg)
-    # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
     with obs.span("compose"):
-        u = exact_matmul(u2, ub)
-        vt = exact_matmul(vtb, vt2)
+        u, vt = _compose(u2, ub, vtb, vt2)
     return (u.reshape(lead + (n, n)), sig.reshape(lead + (n,)),
             vt.reshape(lead + (n, n)))
 
@@ -368,6 +387,8 @@ def _uv_pipeline(a: jax.Array, *, config: tuning.PipelineConfig,
         with obs.span("stage1", **_span_attrs(a, config, tape=True)):
             band_in, s1_tape = s1.band_reduce(a, nb=config.bw, config=config,
                                               tape=True)
+        obs.count_tape_bytes("stage1", sum(x.size * x.dtype.itemsize
+                                           for x in s1_tape))
     d, e, chase_tapes = bc.bidiagonalize(band_in, bw=config.bw, tw=config.tw,
                                          config=config, tape=True)
     with obs.span("replay", n=int(n)):
@@ -375,18 +396,16 @@ def _uv_pipeline(a: jax.Array, *, config: tuning.PipelineConfig,
             n, s1_tape=s1_tape, chase_tapes=chase_tapes, lead=lead,
             dtype=a.dtype, config=config)
     ub, sig, vtb = _stage3_svd(d, e, config)
-    # A = U2 B V2^T and B = Ub S Vb^T  =>  U = U2 Ub, V^T = Vb^T V2^T.
     with obs.span("compose"):
-        u = exact_matmul(u2, ub)
-        vt = exact_matmul(vtb, vt2)
+        u, vt = _compose(u2, ub, vtb, vt2)
     return u, sig, vt
 
 
 def _checked_uv(a, out, *, check: bool):
     """Post-solve health guard for a full-SVD result (DESIGN.md §15):
-    sigma invariants, U/V^T finiteness, and the one-matrix residual
-    spot-check — the cheapest test that the FACTORS (not just the
-    spectrum) are trustworthy."""
+    sigma invariants, U/V^T finiteness, and the one-matrix residual and
+    orthogonality spot-check — the cheapest test that the FACTORS (not
+    just the spectrum) are trustworthy."""
     if check:
         u, sig, vt = out
         with obs.span("validate"):
@@ -420,8 +439,9 @@ def svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
     ``tape_apply`` call over all B*G wavefront slots per cycle).
 
     ``check=True`` (DESIGN.md §15) validates sigma, checks U/V^T
-    finiteness, and residual-spot-checks the first matrix; violations
-    raise :class:`NumericalFault`.  ``trace=`` as in
+    finiteness, and spot-checks the residual and the orthogonality of the
+    first matrix (:func:`spot_check_svd`); violations raise
+    :class:`NumericalFault`.  ``trace=`` as in
     :func:`banded_singular_values`.
     """
     if not compute_uv:

@@ -58,6 +58,11 @@ class ChaseTape:
     tau: jax.Array
     fuse: int = 1
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the tape, from its static shapes (batch included)."""
+        return sum(x.size * x.dtype.itemsize for x in (self.v, self.tau))
+
 
 def _acc_dtype(dt):
     return jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
@@ -176,7 +181,7 @@ def accumulate_transforms(n: int, *, s1_tape=None, chase_tapes=(),
         tv = tape.v.reshape((b,) + tape.v.shape[len(lead):]).astype(acc)
         tt = tape.tau.reshape((b,) + tape.tau.shape[len(lead):]).astype(acc)
         with obs.span("replay_chase", n=tape.n, b_in=tape.b_in, tw=tape.tw,
-                      fuse=tape.fuse):
+                      fuse=tape.fuse, tape_bytes=tape.nbytes):
             ut, vt = replay_chase(ut, vt, tv, tt, n=tape.n, b_in=tape.b_in,
                                   tw=tape.tw, config=config, fuse=tape.fuse)
     u = jnp.swapaxes(ut, -1, -2)

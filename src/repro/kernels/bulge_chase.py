@@ -55,6 +55,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.householder import reflector_parts
+
 __all__ = ["chase_cycle_pallas", "chase_superstep_pallas", "chase_stage_pallas"]
 
 
@@ -67,15 +69,9 @@ def _reflector_in_kernel(x, pos, axis, acc):
     """
     xa = x.astype(acc)
     alpha = jnp.sum(jnp.where(pos == 0, xa, 0), axis=axis, keepdims=True)
-    tail = jnp.where(pos > 0, xa, 0)
-    sigma = jnp.sum(tail * tail, axis=axis, keepdims=True)
-    mu = jnp.sqrt(alpha * alpha + sigma)
-    beta = jnp.where(alpha >= 0, -mu, mu)
-    safe = sigma > 0
-    denom = jnp.where(safe, alpha - beta, 1.0)
-    tau = jnp.where(safe, (beta - alpha) / jnp.where(beta == 0, 1.0, beta), 0.0)
-    v = jnp.where(pos > 0, xa / denom, 1.0)
-    return v, tau, jnp.where(safe, beta, alpha)
+    tau, v_tail, beta = reflector_parts(alpha, jnp.where(pos > 0, xa, 0),
+                                        axis=axis)
+    return jnp.where(pos > 0, v_tail, 1.0), tau, beta
 
 
 def _chase_window_vmem(wr, first, *, b_in: int, tw: int):

@@ -27,7 +27,7 @@ plus the accumulated two-sided transforms; the caller composes the final
 vectors with one batched ``bidiag_svd`` call (two dispatches total — the
 values path, the B-heavy serve workload, is the one-dispatch tier).
 
-Reflectors use a *masked* variant of ``core.householder.make_reflector``:
+Reflectors use a *masked* form of ``core.householder.reflector_parts``:
 full-length (1, n) rows or (n, 1) columns with support ``[lo, hi]``
 selected by iota masks, so every loop iteration has static shapes and
 inactive cycles (pivot past the edge) degenerate to exact no-ops through
@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.core.householder import reflector_parts
 
 __all__ = ["fused_small_svd_pallas"]
 
@@ -75,7 +77,8 @@ def _masked_reflector(x, lo, hi, idx):
     returned as a full-length masked vector of ``x``'s orientation:
     ``v[lo] = 1``, support-only tail, zeros elsewhere; ``tau``/``beta`` are
     (1, 1).  Empty / out-of-range / zero-tail supports give ``tau = 0`` —
-    same formulas and guards as ``householder.make_reflector``.
+    the formula of ``householder.reflector_parts``, which every
+    implementation shares.
     """
     dt = x.dtype
     acc = jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
@@ -83,16 +86,10 @@ def _masked_reflector(x, lo, hi, idx):
     xa = x.astype(acc)
     tail = (idx > lo) & (idx <= hi)
     alpha = jnp.sum(jnp.where(idx == lo, xa, 0), axis=axis, keepdims=True)
-    x2 = jnp.where(tail, xa, 0)
-    sigma = jnp.sum(x2 * x2, axis=axis, keepdims=True)
-    mu = jnp.sqrt(alpha * alpha + sigma)
-    beta = jnp.where(alpha >= 0, -mu, mu)
-    safe = sigma > 0
-    denom = jnp.where(safe, alpha - beta, 1.0)
-    tau = jnp.where(safe, (beta - alpha) / beta, 0.0)
-    v = jnp.where(safe, x2 / denom, 0.0) + (idx == lo).astype(acc)
-    beta_out = jnp.where(safe, beta, alpha)
-    return v.astype(dt), tau.astype(dt), beta_out.astype(dt)
+    tau, v_tail, beta = reflector_parts(alpha, jnp.where(tail, xa, 0),
+                                        axis=axis)
+    v = v_tail + (idx == lo).astype(acc)
+    return v.astype(dt), tau.astype(dt), beta.astype(dt)
 
 
 def _fix_row(a, rows2, cols2, r, lo, hi, beta, tau):

@@ -6,9 +6,10 @@ Three pieces, importable from this package root:
   that land on the profiler's clock as ``repro/<name>`` annotations, are
   recorded into a tracer only when one is active, and never change what
   runs; plus the program's own compile counter, :func:`compile_counts`,
-  and the stage-2 chase-path counter, :func:`chase_stage_counts`
-  (``trace.py``); :func:`scope` names a stage's jitted body
-  ``repro.<stage>`` for the device trace.  Ambient-tracer helpers: :func:`current`,
+  the stage-2 chase-path counter, :func:`chase_stage_counts`, and the
+  reflector-tape byte counter, :func:`tape_bytes` (``trace.py``);
+  :func:`scope` names a stage's jitted body ``repro.<stage>`` for the
+  device trace.  Ambient-tracer helpers: :func:`current`,
   :func:`activated`, :func:`install`.
 * :class:`StreamingHistogram` — mergeable fixed-log-bucket latency
   histograms with bounded memory (``hist.py``).
@@ -29,10 +30,12 @@ from .trace import (
     chase_stage_counts,
     compile_counts,
     count_chase_stage,
+    count_tape_bytes,
     current,
     install,
     scope,
     span,
+    tape_bytes,
 )
 
 __all__ = [
@@ -50,8 +53,10 @@ __all__ = [
     "chase_stage_counts",
     "compile_counts",
     "count_chase_stage",
+    "count_tape_bytes",
     "current",
     "install",
     "scope",
     "span",
+    "tape_bytes",
 ]

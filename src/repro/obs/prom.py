@@ -182,8 +182,9 @@ def render_compile_metrics() -> str:
     """Exposition text for the program's compile counter: executables
     compiled or loaded from the persistent cache, and the loads among them,
     by the innermost program span open when each happened; then the
-    stage-2 stages by chase path."""
-    from repro.obs.trace import chase_stage_counts, compile_counts
+    stage-2 stages by chase path and the bytes of reflector tape recorded
+    by stage."""
+    from repro.obs.trace import chase_stage_counts, compile_counts, tape_bytes
 
     counts = compile_counts()
     lines: list[str] = []
@@ -203,6 +204,13 @@ def render_compile_metrics() -> str:
     lines.append("# TYPE repro_chase_stages_total counter")
     for path, n in sorted(chase_stage_counts().items()):
         lines.append(_sample("repro_chase_stages_total", {"path": path},
+                             int(n)))
+    lines.append("# HELP repro_tape_bytes_total Bytes of reflector tape "
+                 "recorded, by stage, per eager call or per trace of a "
+                 "jitted pipeline.")
+    lines.append("# TYPE repro_tape_bytes_total counter")
+    for stage, n in sorted(tape_bytes().items()):
+        lines.append(_sample("repro_tape_bytes_total", {"stage": stage},
                              int(n)))
     return "\n".join(lines) + "\n"
 

@@ -24,10 +24,10 @@ what runs, only what is recorded about it.
 
 Stage scopes: :func:`scope` traces a stage's jitted body as a nested
 jitted call named ``repro.<stage>`` (``stage1``, ``stage2``, ``stage3``,
-``replay``, ``fused``).  The name lands in the ``op_name`` metadata of
-every op of the stage (``.../jit(repro.stage3)/...``), whether the stage
-runs as its own executable or is inlined into a larger one, which is how
-a profiler trace's device time is split by stage.  A ``jax.named_scope``
+``replay``, ``compose``, ``fused``).  The name lands in the ``op_name``
+metadata of every op of the stage (``.../jit(repro.stage3)/...``),
+whether the stage runs as its own executable or is inlined into a larger
+one, which is how a profiler trace's device time is split by stage.  A ``jax.named_scope``
 would put the same name into ``op_name`` only: JAX's persistent
 compilation cache hashes the module without its debug information, so a
 build without the scopes and one with them share cache entries, and a
@@ -42,7 +42,10 @@ per executable compiled or loaded from the persistent cache) and its
 innermost span open on the compiling thread.  The counts are on each
 recorded span (``compiles``, ``cache_loads``), in its JSONL export, and
 process-wide in :func:`compile_counts`, which ``obs.prom`` renders as
-``repro_compiles_total{span=...}``.
+``repro_compiles_total{span=...}``.  Beside it, :func:`chase_stage_counts`
+(``repro_chase_stages_total{path=...}``) counts stage-2 stages by chase
+path and :func:`tape_bytes` (``repro_tape_bytes_total{stage=...}``) the
+bytes of reflector tape recorded.
 """
 
 from __future__ import annotations
@@ -68,6 +71,8 @@ __all__ = [
     "compile_counts",
     "count_chase_stage",
     "chase_stage_counts",
+    "count_tape_bytes",
+    "tape_bytes",
     "scope",
 ]
 
@@ -376,3 +381,23 @@ def chase_stage_counts() -> dict[str, int]:
     """Process-wide stage-2 stage counts by chase path."""
     with _compile_lock:
         return dict(_chase_stages)
+
+
+# ----------------------------------------------------------------------
+# reflector-tape counter
+
+_tape_bytes: collections.Counter = collections.Counter()
+
+
+def count_tape_bytes(stage: str, nbytes: int) -> None:
+    """Count ``nbytes`` of reflector tape recorded by ``stage`` (``"stage1"``
+    or ``"stage2"``, DESIGN.md §8), computed from the tape's static shapes
+    where it is made; counted as :func:`count_chase_stage` counts."""
+    with _compile_lock:
+        _tape_bytes[stage] += int(nbytes)
+
+
+def tape_bytes() -> dict[str, int]:
+    """Process-wide bytes of reflector tape recorded, by stage."""
+    with _compile_lock:
+        return dict(_tape_bytes)

@@ -7,6 +7,7 @@ that a tracer changes neither what compiles nor what is returned, the
 serve dispatch's span tree, and the bounded-memory property of the
 serve-tier latency histograms."""
 
+import collections
 import glob
 import json
 import re
@@ -363,6 +364,40 @@ def test_svd_uv_trace_has_replay_children():
     assert replay.find("replay_stage1")
 
 
+def test_tape_bytes_counter_and_replay_span():
+    """``repro_tape_bytes_total`` counts each recorded tape's bytes from its
+    static shapes where it is made, stage 1's and stage 2's apart, and the
+    ``replay_chase`` span carries the bytes it replays."""
+    from repro.core import bulge_chasing as bc
+    cfg = PipelineConfig.resolve(n=16, bw=4, tw=3, backend="ref",
+                                 dtype=np.float64)
+    a = _band(16, 4)
+
+    def counted():
+        return collections.Counter(obs.tape_bytes())
+
+    c0 = counted()
+    _, _, tapes = bc.bidiagonalize(a, bw=4, tw=3, config=cfg, tape=True)
+    stage2 = sum(t.nbytes for t in tapes)
+    assert stage2 == sum(t.v.size * 8 + t.tau.size * 8 for t in tapes) > 0
+    assert counted() - c0 == {"stage2": stage2}
+    tr = Tracer("uv")
+    c0 = counted()
+    svdmod.banded_svd(a, config=cfg, trace=tr)
+    assert counted() - c0 == {"stage2": stage2}
+    (span,) = tr.roots[0].find("replay_chase")
+    assert span.attrs["tape_bytes"] == stage2
+    c0 = counted()
+    svdmod.svd(jnp.asarray(np.random.default_rng(3).standard_normal((16, 16))),
+               config=cfg)
+    grew = counted() - c0
+    assert set(grew) == {"stage1", "stage2"} and grew["stage1"] > 0
+    text = obs.render_compile_metrics()
+    assert "# TYPE repro_tape_bytes_total counter" in text
+    assert (f'repro_tape_bytes_total{{stage="stage2"}} '
+            f'{obs.tape_bytes()["stage2"]}') in text
+
+
 def _band(n, bw, seed=0, batch=None):
     rng = np.random.default_rng(seed)
     shape = (n, n) if batch is None else (batch, n, n)
@@ -380,13 +415,13 @@ _ENTRIES = {
     "svd_batched": (lambda a, c: svdmod.svd_batched(a, c), False, 2,
                     {"stage1", "stage2", "stage3"}),
     "svd": (lambda a, c: svdmod.svd(a, config=c), False, None,
-            {"stage1", "stage2", "replay", "stage3"}),
+            {"stage1", "stage2", "replay", "stage3", "compose"}),
     "banded_svd": (lambda a, c: svdmod.banded_svd(a, config=c), True, None,
-                   {"stage2", "replay", "stage3"}),
+                   {"stage2", "replay", "stage3", "compose"}),
     "fused_values": (lambda a, c: svdmod.singular_values(a, config=c),
                      False, None, {"fused"}),
     "fused_uv": (lambda a, c: svdmod.svd(a, config=c), False, None,
-                 {"fused", "stage3"}),
+                 {"fused", "stage3", "compose"}),
 }
 
 
