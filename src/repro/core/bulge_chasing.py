@@ -142,17 +142,18 @@ def stage_path(dtype, *, n: int, b_in: int, tw: int, backend: str = "auto",
                config=None, tape: bool = False) -> str:
     """``"resident"`` or ``"streamed"``: which implementation
     :func:`reduce_stage_packed` runs for one stage, from what the call can
-    observe (DESIGN.md §9).  Resident iff no tape is recorded, the
-    resolved backend is "pallas" (interpret mode off the TPU), the data is
-    32-bit and one matrix's band fits ``tuning.VMEM_BUDGET_BYTES`` as
-    ``tuning.resident_band_bytes`` counts it."""
+    observe (DESIGN.md §9).  Resident iff the resolved backend is
+    "pallas" (interpret mode off the TPU), the data is 32-bit and one
+    matrix's band — with the tape's staging slots when a tape is recorded
+    — fits ``tuning.VMEM_BUDGET_BYTES`` as ``tuning.resident_band_bytes``
+    counts it."""
     from repro.core import tuning
     from repro.kernels import ops
-    if tape or jnp.dtype(dtype).itemsize != 4:
+    if jnp.dtype(dtype).itemsize != 4:
         return "streamed"
     if ops.resolved_backend(backend, config, dtype) != "pallas":
         return "streamed"
-    fits = (tuning.resident_band_bytes(n, b_in, tw, dtype)
+    fits = (tuning.resident_band_bytes(n, b_in, tw, dtype, tape=tape)
             <= tuning.VMEM_BUDGET_BYTES)
     return "resident" if fits else "streamed"
 
@@ -176,10 +177,11 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
     ``b_in - tw`` (bulge space zeroed).
 
     Two implementations, chosen by :func:`stage_path` from the input alone
-    (DESIGN.md §9).  The *resident* path — values only, Pallas backend,
-    32-bit data, a band that fits fast memory — is one ``ops.chase_stage``
-    kernel per stage that keeps each matrix's band in VMEM and runs the
-    whole wavefront loop inside; ``fuse`` and ``unroll`` do not apply.
+    (DESIGN.md §9).  The *resident* path — Pallas backend, 32-bit data, a
+    band that fits fast memory — is one ``ops.chase_stage`` kernel per
+    stage that keeps each matrix's band in VMEM and runs the whole
+    wavefront loop inside, recording the tape when asked; ``fuse`` and
+    ``unroll`` do not apply.
     Everything else takes the *streamed* path
     (:func:`_reduce_stage_streamed`), and the two give the same band bit
     for bit.  On the streamed path all B problems advance on one
@@ -202,8 +204,9 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
     With ``tape=True`` the stage additionally records the reflector tape and
     returns ``(band, tape_v, tape_tau)`` with static shapes
     ``tape_v: (..., T, G, 2, tw+1)`` and ``tape_tau: (..., T, G, 2)`` at
-    ``fuse=1``, and ``(..., T, G, K, 2, tw+1)`` / ``(..., T, G, K, 2)``
-    fused (T = super-cycle count, K pairs per slot) — index 0 of the pair
+    ``fuse=1`` and on the resident path, and ``(..., T, G, K, 2, tw+1)`` /
+    ``(..., T, G, K, 2)`` streamed at ``fuse=K`` (T = super-cycle count, K
+    pairs per slot) — index 0 of the pair
     axis is the right reflector (accumulates into V), index 1 the left one
     (into U); inactive slots carry ``tau = 0`` (identity on replay).  The
     in-band arithmetic is byte-for-byte the same either way, so (d, e) —
@@ -220,8 +223,13 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
         from repro.kernels import ops
         band3 = band.reshape((-1,) + band.shape[-2:])
         out = ops.chase_stage(band3, n=n, b_in=b_in, tw=tw, backend=backend,
-                              config=config)
-        return out.reshape(band.shape)
+                              config=config, with_tape=tape)
+        if not tape:
+            return out.reshape(band.shape)
+        lead = band.shape[:-2]
+        out, tv, tt = out
+        return (out.reshape(band.shape), tv.reshape(lead + tv.shape[1:]),
+                tt.reshape(lead + tt.shape[1:]))
     return _reduce_stage_streamed(band, n=n, b_in=b_in, tw=tw,
                                   backend=backend, unroll=unroll,
                                   config=config, tape=tape, fuse=fuse)
@@ -420,7 +428,8 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
     is a static-length list of :class:`repro.core.transforms.ChaseTape`,
     one per stage of the tile-width plan, in execution order.  ``fuse=K``
     (explicit kwarg or ``config.fuse``) runs every streamed stage in
-    K-cycle super-steps; the tapes carry the fuse depth for replay.
+    K-cycle super-steps; the tapes carry the fuse depth for replay (1 for
+    a resident stage, which always chases in the K = 1 order).
 
     Each stage runs on the path :func:`stage_path` picks; its ``stage2``
     span carries ``path=``, and ``obs.count_chase_stage`` counts it here —
@@ -466,8 +475,10 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
                 cur, tv, tt = reduce_stage_packed(
                     cur, n=n, b_in=b_in, tw=twi, backend=backend,
                     config=config, tape=True, fuse=fuse)
-                tapes.append(transforms.ChaseTape(n=n, b_in=b_in, tw=twi,
-                                                  v=tv, tau=tt, fuse=fuse))
+                # the resident kernel always chases in the K = 1 order
+                tapes.append(transforms.ChaseTape(
+                    n=n, b_in=b_in, tw=twi, v=tv, tau=tt,
+                    fuse=1 if path == "resident" else fuse))
                 obs.count_tape_bytes("stage2", tapes[-1].nbytes)
             else:
                 cur = reduce_stage_packed(cur, n=n, b_in=b_in, tw=twi,

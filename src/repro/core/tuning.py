@@ -31,7 +31,7 @@ __all__ = [
     "max_concurrent_sweeps", "occupancy_matrix_size",
     "vmem_working_set_bytes", "default_fuse_depth", "check_vmem_budget",
     "fused_working_set_bytes", "check_fused_vmem_budget",
-    "resident_band_layout", "resident_band_bytes",
+    "resident_band_layout", "resident_band_bytes", "tape_stage_lanes",
     "DEFAULT_FUSED_CROSSOVER", "STAGE3_CHOICES",
     "stage_plan", "default_bucket_batch", "ChaseConfig", "PipelineConfig",
 ]
@@ -214,17 +214,28 @@ def resident_band_layout(n: int, b_in: int, tw: int) -> tuple[int, int]:
     return _round_up(n + w, SUBLANE), _round_up(b_in + 2 * tw + 1, LANE)
 
 
-def resident_band_bytes(n: int, b_in: int, tw: int, dtype=jnp.float32) -> int:
+def resident_band_bytes(n: int, b_in: int, tw: int, dtype=jnp.float32, *,
+                        tape: bool = False) -> int:
     """VMEM bytes the band-resident stage kernel allocates for one matrix:
     one copy of the band (copied in and out by hand, not double-buffered),
-    the shear workspace, and the (H, W) window, each at its tiled size.
-    ``reduce_stage_packed`` takes the resident path only when this fits
-    ``VMEM_BUDGET_BYTES``."""
+    the shear workspace, and the (H, W) window, each at its tiled size;
+    with ``tape``, also the two reflector-tape staging slots of (2G,
+    ``tape_stage_lanes(tw)``) each.  ``reduce_stage_packed`` takes the
+    resident path only when this fits ``VMEM_BUDGET_BYTES``."""
     rows, lanes = resident_band_layout(n, b_in, tw)
     h, w = b_in + 2 * tw + 1, b_in + tw + 1
     words = (rows * lanes + lanes * lanes
              + _round_up(h, SUBLANE) * _round_up(w, LANE))
+    if tape:
+        pairs = _round_up(2 * max_concurrent_sweeps(n, b_in, 1, tw), SUBLANE)
+        words += 2 * pairs * tape_stage_lanes(tw)
     return words * _bytes(dtype)
+
+
+def tape_stage_lanes(tw: int) -> int:
+    """Lanes of one reflector-tape row of the band-resident stage kernel:
+    v's tw + 1 entries, then tau, padded to the lane width."""
+    return _round_up(tw + 2, LANE)
 
 
 # Default fused-vs-staged crossover (DESIGN.md §13): the ROADMAP names

@@ -6,11 +6,12 @@ Memory mapping (GPU -> TPU, DESIGN.md §2):
 * reflector in shared memory (L1)   -> reflector in VMEM-resident window block
 * TPB rows held in registers        -> row tiles materialized into VREGs from
                                        the VMEM window by the vector unit
-* kernel-launch sync between cycles -> values only: one ``pallas_call``
-                                       per stage (``chase_stage_pallas``);
-                                       with a tape, one per K-cycle
-                                       super-step (``chase_superstep_pallas``;
-                                       K=1 is ``chase_cycle_pallas``)
+* kernel-launch sync between cycles -> one ``pallas_call`` per stage
+                                       (``chase_stage_pallas``), with or
+                                       without a tape; the streamed
+                                       fallback, one per K-cycle super-step
+                                       (``chase_superstep_pallas``; K=1 is
+                                       ``chase_cycle_pallas``)
 
 Each grid step owns one *rolled dense window* (H, W) of the packed band
 storage, H = b_in + 2*tw + 1, W = b_in + tw + 1 — the "1 + BW + TW" working
@@ -30,10 +31,12 @@ block load per K cycles; the kernel hands back the sheared rows and the
 wrapper un-shears them in the store (Mosaic has no roll by minus the row
 index).
 
-Band-resident stage (DESIGN.md §9): on the values-only path a grid step
-owns one matrix's whole band for a whole stage, copied into VMEM once, and
-runs the K = 1 wavefront loop inside the kernel, every window through the
-same ``_chase_window_vmem``; HBM sees the band once in and once out.
+Band-resident stage (DESIGN.md §9): a grid step owns one matrix's whole
+band for a whole stage, copied into VMEM once, and runs the K = 1
+wavefront loop inside the kernel, every window through the same
+``_chase_window_vmem``; HBM sees the band once in and once out.  A
+reflector tape leaves by one DMA per cycle from a double-buffered VMEM
+staging slot (DESIGN.md §8).
 
 The window kernels are batch-oblivious: a window neither knows nor cares
 which matrix it came from, so the batch-native pipeline (DESIGN.md §4)
@@ -310,7 +313,7 @@ def chase_superstep_pallas(blocks: jax.Array, is_first: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Band-resident stage (values only)
+# Band-resident stage
 # ---------------------------------------------------------------------------
 
 def _loop32(count: int, body) -> None:
@@ -323,14 +326,28 @@ def _loop32(count: int, body) -> None:
     jax.lax.while_loop(lambda i: i < count, step, _I0)
 
 
-def _chase_stage_kernel(band_hbm, out_hbm, rev_ref, ws_ref, win_ref, sem, *,
-                        n: int, b_in: int, tw: int, T: int, G: int):
+def _chase_stage_kernel(band_hbm, out_hbm, *refs, n: int, b_in: int,
+                        tw: int, T: int, G: int, with_tape: bool):
     """One matrix's whole stage.  ``rev_ref[c, r] = band[H-1-r, c]``: a
     window's W band columns are W consecutive rows at the dynamic sublane
     offset p, and its dense cells ``win[r + w, w] = rev[p + w, r]`` sit at
     a static lane offset w per column, so shear and un-shear are W static
     row rolls around one transpose each way of the (lanes, lanes)
-    workspace ``ws_ref``."""
+    workspace ``ws_ref``.
+
+    ``with_tape``: refs start with the tape output ``tape_hbm (B, T, 2G,
+    L)`` and end with two staging slots ``stage (2, 2G, L)`` and their DMA
+    semaphores, L = tw + 2 rounded up to the lane width.  Cycle t's pairs
+    go to slot t % 2 as ``_record_pair`` writes them, row 2g + i holding
+    reflector i's v in lanes [0, tw] and its tau in lane tw + 1; one DMA
+    copies the slot to row t of the tape while cycle t + 1 chases into the
+    other slot.  A slot is DMA'd whole: Mosaic copies only lane-aligned
+    slices."""
+    if with_tape:
+        tape_hbm, rev_ref, ws_ref, win_ref, sem, stage, tsem = refs
+        k = tw + 1
+    else:
+        rev_ref, ws_ref, win_ref, sem = refs
     h, w = b_in + 2 * tw + 1, b_in + tw + 1
     b_out = b_in - tw
     b = pl.program_id(0)
@@ -341,7 +358,11 @@ def _chase_stage_kernel(band_hbm, out_hbm, rev_ref, ws_ref, win_ref, sem, *,
     lanes = rev_ref.shape[1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
 
-    def slot(t3, r, g):
+    def tape_copy(s, t):
+        return pltpu.make_async_copy(stage.at[s], tape_hbm.at[b, t],
+                                     tsem.at[s])
+
+    def slot(t3, r, g, s=None):
         # chase_cycle_indices at fuse = 1 for cycle t = 3*t3 + r, without the
         # scalar floor division Mosaic cannot lower under jax_enable_x64
         sweep, j = t3 - g, r + 3 * g
@@ -359,7 +380,7 @@ def _chase_stage_kernel(band_hbm, out_hbm, rev_ref, ws_ref, win_ref, sem, *,
                 ws_ref[c:c + 1, :] = jnp.where((lane >= c) & (lane < h), row, 0)
             ws_ref[...] = ws_ref[...].T
             win_ref[...] = ws_ref[0:h, 0:w]
-            _chase_window_vmem(win_ref, first, b_in=b_in, tw=tw)
+            pair = _chase_window_vmem(win_ref, first, b_in=b_in, tw=tw)
             ws_ref[0:h, 0:w] = win_ref[...]
             ws_ref[...] = ws_ref[...].T
             # un-shear, writing back only the cells that map into storage
@@ -367,59 +388,113 @@ def _chase_stage_kernel(band_hbm, out_hbm, rev_ref, ws_ref, win_ref, sem, *,
                 old = rev_ref[pl.ds(p + c, 1), :]
                 new = _roll(ws_ref[c:c + 1, :], (lanes - c) % lanes, 1)
                 rev_ref[pl.ds(p + c, 1), :] = jnp.where(lane < h - c, new, old)
+            if with_tape:
+                # rows 2g (right reflector) and 2g + 1 (left), as
+                # _record_pair writes them; a ref view narrower than the
+                # lane tile is refused, so store into the slot directly
+                for i in range(2):
+                    row = pl.ds(2 * g + i, 1)
+                    stage[s, row, 0:k] = pair[2 * i].astype(stage.dtype)
+                    stage[s, row, k:k + 1] = pair[2 * i + 1].astype(
+                        stage.dtype)
+
+    def cycle(t3, r):
+        if not with_tape:
+            _loop32(G, lambda g: slot(t3, r, g))
+            return
+        t = 3 * t3 + r
+        s = t & 1
+
+        @pl.when(t < T)
+        def _():
+            # slot s last held cycle t - 2: its copy must land before the
+            # slot is cleared; inactive slots keep v = tau = 0
+            @pl.when(t >= 2)
+            def _():
+                tape_copy(s, t - 2).wait()
+            stage[s] = jnp.zeros(stage.shape[1:], stage.dtype)
+
+        _loop32(G, lambda g: slot(t3, r, g, s))
+
+        @pl.when(t < T)
+        def _():
+            tape_copy(s, t).start()
 
     # all slots of cycle t before cycle t + 1, as the streamed wavefront;
-    # cycles past T have no active slot
-    _loop32(-(-T // 3), lambda t3: _loop32(3, lambda r: _loop32(
-        G, lambda g: slot(t3, r, g))))
+    # cycles past T have no active slot (and no tape row)
+    _loop32(-(-T // 3), lambda t3: _loop32(3, lambda r: cycle(t3, r)))
+    if with_tape:
+        for t in range(max(T - 2, 0), T):     # the two copies still in flight
+            tape_copy(np.int32(t % 2), np.int32(t)).wait()
     copy = pltpu.make_async_copy(rev_ref, out_hbm.at[b], sem)
     copy.start()
     copy.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "interpret",
+                                             "with_tape"))
 def chase_stage_pallas(band: jax.Array, *, n: int, b_in: int, tw: int,
-                       interpret: bool = False):
+                       interpret: bool = False, with_tape: bool = False):
     """band: (B, H, ncols) packed storage, ncols >= n.  Runs one whole
     stage (bandwidth b_in -> b_in - tw) with each matrix's band resident in
     VMEM, in the streamed K = 1 wavefront order, so the output is the same
-    band bit for bit.  Values only: no reflector tape.
+    band bit for bit.
 
     Grid (B,): step b copies matrix b's band in once, chases every cycle of
     the stage on it, and copies it back; the output aliases the input.  The
     transpose into the resident layout (``tuning.resident_band_layout``)
-    and back is one XLA transpose each way here."""
+    and back is one XLA transpose each way here.
+
+    ``with_tape=True`` also returns the stage's reflector tape as the
+    streamed K = 1 stage records it, ``(vs (B, T, G, 2, tw+1), taus (B, T,
+    G, 2))``, with v = tau = 0 on inactive slots.  The kernel writes it to
+    HBM by one DMA per cycle from a double-buffered VMEM staging slot
+    (DESIGN.md §8): at O(n^2) words the tape cannot stay in VMEM."""
     from repro.core import tuning
     from repro.core.bulge_chasing import stage_schedule
     bsz, h, ncols0 = band.shape
     assert b_in - tw >= 1, (b_in, tw)
     assert h == b_in + 2 * tw + 1 and ncols0 >= n, (band.shape, b_in, tw)
     _, T, G = stage_schedule(n, b_in, tw)
-    rows, lanes = tuning.resident_band_layout(n, b_in, tw)
     dt = band.dtype
+    if with_tape and T == 0:
+        return (band, jnp.zeros((bsz, 0, G, 2, tw + 1), dt),
+                jnp.zeros((bsz, 0, G, 2), dt))
+    rows, lanes = tuning.resident_band_layout(n, b_in, tw)
     cols = min(ncols0, rows)          # windows never touch columns >= rows
     revt = jnp.swapaxes(band[:, ::-1, :cols], 1, 2)
     revt = jnp.pad(revt, ((0, 0), (0, rows - cols), (0, lanes - h)))
     kern = functools.partial(_chase_stage_kernel, n=n, b_in=b_in, tw=tw,
-                             T=T, G=G)
+                             T=T, G=G, with_tape=with_tape)
     any_ = pl.BlockSpec(memory_space=pl.ANY)
-    revt = pl.pallas_call(
+    out_shape = [jax.ShapeDtypeStruct(revt.shape, dt)]
+    scratch = [pltpu.VMEM((rows, lanes), dt),
+               pltpu.VMEM((lanes, lanes), dt),   # W < H lanes
+               pltpu.VMEM((h, b_in + tw + 1), dt),
+               pltpu.SemaphoreType.DMA]
+    if with_tape:
+        pairs = (2 * G, tuning.tape_stage_lanes(tw))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, T) + pairs, dt))
+        scratch += [pltpu.VMEM((2,) + pairs, dt),
+                    pltpu.SemaphoreType.DMA((2,))]
+    res = pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct(revt.shape, dt),
+        out_shape=tuple(out_shape) if with_tape else out_shape[0],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(bsz,),
             in_specs=[any_],
-            out_specs=any_,
-            scratch_shapes=[pltpu.VMEM((rows, lanes), dt),
-                            pltpu.VMEM((lanes, lanes), dt),   # W < H lanes
-                            pltpu.VMEM((h, b_in + tw + 1), dt),
-                            pltpu.SemaphoreType.DMA]),
+            out_specs=(any_,) * 2 if with_tape else any_,
+            scratch_shapes=scratch),
         input_output_aliases={0: 0},
         interpret=interpret,
         name="chase_stage",
     )(revt)
+    revt = res[0] if with_tape else res
     out = jnp.swapaxes(revt[:, :cols, :h], 1, 2)[:, ::-1]
     if cols < ncols0:
         out = jnp.concatenate([out, band[:, :, cols:]], axis=-1)
+    if with_tape:
+        tape = res[1].reshape(bsz, T, G, 2, -1)
+        return out, tape[..., :tw + 1], tape[..., tw + 1]
     return out

@@ -152,10 +152,11 @@ def _pallas_chase(windows, is_first, *, b_in, tw, with_tape, interpret,
                                               with_tape=with_tape)
 
 
-def _pallas_chase_stage(band, *, n, b_in, tw, interpret):
+def _pallas_chase_stage(band, *, n, b_in, tw, with_tape, interpret):
     from repro.kernels import bulge_chase
     return bulge_chase.chase_stage_pallas(band, n=n, b_in=b_in, tw=tw,
-                                          interpret=interpret)
+                                          interpret=interpret,
+                                          with_tape=with_tape)
 
 
 def _pallas_hh(v, t, c, *, block_cols, interpret):
@@ -244,16 +245,20 @@ def chase_cycle(windows: jax.Array, is_first: jax.Array, *, b_in: int, tw: int,
 
 
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
-                                             "interpret", "config"))
+                                             "interpret", "config",
+                                             "with_tape"))
 def chase_stage(band: jax.Array, *, n: int, b_in: int, tw: int,
                 backend: str = "auto", interpret: bool | None = None,
-                config=None) -> jax.Array:
-    """One whole values-only stage (bandwidth b_in -> b_in - tw) on packed
-    storage ``(B, H, ncols)``, each matrix's band resident in fast memory
-    for the stage (DESIGN.md §9).  Only the "pallas" backend implements
-    it; ``core.bulge_chasing.stage_path`` says when it is used."""
+                config=None, with_tape: bool = False):
+    """One whole stage (bandwidth b_in -> b_in - tw) on packed storage
+    ``(B, H, ncols)``, each matrix's band resident in fast memory for the
+    stage (DESIGN.md §9).  ``with_tape=True`` returns ``(band, vs, taus)``
+    with the stage's reflector tape in the K = 1 layout ``(B, T, G, 2,
+    tw+1)`` / ``(B, T, G, 2)``.  Only the "pallas" backend implements it;
+    ``core.bulge_chasing.stage_path`` says when it is used."""
     backend, interpret = _resolve(backend, interpret, config, band.dtype)
     return _impl("chase_stage", backend)(band, n=n, b_in=b_in, tw=tw,
+                                         with_tape=with_tape,
                                          interpret=interpret)
 
 

@@ -1,14 +1,17 @@
 """The band-resident stage-2 kernel (DESIGN.md §9), in interpret mode.
 
-``reduce_stage_packed`` runs a values-only, Pallas, 32-bit stage whose band
-fits fast memory as ONE ``chase_stage`` kernel that keeps each matrix's
-band in VMEM and runs the whole wavefront loop inside.  Covered here:
+``reduce_stage_packed`` runs a Pallas, 32-bit stage whose band fits fast
+memory as ONE ``chase_stage`` kernel that keeps each matrix's band in VMEM
+and runs the whole wavefront loop inside, recording the reflector tape
+when one is asked for.  Covered here:
 
   1. the resident stage equals the streamed K = 1 stage bit for bit, for
      one and several matrices, single- and multi-stage tile-width plans,
-     and sizes whose last sweeps' windows run off the band;
-  2. the path is chosen from the input alone: a tape, the ref backend,
-     bf16 or float64 data and an over-budget band keep the streamed path;
+     and sizes whose last sweeps' windows run off the band; so does its
+     tape, and the full SVD built on it;
+  2. the path is chosen from the input alone: the ref backend, bf16 or
+     float64 data and an over-budget band (counted with the tape's staging
+     slots when a tape is recorded) keep the streamed path;
   3. the ``stage2`` span's ``path`` attribute, the per-path stage counter
      and its Prometheus line say which path ran.
 
@@ -16,6 +19,7 @@ Interpret mode steps the whole T x G wavefront loop on the CPU, so the
 sizes stay small.
 """
 
+import dataclasses
 import re
 
 import numpy as np
@@ -117,13 +121,92 @@ def test_resident_stage_ignores_fuse():
             np.asarray(bc._reduce_stage_streamed(packed, fuse=k, **kw)), base)
 
 
+def _assert_tape_stage_matches(res, strm, n, b_in, tw):
+    """The band and tau bit for bit, v bit for bit wherever tau != 0, and
+    v = tau = 0 on every inactive wavefront slot."""
+    (out, v, tau), (ref, rv, rtau) = (tuple(np.asarray(x) for x in r)
+                                      for r in (res, strm))
+    assert v.shape == rv.shape and tau.shape == rtau.shape
+    np.testing.assert_array_equal(out, ref)
+    np.testing.assert_array_equal(tau, rtau)
+    live = rtau != 0
+    assert live.any()
+    np.testing.assert_array_equal(v[live], rv[live])
+    t, g = np.meshgrid(*map(np.arange, tau.shape[-3:-1]), indexing="ij")
+    idle = ~np.asarray(bc.chase_cycle_indices(t, g, n, b_in, tw)[3])
+    assert idle.any()
+    assert not v[..., idle, :, :].any() and not tau[..., idle, :].any()
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("case", ["stage", "plan"])
+def test_resident_tape_matches_streamed_bitwise(case, batch):
+    """The resident stage's tape against the streamed K = 1 stage's: one
+    stage whose last windows run off the band (n = 37), and every stage of
+    the plan bw 8 -> 5 -> 2 -> 1 at n = 40, tw = 3."""
+    n, bw, tw = (37, 7, 6) if case == "stage" else (40, 8, 3)
+    lead = (batch,) if batch > 1 else ()
+    cur = bandmod.pack(banded_f32(n, bw, seed=n + batch, lead=lead), bw, tw)
+    plan = tuning.stage_plan(bw, tw)
+    assert len(plan) == (1 if case == "stage" else 3)
+    tw_cur = tw
+    for b_in, twi in plan:
+        start = tw_cur - twi
+        cur = jax.lax.slice_in_dim(cur, start, start + b_in + 2 * twi + 1,
+                                   axis=-2)
+        kw = dict(n=n, b_in=b_in, tw=twi, backend="pallas", tape=True)
+        assert bc.stage_path(cur.dtype, **kw) == "resident"
+        res = bc.reduce_stage_packed(cur, **kw)
+        strm = bc._reduce_stage_streamed(cur, fuse=1, **kw)
+        assert res[1].shape[:len(lead)] == lead
+        _assert_tape_stage_matches(res, strm, n, b_in, twi)
+        cur, tw_cur = strm[0], twi
+
+
+def test_resident_tape_stage_without_cycles():
+    """n = 2 at b_in = 2 has no sweep: the band passes through and the tape
+    is empty, in the streamed stage's shapes."""
+    packed = bandmod.pack(banded_f32(2, 2, seed=1), 2, 1)
+    kw = dict(n=2, b_in=2, tw=1, backend="pallas", tape=True)
+    assert bc.stage_path(packed.dtype, **kw) == "resident"
+    res = bc.reduce_stage_packed(packed, **kw)
+    strm = bc._reduce_stage_streamed(packed, **kw)
+    for x, y in zip(res, strm):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("fuse", [1, 2])
+def test_resident_tape_svd_matches_streamed_bitwise(fuse, monkeypatch):
+    """banded_svd on the resident tape stage gives the same U, sigma and
+    V^T bits as with every stage forced onto the streamed path.  At
+    ``fuse = 2`` the resident tape is still recorded in the K = 1 order,
+    and its ``ChaseTape`` must say so for the replay to read it."""
+    n, bw, tw = 24, 6, 3
+    a = banded_f32(n, bw, seed=8)
+    cfg = PipelineConfig.resolve(n=n, bw=bw, tw=tw, backend="pallas",
+                                 dtype=jnp.float32, fuse=fuse)
+    assert cfg.fuse == fuse
+    tr = obs.Tracer("resident")
+    res = svdmod.banded_svd(a, config=cfg, trace=tr)
+    assert {sp.attrs["path"] for sp in _stage2_spans(tr)} == {"resident"}
+    monkeypatch.setattr(bc, "stage_path", lambda *_, **__: "streamed")
+    monkeypatch.setattr(bc, "reduce_stage_packed", bc._reduce_stage_streamed)
+    strm = svdmod.banded_svd(a, config=dataclasses.replace(cfg, fuse=1))
+    for x, y in zip(res, strm):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 @pytest.mark.parametrize("case,kw,want", [
     ("resident", dict(), "resident"),
-    ("tape", dict(tape=True), "streamed"),
+    ("tape", dict(tape=True), "resident"),
     ("ref", dict(backend="ref"), "streamed"),
     ("bf16", dict(dtype=jnp.bfloat16), "streamed"),
     ("float64", dict(dtype=jnp.float64), "streamed"),
     ("over_budget", dict(n=40000), "streamed"),
+    ("tape_bf16", dict(tape=True, dtype=jnp.bfloat16), "streamed"),
+    ("tape_float64", dict(tape=True, dtype=jnp.float64), "streamed"),
+    ("tape_over_budget", dict(tape=True, n=40000), "streamed"),
 ])
 def test_stage_path_selection(case, kw, want):
     args = dict(dtype=jnp.float32, n=1024, b_in=32, tw=31, backend="pallas")
@@ -145,6 +228,31 @@ def test_resident_budget_counts_the_band():
             == 8 * lanes * 4)
     assert tuning.resident_band_bytes(32000, b_in, tw) <= \
         tuning.VMEM_BUDGET_BYTES < tuning.resident_band_bytes(33000, b_in, tw)
+
+
+def test_resident_budget_counts_the_tape_staging():
+    """With a tape, resident_band_bytes adds exactly the two staging slots
+    at their tiled size (2G rows, v and tau in one lane-padded row), and a
+    band that fits only without them takes the streamed path with a
+    tape."""
+    b_in, tw = 32, 31
+    for n in (1024, 4096):
+        rows = -(-2 * tuning.max_concurrent_sweeps(n, b_in) // 8) * 8
+        extra = (tuning.resident_band_bytes(n, b_in, tw, tape=True)
+                 - tuning.resident_band_bytes(n, b_in, tw))
+        assert extra == 2 * rows * tuning.tape_stage_lanes(tw) * 4
+    # n = 1024: G = 12, so two slots of 24 rows by 128 lanes
+    assert (tuning.resident_band_bytes(1024, b_in, tw, tape=True)
+            - tuning.resident_band_bytes(1024, b_in, tw)) == 2 * 24 * 128 * 4
+    lo, hi = 1024, 1 << 16
+    while lo < hi:                   # the largest band that fits, no tape
+        mid = (lo + hi + 1) // 2
+        fits = (tuning.resident_band_bytes(mid, b_in, tw)
+                <= tuning.VMEM_BUDGET_BYTES)
+        lo, hi = (mid, hi) if fits else (lo, mid - 1)
+    kw = dict(n=lo, b_in=b_in, tw=tw, backend="pallas")
+    assert bc.stage_path(jnp.float32, **kw) == "resident"
+    assert bc.stage_path(jnp.float32, tape=True, **kw) == "streamed"
 
 
 def _stage2_spans(tracer):
@@ -169,6 +277,23 @@ def test_values_path_reports_resident():
     np.testing.assert_allclose(np.asarray(sig), ref, atol=1e-4 * ref[0])
 
 
+def test_tape_path_reports_resident():
+    """banded_svd's tape stages take the resident kernel too."""
+    n, bw = 20, 4
+    a = banded_f32(n, bw, seed=5)
+    before = obs.chase_stage_counts()
+    tr = obs.Tracer("tape")
+    svdmod.banded_svd(a, bw=bw, tw=3, backend="pallas", trace=tr)
+    spans = _stage2_spans(tr)
+    assert spans and all(sp.attrs["path"] == "resident" for sp in spans)
+    assert all(sp.attrs["tape"] for sp in spans)
+    after = obs.chase_stage_counts()
+    assert after.get("resident", 0) == before.get("resident", 0) + len(spans)
+    assert after.get("streamed", 0) == before.get("streamed", 0)
+    assert re.search(r'^repro_chase_stages_total\{path="resident"\} \d+$',
+                     obs.render_compile_metrics(), re.M)
+
+
 @pytest.mark.parametrize("kind", ["tape", "ref"])
 def test_other_paths_report_streamed(kind):
     n, bw = 20, 4
@@ -176,7 +301,9 @@ def test_other_paths_report_streamed(kind):
     before = obs.chase_stage_counts()
     tr = obs.Tracer(kind)
     if kind == "tape":
-        svdmod.banded_svd(a, bw=bw, tw=3, backend="pallas", trace=tr)
+        # a bf16 band keeps the tape on the streamed path
+        svdmod.banded_svd(a.astype(jnp.bfloat16), bw=bw, tw=3,
+                          backend="pallas", trace=tr)
     else:
         svdmod.banded_singular_values(a, bw=bw, tw=3, backend="ref",
                                       trace=tr)

@@ -13,6 +13,7 @@ must be collected identically by every worker of a parallel run.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +111,35 @@ def test_chase_stage_kernel_compiles(one_chip, x64, n):
                             band, n=n, b_in=b_in, tw=tw),
                         _spec(one_chip, (1, b_in + 2 * tw + 1, n)))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the same stage recording the reflector tape: the per-cycle DMA of a
+# staging slot to the dynamic tape row (b, t) is what Mosaic must accept
+@pytest.mark.parametrize("n", [1024, 4096])
+def test_chase_stage_tape_kernel_compiles(one_chip, x64, n):
+    b_in, tw = 32, 31
+    assert (tuning.resident_band_bytes(n, b_in, tw, tape=True)
+            <= tuning.VMEM_BUDGET_BYTES)
+    compiled = _compile(lambda band: bulge_chase.chase_stage_pallas(
+                            band, n=n, b_in=b_in, tw=tw, with_tape=True),
+                        _spec(one_chip, (1, b_in + 2 * tw + 1, n)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_uv_pipeline_compiles(one_chip, x64):
+    """The full banded SVD the chip runs at n = 1024, bw = 32: its stage 2
+    is the band-resident ``chase_stage`` kernel recording the tape, and no
+    streamed ``chase_cycle`` is left in it."""
+    n = 1024
+    cfg = tuning.PipelineConfig.resolve(bw=32, backend="pallas",
+                                        interpret=False, dtype=jnp.float32,
+                                        n=n).kernel()
+    assert bc.stage_path(jnp.float32, n=n, b_in=32, tw=cfg.tw, config=cfg,
+                         tape=True) == "resident"
+    text = _compile(lambda a: svd._uv_pipeline(a, config=cfg, banded=True),
+                    _spec(one_chip, (n, n))).as_text()
+    # (``chase_cycle_indices``, the replay's schedule, stays in op metadata)
+    assert "chase_stage" in text and not re.search(r"chase_cycle\b", text)
 
 
 # stage-1 panel replay at m = 4096 (k = nb = 32) and chase-tape replay
