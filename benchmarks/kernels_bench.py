@@ -2,9 +2,9 @@
 
 Wall-clock of one batched chase cycle (ref backend, jitted — the XLA-fused
 CPU realization of the kernel math) across (b_in, tw, wavefront width), plus
-the per-window VMEM bytes the Pallas kernel would stage on TPU, plus the
-kernel-dispatch launch-overhead probe that motivates the fuse-K super-steps
-(``_launch_overhead``; DESIGN.md §9).  Pallas interpret-mode timing is NOT a
+the per-window VMEM bytes the Pallas kernel would stage on TPU, plus a
+kernel-dispatch launch-overhead probe (``_launch_overhead``).  Pallas
+interpret-mode timing is NOT a
 performance signal (python interpreter), so the TPU projection is the
 roofline entry in EXPERIMENTS.md.
 """
@@ -21,42 +21,25 @@ from repro.kernels import ops
 CASES = [(32, 8, 4), (32, 8, 16), (64, 16, 8), (128, 32, 4), (128, 32, 16)]
 SMOKE_CASES = [(32, 8, 4), (64, 16, 8)]
 
-# launch-overhead microbenchmark: (b_in, tw, fuse depths to amortize over)
-LAUNCH_CASES = [(16, 4, (2, 4, 8)), (32, 8, (2, 4, 8))]
-LAUNCH_SMOKE = [(16, 4, (4, 8))]
+# launch-overhead microbenchmark: (b_in, tw) of a one-slot chase call
+LAUNCH_CASES = [(16, 4), (32, 8)]
+LAUNCH_SMOKE = [(16, 4)]
 
 
 def _launch_overhead(cases) -> list[str]:
-    """Fixed per-dispatch cost vs fused amortization (DESIGN.md §9).
-
-    A single-slot K=1 ``chase_cycle`` call is almost pure dispatch overhead
-    (one tiny window); the fused call retires K cycles per dispatch, so its
-    per-cycle time bounds the overhead a super-step amortizes away.  The
-    derived column reports us/cycle at each depth and the K=1 : fused
-    per-cycle ratio — the CPU-visible analogue of the paper's
-    kernel-launch-sync cost that motivates fusing.
-    """
+    """Fixed per-dispatch cost: a single-slot ``chase_cycle`` call is
+    almost pure dispatch overhead (one tiny window) — the figure
+    ``autotune.model.DeviceProfile.launch_overhead_s`` stands for."""
     out = []
     rng = np.random.default_rng(1)
-    for b_in, tw, depths in cases:
+    for b_in, tw in cases:
         h, w = b_in + 2 * tw + 1, b_in + tw + 1
         win = jnp.asarray(rng.standard_normal((1, h, w)), jnp.float32)
         first = jnp.zeros((1,), bool)
         t1 = timeit(lambda: ops.chase_cycle(win, first, b_in=b_in, tw=tw,
                                             backend="ref"),
                     warmup=2, iters=5)
-        parts = [f"us_per_cycle_K1={t1 * 1e6:.1f}"]
-        for k in depths:
-            wk = k * b_in + tw + 1
-            blk = jnp.asarray(rng.standard_normal((1, h, wk)), jnp.float32)
-            act = jnp.ones((1, k), bool)
-            tk = timeit(lambda blk=blk, act=act, k=k: ops.chase_cycle(
-                blk, first, b_in=b_in, tw=tw, fuse=k, active=act,
-                backend="ref"), warmup=2, iters=5)
-            parts.append(f"us_per_cycle_K{k}={tk / k * 1e6:.1f}")
-            parts.append(f"overhead_ratio_K{k}={t1 * k / tk:.2f}x")
-        out.append(row(f"chase_launch/b{b_in}_tw{tw}", t1 * 1e6,
-                       ";".join(parts)))
+        out.append(row(f"chase_launch/b{b_in}_tw{tw}", t1 * 1e6, "slots=1"))
     return out
 
 

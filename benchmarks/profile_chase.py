@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m benchmarks.profile_chase \\
         [--out chiprun_out/profile_chase]
 
-For fuse depths 1 and 4: a seeded random upper-banded f32 matrix
-(n = 1024, bw = 32, the banded chase of ``chip_smoke.py``) goes through
+A seeded random upper-banded f32 matrix (n = 1024, bw = 32, the banded
+chase of ``chip_smoke.py``) goes through
 ``banded_singular_values`` (stage 2 + bisection) under one ``jax.jit``, so
 the whole call is one executable whose optimized HLO is known.  One call
 warms up, one steady call runs under ``jax.profiler.trace``.  Every device
@@ -14,17 +14,16 @@ a dynamic-update-slice, other.  A fusion takes the class of the ops it
 fuses.  ``while`` ops contain their body's ops and are reported on their
 own, outside the class sums.
 
-Prints, per fuse depth: wall time of the traced call, device busy time
-and span of the executable (idle share = 1 - busy / span), time per
-class and the top ops.  Writes the same as JSON to ``<out>/profile.json``
-and keeps the traces under ``<out>/trace_fuse<K>``.  Needs a TPU.
+Prints the wall time of the traced call, device busy time and span of
+the executable (idle share = 1 - busy / span), time per class and the top
+ops.  Writes the same as JSON to ``<out>/profile.json`` and keeps the
+trace under ``<out>/trace``.  Needs a TPU.
 """
 
 from __future__ import annotations
 
 import argparse
 import collections
-import dataclasses
 import glob
 import json
 import os
@@ -37,7 +36,7 @@ import numpy as np
 
 from repro.core import svd, tuning
 
-N, BW, FUSES, SEED = 1024, 32, (1, 4), 0
+N, BW, SEED = 1024, 32, 0
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([a-z][\w\-]*)\(")
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
@@ -162,29 +161,26 @@ def main(argv=None) -> int:
     dense = np.triu(rng.standard_normal((N, N)))
     band = (dense - np.triu(dense, BW + 1)).astype(np.float32)
     a = jax.device_put(band)
-    base = tuning.PipelineConfig.resolve(bw=BW, n=N, dtype=jnp.float32)
-    report = {}
-    for k in FUSES:
-        cfg = dataclasses.replace(base, fuse=k).kernel()
-        compiled = jax.jit(lambda m, cfg=cfg: svd.banded_singular_values(
-            m, config=cfg)).lower(a).compile()
-        compiled(a).block_until_ready()                       # warm-up
-        trace_dir = os.path.join(args.out, f"trace_fuse{k}")
-        with jax.profiler.trace(trace_dir):
-            t0 = time.perf_counter()
-            compiled(a).block_until_ready()
-            wall = time.perf_counter() - t0
-        rep = device_breakdown(trace_dir, hlo_classes(compiled.as_text()))
-        rep.update(config=repr(cfg), wall_s=wall)
-        report[f"fuse{k}"] = rep
-        print(f"n={N} bw={BW} tw={cfg.tw} fuse={k}: traced call "
-              f"{wall:.4f}s wall; device busy {rep['busy_s']:.4f}s of "
-              f"{rep['span_s']:.4f}s span (idle {rep['idle_share']:.2%})")
-        for cls, sec in rep["classes"].items():
-            print(f"  {cls:22s} {sec:10.4f}s")
-        for row in rep["top_ops"]:
-            print(f"    {row['seconds']:10.4f}s x{row['calls']:6d} "
-                  f"[{row['cls']}] {row['op']}")
+    cfg = tuning.PipelineConfig.resolve(bw=BW, n=N,
+                                        dtype=jnp.float32).kernel()
+    compiled = jax.jit(lambda m: svd.banded_singular_values(
+        m, config=cfg)).lower(a).compile()
+    compiled(a).block_until_ready()                           # warm-up
+    trace_dir = os.path.join(args.out, "trace")
+    with jax.profiler.trace(trace_dir):
+        t0 = time.perf_counter()
+        compiled(a).block_until_ready()
+        wall = time.perf_counter() - t0
+    report = device_breakdown(trace_dir, hlo_classes(compiled.as_text()))
+    report.update(config=repr(cfg), wall_s=wall)
+    print(f"n={N} bw={BW} tw={cfg.tw}: traced call {wall:.4f}s wall; "
+          f"device busy {report['busy_s']:.4f}s of {report['span_s']:.4f}s "
+          f"span (idle {report['idle_share']:.2%})")
+    for cls, sec in report["classes"].items():
+        print(f"  {cls:22s} {sec:10.4f}s")
+    for row in report["top_ops"]:
+        print(f"    {row['seconds']:10.4f}s x{row['calls']:6d} "
+              f"[{row['cls']}] {row['op']}")
     with open(os.path.join(args.out, "profile.json"), "w") as f:
         json.dump(report, f, indent=1)
     return 0

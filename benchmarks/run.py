@@ -48,7 +48,7 @@ import jax.numpy as jnp
 jax.config.update("jax_enable_x64", True)
 
 SUITES = ["accuracy", "hyperparams", "occupancy", "scaling", "precision",
-          "kernels_bench", "fusion", "batched", "vectors", "fused_small",
+          "kernels_bench", "batched", "vectors", "fused_small",
           "serve_load", "stage3"]
 
 

@@ -99,7 +99,7 @@ def _requests(mix, count, seed=0):
 def _tune_bucket_cache(mix, *, backend="ref", seed=0):
     """Batch-axis autotune for every bucket in the mix (DESIGN.md §11).
 
-    Full (non-smoke) mode only: searches ``(tw, fuse, batch)`` including
+    Full (non-smoke) mode only: searches ``(tw, batch)`` including
     the batch axis for each distinct ``(n, bw, dtype, uv)`` and persists
     the winners to one throwaway cache file; the engine then consumes it
     via ``autotune=True`` — the measured ``max_batch`` replaces the Eq.-1
@@ -116,7 +116,7 @@ def _tune_bucket_cache(mix, *, backend="ref", seed=0):
     bests = []
     for n, bw, dtype, uv, _w in mix:
         res = run_search(n, bw, dtype=np.dtype(dtype), backend=backend,
-                         compute_uv=uv, top_k=2, fuses=(1, 2),
+                         compute_uv=uv, top_k=2,
                          batches=(4, 8, 16), iters=1, seed=seed)
         at_cache.store(res.to_entry(), device_kind=at_model.device_kind(),
                        n=n, bw=bw, dtype=np.dtype(dtype).name,
@@ -841,7 +841,7 @@ def main(argv=None) -> None:
         cache, bests = _tune_bucket_cache(mix, seed=args.seed)
         for (n, bw, dt, uv, _w), best in zip(mix, bests):
             print(f"# tuned bucket n={n} bw={bw} {dt} uv={int(uv)}: "
-                  f"tw={best.tw} fuse={best.fuse} max_batch={best.batch}",
+                  f"tw={best.tw} max_batch={best.batch}",
                   flush=True)
     faults_thr = faults_poi = None
     if args.chaos:

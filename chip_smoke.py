@@ -2,8 +2,8 @@
 """Smoke test of the SVD system on a TPU: the quickest proof that it still
 starts on the chip and gives right answers there.
 
-    python chip_smoke.py             # one chip: served path, banded chase
-                                     # (K=1 and fused), one large reduction
+    python chip_smoke.py             # one chip: served path, banded chase,
+                                     # one large reduction
     python chip_smoke.py --chips 4   # four chips: one bucket sharded over a
                                      # ("data",) mesh vs the same bucket on one chip
 
@@ -209,11 +209,10 @@ def large_phase(seed: int, n: int, bw: int) -> None:
           f"{sigma_tol(n):.3e}")
 
 
-def chase_phase(seed: int, n: int, bw: int, fuse: int) -> None:
+def chase_phase(seed: int, n: int, bw: int) -> None:
     """A banded matrix through ``banded_singular_values`` (stages 2 + 3)
-    with the K=1 chase kernel and with K-cycle super-steps."""
-    import dataclasses
-
+    on the default configuration: its stage 2 is the band-resident chase
+    kernel."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -224,28 +223,19 @@ def chase_phase(seed: int, n: int, bw: int, fuse: int) -> None:
     dense = np.triu(rng.standard_normal((n, n)))
     band = dense - np.triu(dense, bw + 1)            # upper band, width bw
     a = jax.device_put(band.astype(np.float32))
-    base = tuning.PipelineConfig.resolve(bw=bw, n=n, dtype=jnp.float32)
-    sigmas = {}
-    for k in (1, fuse):
-        cfg = dataclasses.replace(base, fuse=k)
-        check_chip_config(cfg, f"chase fuse={k}")
-        t0 = time.perf_counter()
-        sig = svd.banded_singular_values(a, config=cfg).block_until_ready()
-        t_first = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        sig = svd.banded_singular_values(a, config=cfg).block_until_ready()
-        t_steady = time.perf_counter() - t0
-        sigmas[k] = np.asarray(sig)
-        err = reference.sigma_error(sigmas[k], band)
-        log(f"chase: n={n} bw={bw} tw={cfg.tw} fuse={k} "
-            f"max|dsigma|/sigma_max={err:.3e} (tol {sigma_tol(n):.3e}); "
-            f"first call {t_first:.3f}s, steady {t_steady:.3f}s")
-        check(err <= sigma_tol(n), f"chase fuse={k}: sigma error {err:.3e}")
-    agree = float(np.max(np.abs(sigmas[fuse] - sigmas[1]))
-                  / np.max(sigmas[1]))
-    log(f"chase: fuse={fuse} vs fuse=1 max|dsigma|/sigma_max={agree:.3e}")
-    check(agree <= sigma_tol(n), f"chase: fuse={fuse} and fuse=1 differ by "
-          f"{agree:.3e}")
+    cfg = tuning.PipelineConfig.resolve(bw=bw, n=n, dtype=jnp.float32)
+    check_chip_config(cfg, "chase")
+    t0 = time.perf_counter()
+    sig = svd.banded_singular_values(a, config=cfg).block_until_ready()
+    t_first = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    sig = svd.banded_singular_values(a, config=cfg).block_until_ready()
+    t_steady = time.perf_counter() - t0
+    err = reference.sigma_error(np.asarray(sig), band)
+    log(f"chase: n={n} bw={bw} tw={cfg.tw} "
+        f"max|dsigma|/sigma_max={err:.3e} (tol {sigma_tol(n):.3e}); "
+        f"first call {t_first:.3f}s, steady {t_steady:.3f}s")
+    check(err <= sigma_tol(n), f"chase: sigma error {err:.3e}")
 
 
 def sharded_phase(seed: int, devices, n: int, bw: int, batch: int) -> None:
@@ -328,7 +318,7 @@ def main(argv=None) -> int:
             sharded_phase(args.seed, devices[:4], n=512, bw=32, batch=8)
         else:
             served_phase(args.seed)
-            chase_phase(args.seed, n=1024, bw=32, fuse=4)
+            chase_phase(args.seed, n=1024, bw=32)
             large_phase(args.seed, LARGE_N, bw=32)
     except SmokeFailure as exc:
         print(f"chip_smoke: FAILED: {exc}", file=sys.stderr)

@@ -75,26 +75,9 @@ print(f"full SVD: max recon err {recon:.2e}, max |U^T U - I| {orth:.2e}, "
 assert recon < 1e-10 and orth < 1e-12
 assert np.array_equal(sigma3, sigma4)
 
-# --- 5. cycle-fused chase super-steps (PipelineConfig.fuse) ------------------
-# fuse=K chases K consecutive cycles of each sweep per kernel dispatch inside
-# one VMEM-resident (H, K*b_in + tw + 1) band block: each cycle costs ~1/K of
-# a contiguous HBM block round trip instead of its own sheared window
-# gather/scatter, launches drop 3*nsweeps -> 2*nsweeps, and numerics are
-# invariant (DESIGN.md §9).  fuse=None asks the VMEM performance model for
-# the deepest super-step that fits (tuning.default_fuse_depth).
-import dataclasses
-fused_cfg = dataclasses.replace(cfg, fuse=4)
-sigma5 = np.asarray(svd_batched(jnp.asarray(stack), config=fused_cfg))
-auto = PipelineConfig.resolve(bw=8, dtype=jnp.float64, n=k, fuse=None)
-print(f"fuse=4 max |sigma - sigma(fuse=1)| = "
-      f"{np.abs(sigma5 - sigma3).max():.2e}; "
-      f"VMEM-model default fuse depth for bw=8: {auto.fuse}")
-assert np.abs(sigma5 - sigma3).max() < 1e-12
-print("OK")
-
-# --- 6. hardware-aware autotuning (DESIGN.md §11) ----------------------------
+# --- 5. hardware-aware autotuning (DESIGN.md §11) ----------------------------
 # The closed-form defaults above are a guess about this host; the autotuner
-# measures the truth.  The analytic cost model ranks the (tw, fuse, batch)
+# measures the truth.  The analytic cost model ranks the (tw, batch)
 # grid, only the top-K (plus the static default) are timed, and the winner is
 # persisted to a JSON cache keyed by (device, n, bw, dtype, uv, backend) —
 # which resolve(autotune=True) then consults.  CLI equivalent:
@@ -104,20 +87,20 @@ import tempfile
 from repro.autotune import cache as at_cache, model as at_model, run_search
 
 cache_file = os.path.join(tempfile.mkdtemp(), "autotune.json")
-res = run_search(64, 8, backend="ref", top_k=2, fuses=(1, 2), iters=1)
+res = run_search(64, 8, backend="ref", top_k=2, iters=1)
 print(res.table())
 at_cache.store(res.to_entry(), device_kind=at_model.device_kind(), n=64,
                bw=8, dtype="float32", compute_uv=False, backend="ref",
                path=cache_file)
 tuned = PipelineConfig.resolve(n=64, bw=8, backend="ref", autotune=True,
                                autotune_cache=cache_file)
-assert (tuned.tw, tuned.fuse) == (res.best.tw, res.best.fuse)
+assert tuned.tw == res.best.tw
 assert res.best.measured_s <= res.default.measured_s   # beats or ties default
 print(f"tuned config for n=64, bw=8 on this host: tw={tuned.tw} "
-      f"fuse={tuned.fuse} max_batch={tuned.max_batch}")
+      f"max_batch={tuned.max_batch}")
 print("OK")
 
-# --- 7. async serving: concurrent requests -> micro-batched buckets ----------
+# --- 6. async serving: concurrent requests -> micro-batched buckets ----------
 # (DESIGN.md §12)  Callers from any thread (or asyncio task) submit and get a
 # future; the engine aggregates concurrent same-shape requests into one
 # batched pipeline call per bucket — the batch axis of section 3, fed by
@@ -152,7 +135,7 @@ assert len(done) == 12 and worst < 1e-10
 assert snap["completed"] == 12 and snap["failed"] == 0
 print("OK")
 
-# --- 8. fused small-n tier: the whole pipeline as ONE dispatch ---------------
+# --- 7. fused small-n tier: the whole pipeline as ONE dispatch ---------------
 # (DESIGN.md §13)  Below the fused crossover the staged pipeline's per-stage
 # dispatches are pure overhead on a VMEM-resident problem: backend
 # "fused_small" runs band reduction, the whole bulge chase, and the Sturm
@@ -179,7 +162,7 @@ print(f"serve routing: n=24 bucket -> tier={tier['tier']!r} "
 assert tier["tier"] == "fused" and snap["tiers"]["fused"]["batches"] >= 1
 print("OK")
 
-# --- 9. divide-and-conquer stage 3: the large-n end (DESIGN.md §14) ----------
+# --- 8. divide-and-conquer stage 3: the large-n end (DESIGN.md §14) ----------
 # The Sturm bisection's critical path grows like n (every sweep is a
 # sequential depth-2n recurrence); Cuppen's D&C replaces it with log2(n/32)
 # secular merge levels whose deflated blocks are skipped at run time, so past
@@ -214,7 +197,7 @@ print(f"stage 3 at n={n9}: bisect {t_bi:.2f}s, dc {t_dc:.2f}s "
 assert agree9 < 1e-12
 print("OK")
 
-# --- 10. fault tolerance: injected faults, absorbed (DESIGN.md §15) ----------
+# --- 9. fault tolerance: injected faults, absorbed (DESIGN.md §15) ----------
 # A serving tier that only works when nothing fails is a benchmark, not a
 # service.  Inject a deterministic fault plan — the FIRST dispatch raises,
 # and the next result comes back NaN-poisoned — and watch the fabric absorb
@@ -252,7 +235,7 @@ assert health["client_error_rate"] == 0.0
 assert snap10["retried"] + snap10["degraded"] >= 1
 print("OK")
 
-# --- 11. tracing: what did the host do in one banded call? ------------------
+# --- 10. tracing: what did the host do in one banded call? ------------------
 # (DESIGN.md §16)  Pass a Tracer into any core.svd entry point to record
 # its host spans (config, pack, one stage2 per tile-width stage, extract,
 # stage3) with the compiles counted under each.  The spans are always on as
@@ -279,7 +262,7 @@ assert [c.name for c in root11.children] == [
     "config", "pack", "stage2", "extract", "stage3"]
 print("OK")
 
-# --- 12. multi-host serving: router + two local worker processes -------------
+# --- 11. multi-host serving: router + two local worker processes -------------
 # (DESIGN.md §17)  The serve tier across PROCESS boundaries: SVDRouter owns
 # admission and pins each shape-bucket to one worker host (rendezvous
 # hashing keeps micro-batching intact); each worker is a real subprocess

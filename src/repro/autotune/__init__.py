@@ -9,7 +9,7 @@ persistent and falsifiable:
 * :mod:`repro.autotune.measure` — the one blocking/jit-warmup timing
   harness (the ``benchmarks/`` suites reuse it);
 * :mod:`repro.autotune.search`  — model-pruned search: rank the full
-  ``(tw, fuse, batch)`` grid by predicted cost, time only the top-K (plus
+  ``(tw, batch)`` grid by predicted cost, time only the top-K (plus
   the static default), report predicted-vs-measured error;
 * :mod:`repro.autotune.cache`   — persistent JSON cache keyed by
   ``(device_kind, n, bw, dtype, compute_uv, backend)``; atomic writes,

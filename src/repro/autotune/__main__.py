@@ -6,7 +6,7 @@ persist the winners to the tuned-config cache.
       --shapes n=256:bw=16,n=512:bw=32 --backend ref --top-k 3 --iters 2
 
 Each ``--shapes`` item is ``n=<int>:bw=<int>``.  The winning
-``(tw, fuse, max_batch)`` per shape is merged into the cache at
+``(tw, max_batch)`` per shape is merged into the cache at
 ``--cache`` / ``$REPRO_AUTOTUNE_CACHE`` / the XDG default, keyed by
 ``(device_kind, n, bw, dtype, compute_uv, backend)`` — exactly the key
 ``PipelineConfig.resolve(autotune=True)`` then looks up.  ``--no-store``
@@ -47,7 +47,7 @@ def parse_shapes(spec: str) -> list[tuple[int, int]]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.autotune",
-        description="Tune (tw, fuse, batch) per shape; persist the winners.")
+        description="Tune (tw, batch) per shape; persist the winners.")
     ap.add_argument("--shapes", required=True,
                     help="comma list of n=<int>:bw=<int> items")
     ap.add_argument("--backend", default="auto",
@@ -68,11 +68,11 @@ def main(argv=None) -> int:
     ap.add_argument("--no-store", action="store_true",
                     help="print the table only; do not write the cache")
     ap.add_argument("--fused-crossover", action="store_true",
-                    help="instead of the (tw, fuse, batch) grid, measure the "
+                    help="instead of the (tw, batch) grid, measure the "
                          "fused-vs-staged crossover per --shapes bw "
                          "(DESIGN.md §13) and persist fused_n_max")
     ap.add_argument("--stage3-crossover", action="store_true",
-                    help="instead of the (tw, fuse, batch) grid, measure the "
+                    help="instead of the (tw, batch) grid, measure the "
                          "stage-3 bisect-vs-dc crossover up to the largest "
                          "--shapes n (DESIGN.md §14) and persist dc_n_min")
     ap.add_argument("--trace-jsonl", default="", metavar="PATH",

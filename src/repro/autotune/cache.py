@@ -2,7 +2,7 @@
 
 Entries are keyed by everything that shifts the optimum —
 ``(device_kind, n, bw, dtype, compute_uv, backend)`` — and hold the tuned
-knobs ``(tw, fuse, max_batch)`` plus the provenance needed to audit them
+knobs ``(tw, max_batch)`` plus the provenance needed to audit them
 (measured/predicted times, tuner version, jax version, timestamp).
 ``PipelineConfig.resolve(autotune=True)`` looks entries up and falls back
 to the analytic defaults on a miss; ``python -m repro.autotune`` writes
@@ -71,19 +71,18 @@ def lookup(*, device_kind: str, n: int, bw: int, dtype: str,
            ) -> dict | None:
     """The tuned entry for a pipeline key, or None (fall back to defaults).
 
-    Entries missing either kernel knob (``tw``, ``fuse``) are treated as
-    corrupt (None) so a half-written record can never half-configure a
-    pipeline.  ``max_batch`` is OPTIONAL — the search only persists it
-    when the batch axis was actually explored; when present it must be a
-    valid int >= 1 or the whole entry is rejected.
+    An entry without a valid kernel knob ``tw`` is treated as corrupt
+    (None).  ``max_batch`` is OPTIONAL — the search only persists it when
+    the batch axis was actually explored; when present it must be a valid
+    int >= 1 or the whole entry is rejected.  Keys it does not read (an
+    older tuner's retired knobs) are ignored, so older cache files load.
     """
     entry = load(path)["entries"].get(make_key(
         device_kind=device_kind, n=n, bw=bw, dtype=dtype,
         compute_uv=compute_uv, backend=backend))
     if not isinstance(entry, dict):
         return None
-    if not all(isinstance(entry.get(k), int) and entry[k] >= 1
-               for k in ("tw", "fuse")):
+    if not (isinstance(entry.get("tw"), int) and entry["tw"] >= 1):
         return None
     if "max_batch" in entry and not (isinstance(entry["max_batch"], int)
                                      and entry["max_batch"] >= 1):
@@ -129,8 +128,8 @@ def store(entry: dict, *, device_kind: str, n: int, bw: int, dtype: str,
 # The fused-vs-staged crossover is a property of (device, dtype, uv[, bw]),
 # not of one (n, bw) shape, so it gets its own key family in the SAME
 # entries dict ("crossover|..." never collides with make_key's "device=..."
-# namespace, and the per-shape ``lookup`` validation — which demands tw/fuse
-# — never sees these entries).
+# namespace, and the per-shape ``lookup`` validation — which demands tw —
+# never sees these entries).
 
 def crossover_key(*, device_kind: str, dtype: str, compute_uv: bool,
                   bw: int | None = None) -> str:

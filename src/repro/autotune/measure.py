@@ -5,7 +5,7 @@ autotuner and the ``benchmarks/`` suites.
 ``block_until_ready`` wall times (``benchmarks/common.timeit`` delegates
 here, so a fix to the methodology lands everywhere at once).
 ``time_stage2`` is the autotuner's workload: one batched stage-2 reduction
-at a candidate ``(tw, fuse, batch)`` — either the full ``bw -> 1``
+at a candidate ``(tw, batch)`` — either the full ``bw -> 1``
 tile-width plan (``full=True``, what the search ranks: it charges small
 ``tw`` for the extra stages it forces) or a single stage at the entry
 bandwidth (``full=False``, what ``benchmarks/hyperparams.py`` sweeps).
@@ -67,7 +67,7 @@ def banded_input(n: int, bw: int, *, batch: int = 1, dtype=jnp.float32,
     return jnp.asarray(a.astype(jnp.dtype(dtype).name))
 
 
-def time_stage2(n: int, bw: int, *, tw: int, fuse: int = 1, batch: int = 1,
+def time_stage2(n: int, bw: int, *, tw: int, batch: int = 1,
                 backend: str = "ref", dtype=jnp.float32, tape: bool = False,
                 full: bool = True, warmup: int = 1, iters: int = 3,
                 seed: int = 0) -> float:
@@ -88,15 +88,13 @@ def time_stage2(n: int, bw: int, *, tw: int, fuse: int = 1, batch: int = 1,
     if full:
         def call():
             out = bc.bidiagonalize_packed(packed, n=n, bw=bw, tw=tw,
-                                          backend=backend, tape=tape,
-                                          fuse=fuse)
+                                          backend=backend, tape=tape)
             return out[:2] if tape else out
     else:
         def call():
             return bc.reduce_stage_packed(packed, n=n, b_in=bw, tw=tw0,
-                                          backend=backend, tape=tape,
-                                          fuse=fuse)
+                                          backend=backend, tape=tape)
 
     return measure_seconds(
         call, warmup=warmup, iters=iters,
-        label=f"time_stage2/n{n}/bw{bw}/tw{tw}/fuse{fuse}/b{batch}")
+        label=f"time_stage2/n{n}/bw{bw}/tw{tw}/b{batch}")

@@ -4,18 +4,15 @@ made falsifiable).
 The paper's methodological core is a *hardware-aware performance model* that
 ranks configurations before any kernel runs; measurement then only has to
 confirm (or refute) the top of the ranking.  This module is that model for
-our wavefront chase: given a candidate ``(tw, fuse, batch)`` it composes
+our wavefront chase: given a candidate ``(tw, batch)`` it composes
 
-* **bytes moved** — from the packed-band layout: one fused super-step
-  streams the contiguous block ``(H, W_K)``, ``H = b_in + 2*tw + 1``,
-  ``W_K = fuse*b_in + tw + 1``, through fast memory once per K retired
-  cycles, i.e. each chase cycle costs ``2*H*W_K/K`` words of slow-memory
-  round trip (gather + scatter; the amortized form of DESIGN.md §9 — the
-  sub-leading ceil waste of partially-dead final super-steps is ignored so
-  the model stays strictly monotone in the knobs it ranks);
-* **launch overhead** — one fused dispatch per super-cycle ``T`` regardless
-  of batch (the batch axis folds into the same grid), amortized by ``fuse``
-  through the super-cycle count ``T(K) ~ sep(K)*nsweeps``;
+* **bytes moved** — from the packed-band layout: each chase cycle streams
+  its window ``(H, W)``, ``H = b_in + 2*tw + 1``, ``W = b_in + tw + 1``,
+  through fast memory once, i.e. costs ``2*H*W`` words of slow-memory
+  round trip (gather + scatter);
+* **launch overhead** — one dispatch per wavefront cycle ``T ~
+  3*nsweeps`` regardless of batch (the batch axis folds into the same
+  grid);
 * **wavefront occupancy** — paper Eq. 1: achieved bandwidth scales with the
   fraction of execution units the ``batch * G`` concurrent windows cover,
   saturating at 1;
@@ -60,7 +57,7 @@ class DeviceProfile:
 
     ``mem_bw`` is the achievable slow-memory stream bandwidth feeding the
     chase (HBM on TPU/GPU; DRAM on the CPU ref path), ``launch_overhead_s``
-    the per-dispatch fixed cost being amortized by ``fuse`` (measured by
+    the per-dispatch fixed cost (measured by
     ``benchmarks/kernels_bench.py::_launch_overhead``), ``fast_mem_bytes``
     the per-core budget the working set must fit (VMEM on TPU; the model
     reuses it as the residency cliff on every platform), and
@@ -69,7 +66,7 @@ class DeviceProfile:
     """
     device_kind: str
     mem_bw: float                   # bytes/s
-    launch_overhead_s: float        # per fused dispatch
+    launch_overhead_s: float        # per dispatch
     fast_mem_bytes: int             # residency budget per core
     execution_units: int
 
@@ -92,7 +89,7 @@ PROFILES: dict[str, DeviceProfile] = {
     # TPU/ref.  fast_mem ~ L2-resident working set.
     "gpu": DeviceProfile("gpu", mem_bw=1.0e12, launch_overhead_s=5e-6,
                          fast_mem_bytes=32 * 2 ** 20, execution_units=64),
-    # CPU ref path: the "launch" is one fori_loop super-cycle of the jnp
+    # CPU ref path: the "launch" is one fori_loop cycle of the jnp
     # wavefront (~hundreds of us — see BENCH_stage2.json chase_launch rows),
     # which dominates; mem_bw is a DRAM-stream figure.
     "cpu": DeviceProfile("cpu", mem_bw=2.0e10, launch_overhead_s=250e-6,
@@ -127,11 +124,9 @@ def profile_for(kind: str | None = None) -> DeviceProfile:
 # ---------------------------------------------------------------------------
 
 def total_chase_cycles(n: int, b_in: int, tw: int) -> int:
-    """Fuse-invariant count of chase cycles one stage executes.
+    """Count of chase cycles one stage executes, over all sweeps.
 
-    Sweep R runs local cycles 0..j_max(R), ``j_max = (n-1-R-b_out)//b_in``
-    (canonical home of the count; ``benchmarks/fusion.py`` reports it as the
-    honest throughput axis).
+    Sweep R runs local cycles 0..j_max(R), ``j_max = (n-1-R-b_out)//b_in``.
     """
     b_out = b_in - tw
     return sum((n - 1 - r - b_out) // b_in + 1
@@ -145,8 +140,8 @@ class CostBreakdown:
     mem_seconds: float
     launch_seconds: float
     bytes_moved: float              # slow-memory round-trip bytes, all slots
-    cycles: int                     # chase cycles (fuse-invariant)
-    supercycles: int                # fused dispatches
+    cycles: int                     # chase cycles, all sweeps
+    dispatches: int                 # kernel dispatches (wavefront cycles)
     wavefront: int                  # concurrent windows per matrix (G)
     occupancy: float                # Eq.-1 utilization in [1/eu, 1]
     vmem_bytes: int                 # per-slot working set vs the budget
@@ -157,44 +152,43 @@ class CostBreakdown:
         return self.seconds          # callers divide by batch explicitly
 
 
-def stage_cost(n: int, b_in: int, tw: int, *, fuse: int = 1, batch: int = 1,
+def stage_cost(n: int, b_in: int, tw: int, *, batch: int = 1,
                dtype=jnp.float32, profile: DeviceProfile | None = None,
                tape: bool = False) -> CostBreakdown:
     """Predicted wall seconds of ONE batched stage reduction ``b_in ->
-    b_in - tw`` at super-step depth ``fuse`` (the model of the module
-    docstring).  Infeasible working sets return ``seconds=inf``."""
+    b_in - tw`` (the model of the module docstring).  Infeasible working
+    sets return ``seconds=inf``."""
     from repro.core import bulge_chasing as bc
 
     prof = profile if profile is not None else profile_for()
     assert 1 <= tw <= b_in - 1 or b_in == 1, (b_in, tw)
-    assert fuse >= 1 and batch >= 1, (fuse, batch)
+    assert batch >= 1, batch
     s = jnp.dtype(dtype).itemsize
     h = b_in + 2 * tw + 1
-    wk = fuse * b_in + tw + 1
+    w = b_in + tw + 1
     cycles = total_chase_cycles(n, b_in, tw)
-    _, supercycles, g = bc.stage_schedule(n, b_in, tw, fuse)
-    vmem = tuning.vmem_working_set_bytes(b_in, tw, dtype, fuse=fuse,
-                                         tape=tape)
+    _, dispatches, g = bc.stage_schedule(n, b_in, tw)
+    vmem = tuning.vmem_working_set_bytes(b_in, tw, dtype, tape=tape)
     feasible = vmem <= prof.fast_mem_bytes
-    # Amortized slow-memory traffic: each cycle costs 1/K of a contiguous
-    # (H, W_K) block round trip (gather + scatter), plus its tape slice.
-    words_per_cycle = 2.0 * h * wk / fuse
+    # Slow-memory traffic: each cycle's (H, W) window round trip (gather +
+    # scatter), plus its tape slice.
+    words_per_cycle = 2.0 * h * w
     if tape:
         words_per_cycle += 2.0 * (tw + 2)      # (v, tau) pair per cycle
     bytes_moved = batch * cycles * words_per_cycle * s
     occupancy = min(1.0, batch * max(g, 1) / prof.execution_units)
     occupancy = max(occupancy, 1.0 / prof.execution_units)
     t_mem = bytes_moved / (prof.mem_bw * occupancy)
-    t_launch = supercycles * prof.launch_overhead_s
+    t_launch = dispatches * prof.launch_overhead_s
     total = (t_mem + t_launch) if feasible else math.inf
     return CostBreakdown(seconds=total, mem_seconds=t_mem,
                          launch_seconds=t_launch, bytes_moved=bytes_moved,
-                         cycles=cycles, supercycles=supercycles, wavefront=g,
+                         cycles=cycles, dispatches=dispatches, wavefront=g,
                          occupancy=occupancy, vmem_bytes=vmem,
                          feasible=feasible)
 
 
-def pipeline_cost(n: int, bw: int, tw: int, *, fuse: int = 1, batch: int = 1,
+def pipeline_cost(n: int, bw: int, tw: int, *, batch: int = 1,
                   dtype=jnp.float32, profile: DeviceProfile | None = None,
                   tape: bool = False) -> float:
     """Predicted seconds of the whole stage-2 reduction ``bw -> 1`` — the
@@ -203,7 +197,7 @@ def pipeline_cost(n: int, bw: int, tw: int, *, fuse: int = 1, batch: int = 1,
     ``inf`` as soon as any stage's working set misses the budget."""
     total = 0.0
     for b_in, twi in tuning.stage_plan(bw, tw):
-        c = stage_cost(n, b_in, twi, fuse=fuse, batch=batch, dtype=dtype,
+        c = stage_cost(n, b_in, twi, batch=batch, dtype=dtype,
                        profile=profile, tape=tape)
         if not c.feasible:
             return math.inf
@@ -231,7 +225,7 @@ def fused_cost(n: int, bw: int, *, batch: int = 1, dtype=jnp.float32,
     as a single launch with the matrix fast-memory resident.
 
     * ONE ``launch_overhead_s`` total — the entire point of the tier; the
-      staged path pays one per super-cycle (``stage_cost``).
+      staged path pays one per wavefront cycle (``stage_cost``).
     * slow-memory traffic: the stack streamed in and the results out, once.
     * in-kernel work: each reflector cycle touches the (n, n) working set a
       few times (extract, matvec, rank-1 update, fix) served from fast
@@ -266,7 +260,7 @@ def fused_cost(n: int, bw: int, *, batch: int = 1, dtype=jnp.float32,
     total = (t_mem + t_compute + t_launch) if feasible else math.inf
     return CostBreakdown(seconds=total, mem_seconds=t_mem + t_compute,
                          launch_seconds=t_launch, bytes_moved=bytes_moved,
-                         cycles=cycles, supercycles=1, wavefront=1,
+                         cycles=cycles, dispatches=1, wavefront=1,
                          occupancy=occupancy, vmem_bytes=vmem,
                          feasible=feasible)
 
@@ -295,7 +289,7 @@ def predicted_crossover(bw: int, *, dtype=jnp.float32, batch: int = 8,
             break
         tw = max(1, min(tuning.default_tilewidth(bw_eff, dtype),
                         max(bw_eff - 1, 1)))
-        staged = pipeline_cost(n, bw_eff, tw, fuse=1, batch=batch,
+        staged = pipeline_cost(n, bw_eff, tw, batch=batch,
                                dtype=dtype, profile=prof, tape=compute_uv)
         if fc.seconds < staged:
             best = n
@@ -397,7 +391,7 @@ def stage3_cost(n: int, *, solver: str, dtype=jnp.float64, batch: int = 1,
     return CostBreakdown(seconds=t_mem + t_launch, mem_seconds=t_mem,
                          launch_seconds=t_launch, bytes_moved=bytes_moved,
                          cycles=max_iter if solver == "bisect" else newton_iters,
-                         supercycles=1, wavefront=1, occupancy=occupancy,
+                         dispatches=1, wavefront=1, occupancy=occupancy,
                          vmem_bytes=vmem, feasible=True)
 
 

@@ -1,4 +1,4 @@
-"""Model-pruned on-device search over the ``(tw, fuse, batch)`` grid.
+"""Model-pruned on-device search over the ``(tw, batch)`` grid.
 
 The paper's tuning methodology, end to end: the analytic model
 (``autotune/model.py``) ranks the FULL candidate grid by predicted cost;
@@ -35,7 +35,6 @@ __all__ = ["Candidate", "SearchResult", "candidate_grid", "search",
 class Candidate:
     """One grid point; times are seconds PER MATRIX (batched call / batch)."""
     tw: int
-    fuse: int
     batch: int
     predicted_s: float
     measured_s: float | None = None
@@ -48,15 +47,14 @@ class Candidate:
         return 100.0 * (self.predicted_s - self.measured_s) / self.measured_s
 
     def label(self) -> str:
-        return f"tw={self.tw} fuse={self.fuse} B={self.batch}"
+        return f"tw={self.tw} B={self.batch}"
 
 
 def candidate_grid(n: int, bw: int, *, dtype=jnp.float32,
-                   fuses: tuple[int, ...] = (1, 2, 4, 8),
                    batches: tuple[int, ...] = (1,),
                    tws: tuple[int, ...] | None = None
-                   ) -> list[tuple[int, int, int]]:
-    """The full (tw, fuse, batch) grid for one shape.
+                   ) -> list[tuple[int, int]]:
+    """The full (tw, batch) grid for one shape.
 
     ``tws`` defaults to the powers of two below ``bw`` plus the two anchors
     that matter: the cache-line default and the single-stage width
@@ -69,8 +67,7 @@ def candidate_grid(n: int, bw: int, *, dtype=jnp.float32,
             cand.add(p)
             p *= 2
         tws = tuple(sorted(t for t in cand if 1 <= t <= max(bw - 1, 1)))
-    return [(t, k, b) for t in tws for k in fuses if k >= 1
-            for b in batches if b >= 1]
+    return [(t, b) for t in tws for b in batches if b >= 1]
 
 
 @dataclasses.dataclass
@@ -92,8 +89,7 @@ class SearchResult:
         """1-based rank of the measured-best candidate in the model's
         predicted ordering (1 = the model nailed it)."""
         for i, c in enumerate(self.candidates):
-            if (c.tw, c.fuse, c.batch) == (self.best.tw, self.best.fuse,
-                                           self.best.batch):
+            if (c.tw, c.batch) == (self.best.tw, self.best.batch):
                 return i + 1
         return len(self.candidates) + 1     # default-only winner, off-grid
 
@@ -103,12 +99,12 @@ class SearchResult:
                f"backend={self.backend} uv={self.compute_uv} "
                f"device={self.device_kind}")
         lines = [hdr,
-                 f"{'rank':>4} {'tw':>4} {'fuse':>4} {'B':>3} "
+                 f"{'rank':>4} {'tw':>4} {'B':>3} "
                  f"{'predicted_us':>13} {'measured_us':>12} {'err%':>7}"]
-        by_key = {(c.tw, c.fuse, c.batch): c for c in self.measured}
+        by_key = {(c.tw, c.batch): c for c in self.measured}
         shown = 0
         for i, c in enumerate(self.candidates):
-            m = by_key.pop((c.tw, c.fuse, c.batch), None)
+            m = by_key.pop((c.tw, c.batch), None)
             if m is None and shown >= self.top_k:
                 continue
             shown += 1
@@ -117,11 +113,11 @@ class SearchResult:
                    else f"{'-':>7}")
             pred = (f"{c.predicted_s * 1e6:13.1f}"
                     if math.isfinite(c.predicted_s) else f"{'vmem-cliff':>13}")
-            mark = " <- best" if (c.tw, c.fuse, c.batch) == (
-                self.best.tw, self.best.fuse, self.best.batch) else ""
-            dflt = " (default)" if (c.tw, c.fuse, c.batch) == (
-                self.default.tw, self.default.fuse, self.default.batch) else ""
-            lines.append(f"{i + 1:>4} {c.tw:>4} {c.fuse:>4} {c.batch:>3} "
+            mark = " <- best" if (c.tw, c.batch) == (
+                self.best.tw, self.best.batch) else ""
+            dflt = " (default)" if (c.tw, c.batch) == (
+                self.default.tw, self.default.batch) else ""
+            lines.append(f"{i + 1:>4} {c.tw:>4} {c.batch:>3} "
                          f"{pred} {mu} {err}{mark}{dflt}")
         lines.append(f"model rank of measured best: "
                      f"{self.model_rank_of_best()} of {len(self.candidates)} "
@@ -140,7 +136,6 @@ class SearchResult:
         """
         entry = {
             "tw": int(self.best.tw),
-            "fuse": int(self.best.fuse),
             "measured_us": round(self.best.measured_s * 1e6, 3),
             "predicted_us": (round(self.best.predicted_s * 1e6, 3)
                              if math.isfinite(self.best.predicted_s)
@@ -156,57 +151,54 @@ class SearchResult:
         return entry
 
 
-def _static_default(n: int, bw: int, dtype) -> tuple[int, int, int]:
+def _static_default(n: int, bw: int, dtype) -> tuple[int, int]:
     """The knobs ``PipelineConfig.resolve`` picks with no cache: cache-line
-    tilewidth, the paper's unfused schedule, the Eq.-1 bucket batch."""
+    tilewidth and the Eq.-1 bucket batch."""
     tw = max(1, min(tuning.default_tilewidth(bw, dtype), max(bw - 1, 1)))
-    return tw, 1, tuning.default_bucket_batch(n, bw)
+    return tw, tuning.default_bucket_batch(n, bw)
 
 
 def search(n: int, bw: int, *, dtype=jnp.float32, backend: str = "ref",
            compute_uv: bool = False, top_k: int = 4,
-           fuses: tuple[int, ...] = (1, 2, 4, 8),
            batches: tuple[int, ...] = (1,),
            profile: model_mod.DeviceProfile | None = None,
            warmup: int = 1, iters: int = 2, seed: int = 0,
            measure_fn=None) -> SearchResult:
     """Tune one shape: rank the grid by the model, time top-K + default.
 
-    ``measure_fn(tw, fuse, batch) -> seconds (whole batched call)`` is
+    ``measure_fn(tw, batch) -> seconds (whole batched call)`` is
     injectable for tests; the real path is ``measure.time_stage2`` on the
     full ``bw -> 1`` reduction (so small tilewidths pay for the extra
     stages they force — the honest objective).
     """
-    if not batches or not fuses:
-        raise ValueError(f"batches={batches!r} and fuses={fuses!r} must be "
-                         f"non-empty")
+    if not batches:
+        raise ValueError(f"batches={batches!r} must be non-empty")
     prof = profile if profile is not None else model_mod.profile_for()
     dname = jnp.dtype(dtype).name
     if measure_fn is None:
-        def measure_fn(tw, fuse, batch):
+        def measure_fn(tw, batch):
             return measure_mod.time_stage2(
-                n, bw, tw=tw, fuse=fuse, batch=batch, backend=backend,
+                n, bw, tw=tw, batch=batch, backend=backend,
                 dtype=dtype, tape=compute_uv, full=True, warmup=warmup,
                 iters=iters, seed=seed)
 
-    grid = candidate_grid(n, bw, dtype=dtype, fuses=fuses, batches=batches)
-    d_tw, d_fuse, d_batch = _static_default(n, bw, dtype)
+    grid = candidate_grid(n, bw, dtype=dtype, batches=batches)
+    d_tw, d_batch = _static_default(n, bw, dtype)
     d_batch = d_batch if d_batch in batches else min(batches)
-    if (d_tw, d_fuse, d_batch) not in grid:
-        grid.append((d_tw, d_fuse, d_batch))
+    if (d_tw, d_batch) not in grid:
+        grid.append((d_tw, d_batch))
 
-    cands = [Candidate(t, k, b, predicted_s=model_mod.pipeline_cost(
-        n, bw, t, fuse=k, batch=b, dtype=dtype, profile=prof,
-        tape=compute_uv) / b) for (t, k, b) in grid]
-    cands.sort(key=lambda c: (c.predicted_s, c.tw, c.fuse, c.batch))
+    cands = [Candidate(t, b, predicted_s=model_mod.pipeline_cost(
+        n, bw, t, batch=b, dtype=dtype, profile=prof,
+        tape=compute_uv) / b) for (t, b) in grid]
+    cands.sort(key=lambda c: (c.predicted_s, c.tw, c.batch))
 
     to_time = [c for c in cands if math.isfinite(c.predicted_s)][:top_k]
-    default = next(c for c in cands if (c.tw, c.fuse, c.batch) ==
-                   (d_tw, d_fuse, d_batch))
+    default = next(c for c in cands if (c.tw, c.batch) == (d_tw, d_batch))
     if default not in to_time:
         to_time.append(default)
     for c in to_time:
-        c.measured_s = measure_fn(c.tw, c.fuse, c.batch) / c.batch
+        c.measured_s = measure_fn(c.tw, c.batch) / c.batch
     best = min(to_time, key=lambda c: c.measured_s)
     return SearchResult(n=n, bw=bw, dtype=dname, backend=backend,
                         compute_uv=compute_uv,

@@ -88,51 +88,42 @@ __all__ = [
 # Wavefront schedule helpers
 # ---------------------------------------------------------------------------
 
-def stage_schedule(n: int, b_in: int, tw: int, fuse: int = 1
-                   ) -> tuple[int, int, int]:
-    """(n_sweeps, total_super_cycles, max_concurrent) for one stage.
+def stage_schedule(n: int, b_in: int, tw: int) -> tuple[int, int, int]:
+    """(n_sweeps, total_cycles, max_concurrent) for one stage.
 
-    With fuse depth K, every super-cycle advances each in-flight sweep by K
-    local cycles; sweep R starts at super-cycle ``sep*R`` where
-    ``sep = tuning.sweep_separation(K)`` (3 at K=1 — the paper's 3-cycle
-    rule — and 2 for K >= 2, which already keeps the wider fused windows
-    disjoint).  Sweep finish times ``sep*R + ceil((j_max(R)+1)/K)`` are
-    increasing in R (``sep >= 2`` while ``j_max`` drops by at most 1 per
-    sweep), so the last sweep finishes last.  ``max_concurrent`` is
-    ``tuning.max_concurrent_sweeps`` (single source of truth for the
+    Sweep R starts at global cycle ``3R`` (``tuning.SWEEP_SEPARATION``, the
+    paper's 3-cycle rule) and runs ``j_max(R) + 1`` local cycles; finish
+    times ``3R + j_max(R) + 1`` are increasing in R (``j_max`` drops by at
+    most 1 per sweep), so the last sweep finishes last.  ``max_concurrent``
+    is ``tuning.max_concurrent_sweeps`` (single source of truth for the
     wavefront width), including for the degenerate 0-sweep case.
     """
     from repro.core import tuning
-    conc = tuning.max_concurrent_sweeps(n, b_in, fuse, tw)
+    conc = tuning.max_concurrent_sweeps(n, b_in)
     b_out = b_in - tw
     nsweeps = max(n - 1 - b_out, 0)
     if nsweeps == 0:
         return 0, 0, conc
     last = nsweeps - 1
     max_j_last = max((n - 1 - last - b_out) // b_in, 0)
-    sep = tuning.sweep_separation(fuse)
-    total = sep * last + -(-(max_j_last + 1) // fuse)
+    total = tuning.SWEEP_SEPARATION * last + max_j_last + 1
     return nsweeps, total, conc
 
 
-def chase_cycle_indices(t, g, n: int, b_in: int, tw: int, fuse: int = 1):
-    """Vectorized slot -> (sweep, base local cycle, base pivot, active,
-    is_first).
+def chase_cycle_indices(t, g, n: int, b_in: int, tw: int):
+    """Vectorized slot -> (sweep, local cycle, pivot, active, is_first).
 
-    Slot g at (super-)cycle t hosts sweep R = t//sep - g at base local cycle
-    j = (t - sep*R) * fuse = (t%sep + sep*g) * fuse, where
-    ``sep = tuning.sweep_separation(fuse)``; the super-step then executes
-    local cycles j..j+fuse-1 with pivots ``p + i*b_in`` (cycle i active iff
-    ``p + i*b_in <= n - 1`` — a prefix of the K cycles, so ``active`` below
-    gates the whole slot via cycle 0).  ``fuse=1`` is the paper's schedule:
-    R = t//3 - g, j = t%3 + 3g.  Works on traced or static ints.
+    Slot g at global cycle t hosts sweep R = t//3 - g at local cycle
+    j = t%3 + 3g (``tuning.SWEEP_SEPARATION``), pivot
+    ``p = R + b_out + j*b_in``; the slot is active iff R is a real sweep
+    and ``p <= n - 1``.  Works on traced or static ints.
     """
     from repro.core import tuning
-    sep = tuning.sweep_separation(fuse)
+    sep = tuning.SWEEP_SEPARATION
     b_out = b_in - tw
     nsweeps = max(n - 1 - b_out, 0)
     R = t // sep - g
-    j = (t - sep * R) * fuse
+    j = t - sep * R
     p = R + b_out + j * b_in
     active = (R >= 0) & (R < nsweeps) & (p <= n - 1)
     return R, j, p, active, (j == 0)
@@ -163,13 +154,11 @@ def stage_path(dtype, *, n: int, b_in: int, tw: int, backend: str = "auto",
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
-                                             "unroll", "config", "tape",
-                                             "fuse"))
+                                             "config", "tape"))
 @obs.scope("stage2")
 def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
-                        backend: str = "auto", unroll: int | None = None,
-                        config=None, tape: bool = False,
-                        fuse: int | None = None):
+                        backend: str = "auto", config=None,
+                        tape: bool = False):
     """One SBR stage on packed band storage, batch-native.
 
     band: (..., b_in + 2*tw + 1, >= n) — any leading batch axes (flattened to
@@ -180,8 +169,7 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
     (DESIGN.md §9).  The *resident* path — Pallas backend, 32-bit data, a
     band that fits fast memory — is one ``ops.chase_stage`` kernel per
     stage that keeps each matrix's band in VMEM and runs the whole
-    wavefront loop inside, recording the tape when asked; ``fuse`` and
-    ``unroll`` do not apply.
+    wavefront loop inside, recording the tape when asked.
     Everything else takes the *streamed* path
     (:func:`_reduce_stage_streamed`), and the two give the same band bit
     for bit.  On the streamed path all B problems advance on one
@@ -189,34 +177,18 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
     flattened into ONE fused kernel call over B*G slots, so independent
     problems fill wavefront slots a single small matrix leaves idle.
 
-    ``fuse=K`` (DESIGN.md §9) chases K consecutive local cycles per kernel
-    dispatch: the wavefront clock ticks in super-cycles, each gathering one
-    contiguous band-storage block ``(H, K*b_in + tw + 1)`` per slot — no
-    per-cell shear indexing on the HBM side; the roll to dense windows
-    happens inside the kernel, VMEM-resident.  Each chased cycle costs ~1/K
-    of an HBM block round trip instead of one sheared window gather/scatter,
-    and the launch count drops by the sweep-separation ratio (3*nsweeps ->
-    2*nsweeps super-cycles; sweep starts, not per-sweep cycles, dominate the
-    schedule).  Numerics are invariant in K: every cycle applies the same
-    reflector pair in the same per-sweep order, so the output band (and any
-    tape) matches ``fuse=1``.
-
     With ``tape=True`` the stage additionally records the reflector tape and
     returns ``(band, tape_v, tape_tau)`` with static shapes
-    ``tape_v: (..., T, G, 2, tw+1)`` and ``tape_tau: (..., T, G, 2)`` at
-    ``fuse=1`` and on the resident path, and ``(..., T, G, K, 2, tw+1)`` /
-    ``(..., T, G, K, 2)`` streamed at ``fuse=K`` (T = super-cycle count, K
-    pairs per slot) — index 0 of the pair
+    ``tape_v: (..., T, G, 2, tw+1)`` and ``tape_tau: (..., T, G, 2)`` on
+    either path (T = cycle count) — index 0 of the pair
     axis is the right reflector (accumulates into V), index 1 the left one
     (into U); inactive slots carry ``tau = 0`` (identity on replay).  The
     in-band arithmetic is byte-for-byte the same either way, so (d, e) —
     and hence sigma — do not change with the tape.
 
-    Explicit ``backend=``/``unroll=``/``fuse=`` kwargs win over ``config``;
-    the config fills whatever was left at its default ("auto" / None).
-    Backend/interpret resolution itself is delegated to the kernel registry
-    (ops._resolve) at the kernel call — this function only resolves
-    ``unroll`` and ``fuse``.
+    An explicit ``backend=`` wins over ``config``; backend/interpret
+    resolution itself is delegated to the kernel registry (ops._resolve)
+    at the kernel call.
     """
     if stage_path(band.dtype, n=n, b_in=b_in, tw=tw, backend=backend,
                   config=config, tape=tape) == "resident":
@@ -231,29 +203,20 @@ def reduce_stage_packed(band: jax.Array, *, n: int, b_in: int, tw: int,
         return (out.reshape(band.shape), tv.reshape(lead + tv.shape[1:]),
                 tt.reshape(lead + tt.shape[1:]))
     return _reduce_stage_streamed(band, n=n, b_in=b_in, tw=tw,
-                                  backend=backend, unroll=unroll,
-                                  config=config, tape=tape, fuse=fuse)
+                                  backend=backend, config=config, tape=tape)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
-                                             "unroll", "config", "tape",
-                                             "fuse"))
+                                             "config", "tape"))
 def _reduce_stage_streamed(band: jax.Array, *, n: int, b_in: int, tw: int,
-                           backend: str = "auto", unroll: int | None = None,
-                           config=None, tape: bool = False,
-                           fuse: int | None = None):
-    """The streamed stage (the K = 1 wavefront, or fuse-K super-steps): per
-    (super-)cycle, gather every slot's window or block from the band in
-    HBM, chase it with one ``ops.chase_cycle`` call, scatter it back.  Same
-    arguments and results as :func:`reduce_stage_packed`, which calls it
-    for every input the resident path does not take."""
+                           backend: str = "auto", config=None,
+                           tape: bool = False):
+    """The streamed stage: per global cycle, gather every slot's rolled
+    window from the band in HBM, chase them with one ``ops.chase_cycle``
+    call, scatter them back.  Same arguments and results as
+    :func:`reduce_stage_packed`, which calls it for every input the
+    resident path does not take."""
     from repro.kernels import ops  # local import to avoid cycles
-
-    if unroll is None:
-        unroll = config.unroll if config is not None else 1
-    if fuse is None:
-        fuse = getattr(config, "fuse", 1) if config is not None else 1
-    fuse = max(int(fuse), 1)
 
     b_out = b_in - tw
     assert b_out >= 1, (b_in, tw)
@@ -263,21 +226,15 @@ def _reduce_stage_streamed(band: jax.Array, *, n: int, b_in: int, tw: int,
     lead = band.shape[:-2]
     band3 = band.reshape((-1,) + band.shape[-2:])
     B = band3.shape[0]
-    nsweeps, T, G = stage_schedule(n, b_in, tw, fuse)
+    nsweeps, T, G = stage_schedule(n, b_in, tw)
     if nsweeps == 0 or T == 0:
         if tape:
-            pair = (G, 2) if fuse == 1 else (G, fuse, 2)
-            empty_v = jnp.zeros(lead + (0,) + pair + (tw + 1,), band.dtype)
-            empty_t = jnp.zeros(lead + (0,) + pair, band.dtype)
+            empty_v = jnp.zeros(lead + (0, G, 2, tw + 1), band.dtype)
+            empty_t = jnp.zeros(lead + (0, G, 2), band.dtype)
             return band, empty_v, empty_t
         return band
 
     ncols0 = band3.shape[-1]
-    if fuse > 1:
-        return _reduce_stage_superstep(band3, lead=lead, n=n, b_in=b_in,
-                                       tw=tw, backend=backend, unroll=unroll,
-                                       config=config, tape=tape, fuse=fuse,
-                                       T=T, G=G)
     dump = n + W                      # start of per-slot dump zones (inactive slots)
     n_pad = dump + G * W
     bandp = bandmod.pad_columns(band3, max(n_pad - ncols0, 0))
@@ -325,81 +282,12 @@ def _reduce_stage_streamed(band: jax.Array, *, n: int, b_in: int, tw: int,
         tape_v0 = jnp.zeros((B, T, G, 2, tw + 1), band.dtype)
         tape_tau0 = jnp.zeros((B, T, G, 2), band.dtype)
         bandp, tape_v, tape_tau = jax.lax.fori_loop(
-            0, T, cycle, (bandp, tape_v0, tape_tau0), unroll=unroll)
+            0, T, cycle, (bandp, tape_v0, tape_tau0))
         out = bandp[..., :ncols0]
         return (out.reshape(lead + out.shape[-2:]),
                 tape_v.reshape(lead + tape_v.shape[1:]),
                 tape_tau.reshape(lead + tape_tau.shape[1:]))
-    bandp = jax.lax.fori_loop(0, T, cycle, bandp, unroll=unroll)
-    out = bandp[..., :ncols0]
-    return out.reshape(lead + out.shape[-2:])
-
-
-def _reduce_stage_superstep(band3: jax.Array, *, lead, n: int, b_in: int,
-                            tw: int, backend: str, unroll: int, config,
-                            tape: bool, fuse: int, T: int, G: int):
-    """Fuse-depth-K super-step wavefront (DESIGN.md §9), fuse >= 2.
-
-    Per super-cycle, each active slot owns one CONTIGUOUS band-storage block
-    of ``W_K = K*b_in + tw + 1`` columns — the union of its K consecutive
-    chase windows, which overlap by ``tw + 1`` columns.  The gather/scatter
-    is therefore a plain column-block copy (the per-cell diagonal shear of
-    the K=1 path moves inside the kernel, where it runs on VMEM-resident
-    data); blocks of one super-cycle are pairwise disjoint by the
-    generalized schedule (``tuning.sweep_separation``), so the scatter is
-    race-free.
-    """
-    from repro.kernels import ops
-
-    H = b_in + 2 * tw + 1
-    WK = fuse * b_in + tw + 1
-    B = band3.shape[0]
-    ncols0 = band3.shape[-1]
-    dump = n + WK                     # start of per-slot dump zones
-    n_pad = dump + G * WK
-    bandp = bandmod.pad_columns(band3, max(n_pad - ncols0, 0))
-
-    g_idx = jnp.arange(G)
-    rows = jnp.arange(H)[None, :, None]              # (1, H, 1)
-    i_off = jnp.arange(fuse, dtype=jnp.int32) * b_in
-
-    def supercycle(t, carry):
-        bandp = carry[0] if tape else carry
-        _, _, p, slot_on, is_first = chase_cycle_indices(t, g_idx, n, b_in,
-                                                         tw, fuse)
-        # per-fused-cycle activity: a prefix of the K cycles (pivot runs off
-        # the band once p + i*b_in > n - 1)
-        act = slot_on[:, None] & ((p[:, None] + i_off) <= n - 1)   # (G, K)
-        p_safe = jnp.where(slot_on, p, dump + g_idx * WK).astype(jnp.int32)
-        cols = p_safe[:, None] + jnp.arange(WK, dtype=jnp.int32)[None, :]
-        blocks = bandp[:, rows, cols[:, None, :]]                  # (B, G, H, WK)
-        with jax.named_scope("chase_supercycle"):
-            res = ops.chase_cycle(blocks.reshape(B * G, H, WK),
-                                  jnp.tile(is_first, B), b_in=b_in, tw=tw,
-                                  fuse=fuse, active=jnp.tile(act, (B, 1)),
-                                  backend=backend, config=config,
-                                  with_tape=tape)
-        out = (res[0] if tape else res).reshape(B, G, H, WK)
-        out = jnp.where(slot_on[None, :, None, None], out, blocks)
-        bandp = bandp.at[:, rows, cols[:, None, :]].set(out)
-        if not tape:
-            return bandp
-        tape_v, tape_tau = carry[1], carry[2]
-        vs = res[1].reshape(B, G, fuse, 2, tw + 1)
-        ts = res[2].reshape(B, G, fuse, 2)
-        ts = jnp.where(act[None, :, :, None], ts, 0)               # identity replay
-        return (bandp, tape_v.at[:, t].set(vs), tape_tau.at[:, t].set(ts))
-
-    if tape:
-        tape_v0 = jnp.zeros((B, T, G, fuse, 2, tw + 1), band3.dtype)
-        tape_tau0 = jnp.zeros((B, T, G, fuse, 2), band3.dtype)
-        bandp, tape_v, tape_tau = jax.lax.fori_loop(
-            0, T, supercycle, (bandp, tape_v0, tape_tau0), unroll=unroll)
-        out = bandp[..., :ncols0]
-        return (out.reshape(lead + out.shape[-2:]),
-                tape_v.reshape(lead + tape_v.shape[1:]),
-                tape_tau.reshape(lead + tape_tau.shape[1:]))
-    bandp = jax.lax.fori_loop(0, T, supercycle, bandp, unroll=unroll)
+    bandp = jax.lax.fori_loop(0, T, cycle, bandp)
     out = bandp[..., :ncols0]
     return out.reshape(lead + out.shape[-2:])
 
@@ -416,7 +304,7 @@ def tw_schedule(bw: int, tw: int) -> list[tuple[int, int]]:
 
 def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
                          backend: str = "auto", config=None,
-                         tape: bool = False, fuse: int | None = None):
+                         tape: bool = False):
     """Full SBR bw -> 1 on packed storage. Returns (diag, superdiag).
 
     ``band`` must be packed with tw_0 = min(tw, bw-1) sub rows, i.e. via
@@ -426,10 +314,7 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
 
     With ``tape=True`` returns ``(diag, superdiag, tapes)`` where ``tapes``
     is a static-length list of :class:`repro.core.transforms.ChaseTape`,
-    one per stage of the tile-width plan, in execution order.  ``fuse=K``
-    (explicit kwarg or ``config.fuse``) runs every streamed stage in
-    K-cycle super-steps; the tapes carry the fuse depth for replay (1 for
-    a resident stage, which always chases in the K = 1 order).
+    one per stage of the tile-width plan, in execution order.
 
     Each stage runs on the path :func:`stage_path` picks; its ``stage2``
     span carries ``path=``, and ``obs.count_chase_stage`` counts it here —
@@ -442,9 +327,6 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
     """
     if tape:
         from repro.core import transforms  # deferred: transforms imports us
-    if fuse is None:
-        fuse = getattr(config, "fuse", 1) if config is not None else 1
-    fuse = max(int(fuse), 1)
     plan = tw_schedule(bw, tw)
     if not plan:
         h = band.shape[-2]
@@ -464,8 +346,8 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
         obs.count_chase_stage(path)
         # One span per stage of the tile-width plan (DESIGN.md §16); inside
         # `_three_stage` this loop is traced and the spans are no-ops.
-        with obs.span("stage2", n=n, b_in=b_in, tw=twi, fuse=fuse,
-                      tape=tape, path=path):
+        with obs.span("stage2", n=n, b_in=b_in, tw=twi, tape=tape,
+                      path=path):
             # re-slice so exactly twi sub rows remain above the diagonal row
             h_i = b_in + 2 * twi + 1
             start = tw_cur - twi
@@ -474,16 +356,13 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
             if tape:
                 cur, tv, tt = reduce_stage_packed(
                     cur, n=n, b_in=b_in, tw=twi, backend=backend,
-                    config=config, tape=True, fuse=fuse)
-                # the resident kernel always chases in the K = 1 order
-                tapes.append(transforms.ChaseTape(
-                    n=n, b_in=b_in, tw=twi, v=tv, tau=tt,
-                    fuse=1 if path == "resident" else fuse))
+                    config=config, tape=True)
+                tapes.append(transforms.ChaseTape(n=n, b_in=b_in, tw=twi,
+                                                  v=tv, tau=tt))
                 obs.count_tape_bytes("stage2", tapes[-1].nbytes)
             else:
                 cur = reduce_stage_packed(cur, n=n, b_in=b_in, tw=twi,
-                                          backend=backend, config=config,
-                                          fuse=fuse)
+                                          backend=backend, config=config)
         tw_cur = twi
     with obs.span("extract"):
         d = bandmod.band_extract_diag(cur, tw_cur, 0, n)
@@ -492,15 +371,14 @@ def bidiagonalize_packed(band: jax.Array, *, n: int, bw: int, tw: int,
 
 
 def bidiagonalize(a: jax.Array, *, bw: int, tw: int, backend: str = "auto",
-                  config=None, tape: bool = False, fuse: int | None = None):
+                  config=None, tape: bool = False):
     """Dense upper-banded (..., n, n) -> (..., n) diag + superdiag pair via
     packed wavefront SBR; a leading batch axis runs batch-native (one fused
     wavefront over all matrices), not as a vmapped loop.  ``tape=True``
-    additionally returns the per-stage reflector tapes; ``fuse=K`` chases K
-    cycles per kernel dispatch (see :func:`bidiagonalize_packed`)."""
+    additionally returns the per-stage reflector tapes."""
     n = a.shape[-1]
     tw0 = min(tw, max(bw - 1, 1))
     with obs.span("pack", bw=bw, tw=tw0):
         packed = bandmod.pack(a, bw, tw0)
     return bidiagonalize_packed(packed, n=n, bw=bw, tw=tw, backend=backend,
-                                config=config, tape=tape, fuse=fuse)
+                                config=config, tape=tape)

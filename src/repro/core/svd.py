@@ -208,9 +208,8 @@ def _span_attrs(a, cfg: tuning.PipelineConfig, **extra) -> dict:
     batch = 1
     for dim in lead:
         batch *= int(dim)
-    return dict(n=int(a.shape[-1]), bw=cfg.bw, tw=cfg.tw, fuse=cfg.fuse,
-                dtype=str(a.dtype), backend=cfg.backend, batch=batch,
-                **extra)
+    return dict(n=int(a.shape[-1]), bw=cfg.bw, tw=cfg.tw, dtype=str(a.dtype),
+                backend=cfg.backend, batch=batch, **extra)
 
 
 @jax.jit

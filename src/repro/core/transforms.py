@@ -46,17 +46,13 @@ class ChaseTape:
 
     ``v``: (..., T, G, 2, tw+1) reflectors, pair axis = (right -> V,
     left -> U); ``tau``: (..., T, G, 2) with tau = 0 on inactive slots.
-    A stage chased with fuse depth K >= 2 (DESIGN.md §9) records K pairs
-    per (super-cycle, slot) instead: ``v (..., T, G, K, 2, tw+1)`` /
-    ``tau (..., T, G, K, 2)``, with ``fuse`` carrying K so replay can
-    recompute each fused cycle's pivot from the generalized schedule.
+    Both stage-2 paths record this one layout.
     """
     n: int
     b_in: int
     tw: int
     v: jax.Array
     tau: jax.Array
-    fuse: int = 1
 
     @property
     def nbytes(self) -> int:
@@ -95,12 +91,11 @@ def replay_stage1(ut: jax.Array, vt: jax.Array, tape, *, config=None):
     return jax.lax.fori_loop(0, n_panels, body, (ut, vt))
 
 
-@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "config",
-                                             "fuse"))
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "config"))
 @obs.scope("replay")
 def replay_chase(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
                  tape_tau: jax.Array, *, n: int, b_in: int, tw: int,
-                 config=None, fuse: int = 1):
+                 config=None):
     """Replay one chase stage's tape into the transposed accumulators.
 
     ut/vt: (B, n, n).  Reuses the chase schedule (``chase_cycle_indices``)
@@ -108,50 +103,37 @@ def replay_chase(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
     ranges are shape-derived, exactly like the chase's own window gather.
     Inactive slots were recorded with tau = 0 and are routed to disjoint
     dump rows (identity applies on scratch space).
-
-    With ``fuse=K`` the tape holds K pairs per (super-cycle, slot); fused
-    cycle i's row range ``[p + i*b_in, p + i*b_in + tw]`` is disjoint from
-    its neighbours' (``b_in >= tw + 1``) exactly like the slots' are, so the
-    whole super-cycle replays as ONE fused ``tape_apply`` over ``B*G*K``
-    slots — the replay batches K-fold with the chase.
     """
     from repro.kernels import ops
 
-    nsweeps, T, G = bc.stage_schedule(n, b_in, tw, fuse)
+    nsweeps, T, G = bc.stage_schedule(n, b_in, tw)
     if nsweeps == 0 or T == 0:
         return ut, vt
     B = ut.shape[0]
-    K = fuse
     W = b_in + tw + 1
     k = tw + 1
     dump = n + W
-    n_pad = dump + G * K * W
+    n_pad = dump + G * W
     pad = ((0, 0), (0, n_pad - n), (0, 0))
     utp = jnp.pad(ut, pad)
     vtp = jnp.pad(vt, pad)
     g_idx = jnp.arange(G)
-    i_off = jnp.arange(K, dtype=jnp.int32) * b_in
     off = jnp.arange(k, dtype=jnp.int32)
-    # (G, K) dump rows: one disjoint scratch range per (slot, fused cycle)
-    dump_rows = dump + (g_idx[:, None] * K + jnp.arange(K)[None, :]) * W
 
     def cycle(t, carry):
         utp, vtp = carry
-        _, _, p, active, _ = bc.chase_cycle_indices(t, g_idx, n, b_in, tw,
-                                                    fuse)
-        p_i = p[:, None] + i_off[None, :]                         # (G, K)
-        act = active[:, None] & (p_i <= n - 1)
-        p_safe = jnp.where(act, p_i, dump_rows).astype(jnp.int32)
-        rows = p_safe[..., None] + off[None, None, :]             # (G, K, k)
-        vs = tape_v[:, t].reshape(B, G, K, 2, k)
-        ts = tape_tau[:, t].reshape(B, G, K, 2)
+        _, _, p, active, _ = bc.chase_cycle_indices(t, g_idx, n, b_in, tw)
+        p_safe = jnp.where(active, p, dump + g_idx * W).astype(jnp.int32)
+        rows = p_safe[:, None] + off[None, :]                     # (G, k)
+        vs = tape_v[:, t]                                         # (B, G, 2, k)
+        ts = tape_tau[:, t]                                       # (B, G, 2)
 
         def apply(side, acc):
-            v = vs[:, :, :, side].reshape(B * G * K, k, 1)
-            tau = ts[:, :, :, side].reshape(B * G * K, 1, 1)
-            sl = acc[:, rows].reshape(B * G * K, k, n)
+            v = vs[:, :, side].reshape(B * G, k, 1)
+            tau = ts[:, :, side].reshape(B * G, 1, 1)
+            sl = acc[:, rows].reshape(B * G, k, n)
             out = ops.tape_apply(v, tau, sl, config=config)
-            return acc.at[:, rows].set(out.reshape(B, G, K, k, n))
+            return acc.at[:, rows].set(out.reshape(B, G, k, n))
 
         return apply(1, utp), apply(0, vtp)                       # left->U, right->V
 
@@ -181,9 +163,9 @@ def accumulate_transforms(n: int, *, s1_tape=None, chase_tapes=(),
         tv = tape.v.reshape((b,) + tape.v.shape[len(lead):]).astype(acc)
         tt = tape.tau.reshape((b,) + tape.tau.shape[len(lead):]).astype(acc)
         with obs.span("replay_chase", n=tape.n, b_in=tape.b_in, tw=tape.tw,
-                      fuse=tape.fuse, tape_bytes=tape.nbytes):
+                      tape_bytes=tape.nbytes):
             ut, vt = replay_chase(ut, vt, tv, tt, n=tape.n, b_in=tape.b_in,
-                                  tw=tape.tw, config=config, fuse=tape.fuse)
+                                  tw=tape.tw, config=config)
     u = jnp.swapaxes(ut, -1, -2)
     out_dt = jnp.dtype(dtype)
     return (u.reshape(lead + (n, n)).astype(out_dt),

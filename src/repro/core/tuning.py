@@ -27,9 +27,9 @@ import dataclasses
 import jax.numpy as jnp
 
 __all__ = [
-    "default_tilewidth", "rows_per_step", "sweep_separation",
+    "default_tilewidth", "rows_per_step", "SWEEP_SEPARATION",
     "max_concurrent_sweeps", "occupancy_matrix_size",
-    "vmem_working_set_bytes", "default_fuse_depth", "check_vmem_budget",
+    "vmem_working_set_bytes", "check_vmem_budget",
     "fused_working_set_bytes", "check_fused_vmem_budget",
     "resident_band_layout", "resident_band_bytes", "tape_stage_lanes",
     "DEFAULT_FUSED_CROSSOVER", "STAGE3_CHOICES",
@@ -39,6 +39,12 @@ __all__ = [
 LANE = 128          # TPU vector lane count
 SUBLANE = 8         # TPU sublane count (f32)
 VMEM_BUDGET_BYTES = 16 * 2 ** 20   # per-TensorCore VMEM (v4/v5-class parts)
+
+# The paper's 3-cycle rule: sweep R starts at global cycle 3R, so the pivot
+# stride between adjacent in-flight sweeps is 3*b_in - 1 >= b_in + tw + 1,
+# the window width, for every valid tw <= b_in - 1 — concurrent windows are
+# disjoint.  ``tests/test_batched.py`` asserts the disjointness.
+SWEEP_SEPARATION = 3
 
 
 def _bytes(dtype) -> int:
@@ -64,46 +70,12 @@ def rows_per_step(b_in: int, tw: int, dtype=jnp.float32) -> int:
     return min(64, max(SUBLANE, SUBLANE * (rows // SUBLANE)))
 
 
-def sweep_separation(fuse: int = 1) -> int:
-    """Sweep-start separation, in (super-)cycles, for fuse depth K.
-
-    Concurrent fused windows are disjoint iff the pivot stride between
-    adjacent in-flight sweeps, ``sep*K*b_in - 1``, is at least the fused
-    window width ``W_K = K*b_in + tw + 1``.  K = 1 keeps the paper's 3-cycle
-    rule (``3*b_in - 1 >= b_in + tw + 1`` for every valid ``tw <= b_in - 1``
-    — strictly stronger than the bound requires when ``tw <= b_in - 2``, but
-    it is the published schedule and the bit-exact baseline).  For K >= 2 a
-    separation of 2 already suffices unconditionally:
-
-        2*K*b_in - 1 >= K*b_in + tw + 1  <=>  K*b_in >= tw + 2,
-
-    and ``K >= 2, b_in >= tw + 1`` give ``K*b_in >= 2*tw + 2 >= tw + 2``.
-    ``tests/test_batched.py`` asserts the disjointness exhaustively for
-    K in {1, 2, 4, 8}.
-    """
-    assert fuse >= 1, fuse
-    return 3 if fuse == 1 else 2
-
-
-def max_concurrent_sweeps(n: int, b_in: int, fuse: int = 1,
-                          tw: int | None = None) -> int:
-    """Wavefront width (paper: #blocks) for one stage.
-
-    ``fuse=1`` is the paper's Eq.-1 analogue ``ceil(n / (3*CBW - 1)) + 1``
-    (pivot-stride bound).  Fused super-steps advance K cycles per dispatch,
-    so a sweep lives for only ``dur = ceil((j_max + 1)/K)`` super-cycles and
-    slot ``g = js // sep`` never exceeds ``(dur - 1) // sep`` — a much
-    tighter bound than the stride formula when K divides the sweep length
-    down.  The tight bound needs the sweep length, hence ``tw`` (``b_out =
-    b_in - tw`` fixes ``j_max``); it is what keeps the fused wavefront from
-    carrying dead slots whose K windows would be chased and discarded.
-    """
-    if fuse == 1 or tw is None:
-        stride = sweep_separation(fuse) * fuse * b_in - 1
-        return max(1, -(-n // stride) + 1)
-    j_max0 = max((n - 1 - (b_in - tw)) // b_in, 0)
-    dur0 = -(-(j_max0 + 1) // fuse)
-    return max(1, (dur0 - 1) // sweep_separation(fuse) + 1)
+def max_concurrent_sweeps(n: int, b_in: int) -> int:
+    """Wavefront width (paper: #blocks) for one stage: the paper's Eq.-1
+    analogue ``ceil(n / (3*CBW - 1)) + 1``, from the pivot stride
+    ``SWEEP_SEPARATION * b_in - 1`` between adjacent in-flight sweeps."""
+    stride = SWEEP_SEPARATION * b_in - 1
+    return max(1, -(-n // stride) + 1)
 
 
 def occupancy_matrix_size(cbw: int, execution_units: int) -> int:
@@ -112,89 +84,40 @@ def occupancy_matrix_size(cbw: int, execution_units: int) -> int:
 
 
 def vmem_working_set_bytes(b_in: int, tw: int, dtype=jnp.float32, *,
-                           fuse: int = 1, tape: bool = False) -> int:
-    """Per-slot VMEM working set of one chase super-step (paper §III-C).
-
-    Counts everything one grid step keeps resident while chasing ``fuse``
-    consecutive cycles:
-
-    * the streamed band block ``(H, W_K)``, ``W_K = fuse*b_in + tw + 1``,
-      **x2** for the double-buffered BlockSpec pipeline (Pallas prefetches
-      step i+1's block while step i computes — the TPU analogue of the
-      paper's L1 residency);
-    * for ``fuse > 1``, the in-kernel rolled dense scratch
-      ``(H + W_K - 1, W_K)`` (the shear workspace the fused kernel chases
-      in — see kernels/bulge_chase.py);
-    * one reflector pair per fused cycle;
-    * with ``tape=True``, the double-buffered tape output blocks
-      (``fuse`` pairs of ``(v, tau)`` per slot).
-
-    Monotone in ``fuse`` — the knob ``default_fuse_depth`` searches.
-    """
+                           tape: bool = False) -> int:
+    """Per-slot VMEM working set of one streamed chase cycle (paper
+    §III-C): the window ``(H, W)``, **x2** for the double-buffered
+    BlockSpec pipeline (Pallas prefetches step i+1's window while step i
+    computes — the TPU analogue of the paper's L1 residency), one
+    reflector pair, and with ``tape=True`` the double-buffered tape output
+    blocks of that pair."""
     h = b_in + 2 * tw + 1
-    wk = fuse * b_in + tw + 1
-    words = 2 * h * wk                       # double-buffered streamed block
-    if fuse > 1:
-        words += (h + wk - 1) * wk           # rolled dense scratch (shear)
-    words += fuse * 2 * (tw + 1)             # reflector pairs
+    w = b_in + tw + 1
+    words = 2 * h * w + 2 * (tw + 1)
     if tape:
-        words += 2 * fuse * 2 * (tw + 2)     # double-buffered (v, tau) blocks
+        words += 2 * 2 * (tw + 2)
     return words * _bytes(dtype)
-
-
-def default_fuse_depth(b_in: int, tw: int, dtype=jnp.float32, *,
-                       budget_bytes: int | None = None, tape: bool = False,
-                       cap: int = 8) -> int:
-    """Largest fuse depth K whose super-step working set fits the per-core
-    VMEM budget (the paper's performance-model-guided tuning, §III-D,
-    applied to the fuse knob).
-
-    ``budget_bytes`` defaults to half of ``VMEM_BUDGET_BYTES`` — the other
-    half is headroom for Pallas pipeline state and compiler spills.  Falls
-    back to K = 1 when even K = 2 does not fit (the K = 1 path streams
-    pre-rolled windows and needs no dense scratch).  The floor is HARD:
-    under any budget — zero, negative, or a cap < 1 — the answer is 1,
-    never 0 (a 0-depth schedule would execute no cycles and silently
-    return the input band; whether even K = 1 is *feasible* is the
-    separate ``check_vmem_budget`` guard that ``resolve`` runs).
-
-    Scope: the model maximizes fast-memory residency per dispatch (the
-    paper's axis), not wall-clock on a given host — launches stop falling
-    past K = 2 (2*nsweeps super-cycles) while per-launch block width keeps
-    growing, so on the CPU ref path the measured optimum can be a shallower
-    K than the deepest that fits (see BENCH_stage2.json: K=2 beats K=4 at
-    n=1024, bw=32).  Treat the result as the residency-feasible ceiling and
-    ``benchmarks/fusion.py`` as the measured curve to pick from.
-    """
-    budget = VMEM_BUDGET_BYTES // 2 if budget_bytes is None else budget_bytes
-    best = 1
-    for cand in range(2, max(cap, 1) + 1):
-        if vmem_working_set_bytes(b_in, tw, dtype, fuse=cand,
-                                  tape=tape) <= budget:
-            best = cand
-    return max(best, 1)
 
 
 def check_vmem_budget(b_in: int, tw: int, dtype=jnp.float32, *,
                       tape: bool = False,
                       budget_bytes: int | None = None) -> int:
-    """Raise (clearly) when even the UNFUSED working set misses the budget.
+    """Raise (clearly) when the chase window's working set misses the budget.
 
-    ``default_fuse_depth`` degrades gracefully to K = 1, but when
-    ``vmem_working_set_bytes(b_in, tw, fuse=1)`` itself exceeds the budget
-    there is no depth to retreat to — proceeding would silently mis-tile
-    (the kernel's window could never be fast-memory resident, the exact
-    regime the paper's model exists to exclude).  Called by
+    When ``vmem_working_set_bytes(b_in, tw)`` exceeds the budget,
+    proceeding would silently mis-tile (the kernel's window could never be
+    fast-memory resident, the exact regime the paper's model exists to
+    exclude).  Called by
     ``ChaseConfig.resolve`` / ``PipelineConfig.resolve``; returns the
     working-set bytes on success so callers can report headroom.
     """
     budget = VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
-    need = vmem_working_set_bytes(b_in, tw, dtype, fuse=1, tape=tape)
+    need = vmem_working_set_bytes(b_in, tw, dtype, tape=tape)
     if need > budget:
         raise ValueError(
             f"chase window working set for b_in={b_in}, tw={tw}, "
             f"dtype={jnp.dtype(dtype).name} (tape={tape}) needs {need} B "
-            f"of fast memory at fuse=1 but the budget is {budget} B; "
+            f"of fast memory but the budget is {budget} B; "
             f"reduce the tilewidth/bandwidth (tw <= {tw} shrinks the "
             f"window H x W = (b_in + 2*tw + 1) x (b_in + tw + 1)) or "
             f"raise budget_bytes")
@@ -227,7 +150,7 @@ def resident_band_bytes(n: int, b_in: int, tw: int, dtype=jnp.float32, *,
     words = (rows * lanes + lanes * lanes
              + _round_up(h, SUBLANE) * _round_up(w, LANE))
     if tape:
-        pairs = _round_up(2 * max_concurrent_sweeps(n, b_in, 1, tw), SUBLANE)
+        pairs = _round_up(2 * max_concurrent_sweeps(n, b_in), SUBLANE)
         words += 2 * pairs * tape_stage_lanes(tw)
     return words * _bytes(dtype)
 
@@ -355,9 +278,7 @@ class PipelineConfig:
     interpret: bool             # Pallas interpret mode (CPU correctness runs)
     dtype: str = "float32"      # working precision of stages 1-2
     max_batch: int = 8          # serve bucket capacity (leading batch axis B)
-    unroll: int = 1             # fori_loop unroll of the wavefront stage
     compute_uv: bool = False    # full SVD: record + replay reflector tapes
-    fuse: int = 1               # chase super-step depth K (cycles per launch)
     stage3: str = "bisect"      # bidiagonal solver: "bisect" | "dc" | "auto"
     dc_leaf_n: int = 32         # D&C recursion floor (leaves solve by bisection)
     dc_n_min: int = 2048        # "auto" routes n >= dc_n_min to "dc"
@@ -394,9 +315,8 @@ class PipelineConfig:
     def resolve(cls, *, bw: int = 32, tw: int | None = None,
                 backend: str = "auto", interpret: bool | None = None,
                 dtype=jnp.float32, n: int | None = None,
-                max_batch: int | None = None, unroll: int = 1,
-                compute_uv: bool = False,
-                fuse: int | None = 1, autotune: bool = False,
+                max_batch: int | None = None, compute_uv: bool = False,
+                autotune: bool = False,
                 autotune_cache: str | None = None,
                 stage3: str = "bisect", dc_leaf_n: int | None = None,
                 dc_n_min: int | None = None) -> "PipelineConfig":
@@ -406,22 +326,18 @@ class PipelineConfig:
         registry (pallas on TPU, ref elsewhere and for float64 on TPU;
         interpret off-TPU only);
         ``tw=None`` falls back to the cache-line/lane heuristic;
-        ``max_batch=None`` uses the Eq.-1 occupancy deficit for (n, bw);
-        ``fuse=None`` asks the VMEM model for the deepest super-step that
-        fits (``default_fuse_depth``), ``fuse=1`` (the default) keeps the
-        paper's one-launch-per-cycle schedule.
+        ``max_batch=None`` uses the Eq.-1 occupancy deficit for (n, bw).
         ``bw`` is clamped to >= 1 (bw = 0 — e.g. a 1x1 problem — would zero
         the stage-1 panel width; a bw-1 "band" is already bidiagonal, so
         stage 2 is a no-op pass-through either way).  A (bw, tw) pair whose
-        unfused chase window cannot be fast-memory resident raises
+        chase window cannot be fast-memory resident raises
         (``check_vmem_budget``) instead of silently mis-tiling.
 
         ``autotune=True`` (DESIGN.md §11) consults the persistent tuned
         cache (``repro.autotune.cache``, keyed by device kind, n, bw,
         dtype, compute_uv and the RESOLVED backend) and uses the measured
         optimum for every knob still at its neutral default — ``tw=None``,
-        ``fuse`` in (None, 1), ``max_batch=None``; explicit values always
-        win.  On a cache miss (or without ``n``) the analytic defaults
+        ``max_batch=None``; explicit values always win.  On a cache miss (or without ``n``) the analytic defaults
         above apply unchanged.  ``autotune_cache`` overrides the cache
         path (else ``$REPRO_AUTOTUNE_CACHE`` / the XDG default).
 
@@ -452,7 +368,6 @@ class PipelineConfig:
                 backend=backend, path=autotune_cache)
         if tuned is not None:
             tw = tw if tw is not None else tuned["tw"]
-            fuse = fuse if fuse not in (None, 1) else tuned["fuse"]
             if max_batch is None:
                 # max_batch is only in the entry when the search actually
                 # explored the batch axis; otherwise the Eq.-1 analytic
@@ -467,8 +382,6 @@ class PipelineConfig:
             check_fused_vmem_budget(n, dtype, compute_uv=compute_uv)
         if max_batch is None:
             max_batch = default_bucket_batch(n, bw) if n else 8
-        if fuse is None:
-            fuse = default_fuse_depth(bw, tw, dtype, tape=compute_uv)
         if stage3 not in STAGE3_CHOICES:
             raise ValueError(f"stage3 must be one of {STAGE3_CHOICES}, "
                              f"got {stage3!r}")
@@ -491,8 +404,7 @@ class PipelineConfig:
             stage3 = "dc" if n >= dc_n_min else "bisect"
         return cls(bw=bw, tw=tw, backend=backend, interpret=interpret,
                    dtype=jnp.dtype(dtype).name, max_batch=max_batch,
-                   unroll=unroll, compute_uv=compute_uv,
-                   fuse=max(int(fuse), 1), stage3=stage3,
+                   compute_uv=compute_uv, stage3=stage3,
                    dc_leaf_n=dc_leaf_n, dc_n_min=dc_n_min)
 
     @classmethod

@@ -9,9 +9,8 @@ Memory mapping (GPU -> TPU, DESIGN.md §2):
 * kernel-launch sync between cycles -> one ``pallas_call`` per stage
                                        (``chase_stage_pallas``), with or
                                        without a tape; the streamed
-                                       fallback, one per K-cycle super-step
-                                       (``chase_superstep_pallas``; K=1 is
-                                       ``chase_cycle_pallas``)
+                                       fallback, one per wavefront cycle
+                                       (``chase_cycle_pallas``)
 
 Each grid step owns one *rolled dense window* (H, W) of the packed band
 storage, H = b_in + 2*tw + 1, W = b_in + tw + 1 — the "1 + BW + TW" working
@@ -19,20 +18,8 @@ set of the paper, staged HBM -> VMEM by the BlockSpec pipeline (double-
 buffered by Pallas, the TPU analogue of the paper's L1 residency), processed
 entirely in VMEM, and written back.
 
-Fused super-steps (DESIGN.md §9): with fuse depth K >= 2 a grid step owns
-the CONTIGUOUS band-storage block (H, K*b_in + tw + 1) covering K
-consecutive cycles of its sweep.  The diagonal shear that rolls band
-storage into dense windows — done host-side per cycle at K=1 — moves inside
-the kernel: one strided roll plus a transpose builds a VMEM-resident dense
-workspace, and the K cycles chase at the workspace origin (the workspace is
-rolled by b_in between cycles), reusing the tw+1-column overlap between
-consecutive windows without ever leaving VMEM.  HBM sees one contiguous
-block load per K cycles; the kernel hands back the sheared rows and the
-wrapper un-shears them in the store (Mosaic has no roll by minus the row
-index).
-
 Band-resident stage (DESIGN.md §9): a grid step owns one matrix's whole
-band for a whole stage, copied into VMEM once, and runs the K = 1
+band for a whole stage, copied into VMEM once, and runs the streamed
 wavefront loop inside the kernel, every window through the same
 ``_chase_window_vmem``; HBM sees the band once in and once out.  A
 reflector tape leaves by one DMA per cycle from a double-buffered VMEM
@@ -60,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.householder import reflector_parts
 
-__all__ = ["chase_cycle_pallas", "chase_superstep_pallas", "chase_stage_pallas"]
+__all__ = ["chase_cycle_pallas", "chase_stage_pallas"]
 
 
 def _reflector_in_kernel(x, pos, axis, acc):
@@ -85,8 +72,8 @@ def _chase_window_vmem(wr, first, *, b_in: int, tw: int):
     scatter), so Mosaic lowers it to masked vector loads/stores.  Returns
     ``(v, tau, v2, tau2)``: the right reflector as a (1, tw+1) row, the left
     one transposed to a row too, and both taus as (1, 1) — shared by the
-    K=1 kernel and every fused cycle of the super-step kernel, so fusing
-    changes data movement only, never an arithmetic operation.
+    per-cycle kernel and the band-resident stage kernel, so the two paths
+    differ in data movement only, never in an arithmetic operation.
     """
     h = b_in + 2 * tw + 1
     w = b_in + tw + 1
@@ -195,126 +182,13 @@ def chase_cycle_pallas(windows: jax.Array, is_first: jax.Array, *, b_in: int,
 
 
 # ---------------------------------------------------------------------------
-# Fuse-depth-K super-steps (DESIGN.md §9)
-# ---------------------------------------------------------------------------
-
-def _roll(x, shift: int, axis: int, **stride):
-    """``pltpu.roll`` with an int32 shift whatever jax_enable_x64 says."""
-    return pltpu.roll(x, np.int32(shift), axis, **stride)
-
-
-def _chase_superstep_kernel(first_ref, act_ref, revt_ref, out_ref, *refs,
-                            b_in: int, tw: int, fuse: int):
-    # refs: optionally (vs_ref, taus_ref) when the reflector tape is
-    # recorded, then the (L, L) dense workspace and one (H, W) window.
-    *tape, ws_ref, win_ref = refs
-    h = b_in + 2 * tw + 1
-    w = b_in + tw + 1
-    wk = fuse * b_in + tw + 1
-    ell = ws_ref.shape[0]
-    dt = ws_ref.dtype
-    g = pl.program_id(0)
-    first = first_ref[g] != 0
-    # Flatten shear on the XLU.  The wrapper hands the kernel the block
-    # reversed and transposed, ``revt[c, r] = block[H-1-r, c]``; at the
-    # corner of a zeroed (L, L) workspace, a sublane-strided lane roll shifts
-    # row c right by c, and one transpose gives the rolled dense form
-    # ``dense[y, c] = revt[c, y - c]`` in which matrix rows align with
-    # workspace rows.  ``L >= H + WK - 1`` keeps the wrapped cells zero.
-    ws_ref[...] = jnp.zeros((ell, ell), dt)
-    ws_ref[0:wk, 0:h] = revt_ref[...]
-    ws_ref[...] = _roll(ws_ref[...], 0, 1, stride=1, stride_axis=0).T
-    for i in range(fuse):
-        # cycle i's window sits at the workspace origin (the previous cycle
-        # rolled it there): the tw+1-column overlap with cycle i-1's window
-        # is already updated — the residency the host round trip threw away.
-        act = act_ref[g * fuse + i] != 0
-        saved = ws_ref[0:h, 0:w]
-        win_ref[...] = saved
-        v, tau, v2, tau2 = _chase_window_vmem(
-            win_ref, first if i == 0 else False, b_in=b_in, tw=tw)
-        ws_ref[0:h, 0:w] = jnp.where(act, win_ref[...], saved)
-        if tape:
-            _record_pair(*tape, 2 * i, v, tau, v2, tau2)
-        if i + 1 < fuse:
-            ws = _roll(ws_ref[...], ell - b_in, 0)
-            ws_ref[...] = _roll(ws, ell - b_in, 1)
-    ws = ws_ref[...]
-    if fuse > 1:
-        back = (fuse - 1) * b_in
-        ws = _roll(_roll(ws, back, 0), back, 1)
-    # Mosaic has no lane roll by minus the row index, so the inverse shear
-    # is left to the wrapper: hand back the sheared rows, transposed.
-    ws_ref[...] = ws.T
-    out_ref[...] = ws_ref[0:wk, 0:h + wk - 1]
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-@functools.partial(jax.jit, static_argnames=("b_in", "tw", "fuse",
-                                             "interpret", "with_tape"))
-def chase_superstep_pallas(blocks: jax.Array, is_first: jax.Array,
-                           active: jax.Array, *, b_in: int, tw: int,
-                           fuse: int, interpret: bool = False,
-                           with_tape: bool = False):
-    """blocks: (G, H, WK) disjoint contiguous band blocks, WK = fuse*b_in +
-    tw + 1; is_first: (G,) bool (fused cycle 0 is its sweep's first);
-    active: (G, fuse) bool prefix mask of live cycles per slot.
-
-    One grid step = one K-cycle super-step of one sweep, entirely
-    VMEM-resident; both masks are scalar-prefetched into SMEM.  The row
-    reversal and transpose the in-kernel shear wants, and the inverse shear
-    of the result, are done here, where XLA fuses them into the caller's
-    block gather and scatter.
-    ``with_tape=True`` additionally returns the super-step's reflector tape
-    slice ``(vs (G, fuse, 2, tw+1), taus (G, fuse, 2))``.
-    """
-    g, h, wk = blocks.shape
-    assert h == b_in + 2 * tw + 1 and wk == fuse * b_in + tw + 1, (
-        blocks.shape, b_in, tw, fuse)
-    first = is_first.astype(jnp.int32).reshape(g)
-    act = active.astype(jnp.int32).reshape(g * fuse)
-    kern = functools.partial(_chase_superstep_kernel, b_in=b_in, tw=tw,
-                             fuse=fuse)
-    slot = lambda i, _f, _a: (i, _I0, _I0)
-    dt = blocks.dtype
-    hc = h + wk - 1
-    revt = jnp.swapaxes(blocks[:, ::-1], 1, 2)             # (G, WK, H)
-    out_shape = [jax.ShapeDtypeStruct((g, wk, hc), dt)]
-    out_specs = [pl.BlockSpec((None, wk, hc), slot)]
-    if with_tape:
-        out_shape += [jax.ShapeDtypeStruct((g, 2 * fuse, tw + 1), dt),
-                      jax.ShapeDtypeStruct((g, 2 * fuse, 1), dt)]
-        out_specs += [pl.BlockSpec((None, 2 * fuse, tw + 1), slot),
-                      pl.BlockSpec((None, 2 * fuse, 1), slot)]
-    ell = _round_up(hc, 128)
-    res = pl.pallas_call(
-        kern,
-        out_shape=tuple(out_shape),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(g,),
-            in_specs=[pl.BlockSpec((None, wk, h), slot)],  # band block
-            out_specs=tuple(out_specs),
-            scratch_shapes=[pltpu.VMEM((ell, ell), dt),
-                            pltpu.VMEM((h, b_in + tw + 1), dt)]),
-        interpret=interpret,
-        name="chase_superstep",
-    )(first, act, revt)
-    # inverse shear: block[H-1-r, c] = sheared[c, r + c]
-    cc = jnp.arange(wk)[None, :]
-    out = res[0][:, cc, jnp.arange(h)[::-1, None] + cc]      # (G, H, WK)
-    if with_tape:
-        return (out, res[1].reshape(g, fuse, 2, tw + 1),
-                res[2].reshape(g, fuse, 2))
-    return out
-
-
-# ---------------------------------------------------------------------------
 # Band-resident stage
 # ---------------------------------------------------------------------------
+
+def _roll(x, shift: int, axis: int):
+    """``pltpu.roll`` with an int32 shift whatever jax_enable_x64 says."""
+    return pltpu.roll(x, np.int32(shift), axis)
+
 
 def _loop32(count: int, body) -> None:
     """``for i in range(count): body(i)`` with an int32 counter whatever
@@ -363,7 +237,7 @@ def _chase_stage_kernel(band_hbm, out_hbm, *refs, n: int, b_in: int,
                                      tsem.at[s])
 
     def slot(t3, r, g, s=None):
-        # chase_cycle_indices at fuse = 1 for cycle t = 3*t3 + r, without the
+        # chase_cycle_indices for cycle t = 3*t3 + r, without the
         # scalar floor division Mosaic cannot lower under jax_enable_x64
         sweep, j = t3 - g, r + 3 * g
         p = sweep + b_out + j * b_in
@@ -437,7 +311,7 @@ def chase_stage_pallas(band: jax.Array, *, n: int, b_in: int, tw: int,
                        interpret: bool = False, with_tape: bool = False):
     """band: (B, H, ncols) packed storage, ncols >= n.  Runs one whole
     stage (bandwidth b_in -> b_in - tw) with each matrix's band resident in
-    VMEM, in the streamed K = 1 wavefront order, so the output is the same
+    VMEM, in the streamed wavefront order, so the output is the same
     band bit for bit.
 
     Grid (B,): step b copies matrix b's band in once, chases every cycle of
@@ -446,7 +320,7 @@ def chase_stage_pallas(band: jax.Array, *, n: int, b_in: int, tw: int,
     and back is one XLA transpose each way here.
 
     ``with_tape=True`` also returns the stage's reflector tape as the
-    streamed K = 1 stage records it, ``(vs (B, T, G, 2, tw+1), taus (B, T,
+    streamed stage records it, ``(vs (B, T, G, 2, tw+1), taus (B, T,
     G, 2))``, with v = tau = 0 on inactive slots.  The kernel writes it to
     HBM by one DMA per cycle from a double-buffered VMEM staging slot
     (DESIGN.md §8): at O(n^2) words the tape cannot stay in VMEM."""

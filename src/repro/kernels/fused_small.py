@@ -1,7 +1,7 @@
 """One-dispatch fused small-n SVD kernel (DESIGN.md §13).
 
 For the serve tier's dominant workload — thousands of small matrices per
-step — the staged pipeline pays one kernel dispatch per chase super-step,
+step — the staged pipeline pays one kernel dispatch per streamed chase cycle,
 so launch overhead, not bandwidth, bounds latency.  Following the batched
 small-size design point (Abdelfattah & Fasi, PAPERS.md: one thread block
 per matrix, whole problem resident on chip), this module runs the ENTIRE

@@ -48,8 +48,7 @@ def register_backend(name: str, **impls: Callable) -> None:
     Every impl takes the op's arrays plus its static kwargs and an
     ``interpret`` kwarg (ignored by non-Pallas backends).  ``chase_cycle``
     impls additionally always receive ``with_tape`` (record the reflector
-    tape, static), ``fuse`` (super-step depth, static) and ``active`` (the
-    per-fused-cycle mask operand, None at fuse=1).
+    tape, static).
     """
     _REGISTRY.setdefault(name, {}).update(impls)
 
@@ -116,18 +115,11 @@ def resolved_backend(backend: str = "auto", config=None, dtype=None) -> str:
 
 # ---- built-in "ref" (pure jnp; interpret flag ignored) ---------------------
 
-def _ref_chase(windows, is_first, *, b_in, tw, with_tape, interpret, fuse=1,
-               active=None):
-    if fuse == 1:
-        return _ref.chase_cycle_ref(windows, is_first, b_in=b_in, tw=tw,
-                                    with_tape=with_tape)
-    return _ref.chase_superstep_ref(windows, is_first, active, b_in=b_in,
-                                    tw=tw, fuse=fuse, with_tape=with_tape)
-
-
 register_backend(
     "ref",
-    chase_cycle=_ref_chase,
+    chase_cycle=lambda windows, is_first, *, b_in, tw, with_tape, interpret:
+        _ref.chase_cycle_ref(windows, is_first, b_in=b_in, tw=tw,
+                             with_tape=with_tape),
     hh_block_apply=lambda v, t, c, *, block_cols, interpret:
         _ref.hh_block_apply_ref(v, t, c),
     tape_apply=lambda v, t, c, *, block_cols, interpret:
@@ -139,17 +131,11 @@ register_backend(
 
 # ---- built-in "pallas" (lazy kernel imports keep CPU-only paths light) -----
 
-def _pallas_chase(windows, is_first, *, b_in, tw, with_tape, interpret,
-                  fuse=1, active=None):
+def _pallas_chase(windows, is_first, *, b_in, tw, with_tape, interpret):
     from repro.kernels import bulge_chase
-    if fuse == 1:
-        return bulge_chase.chase_cycle_pallas(windows, is_first, b_in=b_in,
-                                              tw=tw, interpret=interpret,
-                                              with_tape=with_tape)
-    return bulge_chase.chase_superstep_pallas(windows, is_first, active,
-                                              b_in=b_in, tw=tw, fuse=fuse,
-                                              interpret=interpret,
-                                              with_tape=with_tape)
+    return bulge_chase.chase_cycle_pallas(windows, is_first, b_in=b_in,
+                                          tw=tw, interpret=interpret,
+                                          with_tape=with_tape)
 
 
 def _pallas_chase_stage(band, *, n, b_in, tw, with_tape, interpret):
@@ -213,35 +199,25 @@ register_backend("fused_small",
 
 @functools.partial(jax.jit,
                    static_argnames=("b_in", "tw", "backend", "interpret",
-                                    "config", "with_tape", "fuse"))
+                                    "config", "with_tape"))
 def chase_cycle(windows: jax.Array, is_first: jax.Array, *, b_in: int, tw: int,
                 backend: str = "auto", interpret: bool | None = None,
-                config=None, with_tape: bool = False, fuse: int = 1,
-                active: jax.Array | None = None):
-    """Process one wavefront of bulge-chase (super-)cycles.
+                config=None, with_tape: bool = False):
+    """Process one wavefront of bulge-chase cycles.
 
-    ``fuse=1`` (default): windows: (G, H, W) rolled dense windows
-    (disjoint); is_first: (G,) bool.  With a leading batch axis folded in,
-    G = B * G_matrix — independent problems simply widen the wavefront (one
-    fused call either way).
-
-    ``fuse=K >= 2`` (super-steps, DESIGN.md §9): the operand is instead the
-    wavefront's CONTIGUOUS band-storage blocks (G, H, K*b_in + tw + 1) —
-    K consecutive chase windows per slot, rolled to dense form inside the
-    kernel — plus ``active`` (G, K), the per-fused-cycle liveness prefix
-    mask.  Each slot chases its K cycles sequentially in fast memory, so a
-    dispatch retires K times the cycles of a K=1 call.
+    windows: (G, H, W) rolled dense windows (disjoint); is_first: (G,)
+    bool.  With a leading batch axis folded in, G = B * G_matrix —
+    independent problems simply widen the wavefront (one call either way).
 
     ``with_tape=True`` returns ``(windows, vs, taus)`` — the reflector-tape
     slice for this wavefront (right reflector at pair index 0, left at 1),
     recorded alongside the identical window update; shapes
-    ``(G, 2, tw+1)``/``(G, 2)`` at fuse=1 and ``(G, K, 2, tw+1)``/
-    ``(G, K, 2)`` fused.
+    ``(G, 2, tw+1)``/``(G, 2)``.
     """
     backend, interpret = _resolve(backend, interpret, config, windows.dtype)
     return _impl("chase_cycle", backend)(windows, is_first, b_in=b_in, tw=tw,
-                                         with_tape=with_tape, fuse=fuse,
-                                         active=active, interpret=interpret)
+                                         with_tape=with_tape,
+                                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
@@ -253,8 +229,8 @@ def chase_stage(band: jax.Array, *, n: int, b_in: int, tw: int,
     """One whole stage (bandwidth b_in -> b_in - tw) on packed storage
     ``(B, H, ncols)``, each matrix's band resident in fast memory for the
     stage (DESIGN.md §9).  ``with_tape=True`` returns ``(band, vs, taus)``
-    with the stage's reflector tape in the K = 1 layout ``(B, T, G, 2,
-    tw+1)`` / ``(B, T, G, 2)``.  Only the "pallas" backend implements it;
+    with the stage's reflector tape ``(B, T, G, 2, tw+1)`` / ``(B, T, G,
+    2)``.  Only the "pallas" backend implements it;
     ``core.bulge_chasing.stage_path`` says when it is used."""
     backend, interpret = _resolve(backend, interpret, config, band.dtype)
     return _impl("chase_stage", backend)(band, n=n, b_in=b_in, tw=tw,

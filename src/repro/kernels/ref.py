@@ -26,8 +26,8 @@ from repro.core.householder import make_reflector
 
 _HI = jax.lax.Precision.HIGHEST       # as exact_matmul, for the einsums
 
-__all__ = ["chase_window_ref", "chase_cycle_ref", "chase_superstep_ref",
-           "hh_block_apply_ref", "tape_apply_ref", "fused_small_svd_ref"]
+__all__ = ["chase_window_ref", "chase_cycle_ref", "hh_block_apply_ref",
+           "tape_apply_ref", "fused_small_svd_ref"]
 
 
 def _chase_window(window: jax.Array, is_first: jax.Array, *, b_in: int,
@@ -94,89 +94,6 @@ def chase_cycle_ref(windows: jax.Array, is_first: jax.Array, *, b_in: int,
         return out, jnp.stack([v, v2]), jnp.stack([tau, tau2])
 
     out, vs, taus = jax.vmap(fn)(windows, is_first)
-    if with_tape:
-        return out, vs, taus
-    return out
-
-
-def chase_superstep_ref(blocks: jax.Array, is_first: jax.Array,
-                        active: jax.Array, *, b_in: int, tw: int, fuse: int,
-                        with_tape: bool = False):
-    """Fuse-depth-K super-step oracle on contiguous band-storage blocks.
-
-    blocks: (G, H, WK) with WK = fuse*b_in + tw + 1 — each slot's K
-    consecutive chase windows as ONE column block of the packed storage;
-    is_first: (G,) — fused cycle 0 is its sweep's first cycle;
-    active: (G, fuse) — per-fused-cycle activity (a prefix mask; inactive
-    cycles leave the block untouched and their recorded pair is discarded
-    by the caller via ``tau = 0``).
-
-    The roll to dense windows happens HERE (the fast-memory-resident
-    analogue of the host-side K=1 gather): window i of a slot is the shear
-    ``win_i[y, w] = rev[y - w, i*b_in + w]`` (``rev = block[::-1]``, zero
-    above the diagonal ``y < w``), all K gathered in ONE indexed read.  The
-    K cycles then chase sequentially; consecutive windows overlap in a
-    ``(2*tw+1, tw+1)`` dense corner, and because the overlaps are *nested*
-    (window i's intersection with ANY earlier window lies inside window
-    i-1's footprint), patching that single corner from cycle i-1's output
-    forwards every earlier update — the ``tw+1``-column overlap reuse of
-    DESIGN.md §9.  One static select per block cell (latest covering
-    window, else the untouched input) shears everything back.  Reflector
-    math is :func:`_chase_window`, identical to the K=1 path, so fusing
-    does not change a single arithmetic operation.
-
-    ``with_tape=True`` additionally returns ``vs (G, fuse, 2, tw+1)`` and
-    ``taus (G, fuse, 2)`` (pair axis: right reflector first, then left).
-    """
-    G, H, WK = blocks.shape
-    assert H == b_in + 2 * tw + 1 and WK == fuse * b_in + tw + 1, (
-        blocks.shape, b_in, tw, fuse)
-    W = b_in + tw + 1
-    K = fuse
-
-    # static shear indices: all K windows of one block in one gather
-    ii = jnp.arange(K)[:, None, None]                 # (K, 1, 1)
-    yy = jnp.arange(H)[None, :, None]                 # (1, H, 1)
-    ww = jnp.arange(W)[None, None, :]                 # (1, 1, W)
-    win_rows = jnp.clip(yy - ww, 0, H - 1)            # rev row per window cell
-    win_cols = ii * b_in + ww
-    win_valid = yy >= ww
-    # static un-shear: latest window covering each block cell (else input)
-    dd = jnp.arange(H)[:, None]
-    cc = jnp.arange(WK)[None, :]
-    y_dense = cc + (H - 1 - dd)                       # dense row of band cell
-    i_hi = jnp.minimum(jnp.minimum(y_dense // b_in, cc // b_in), K - 1)
-    i_lo = jnp.maximum(jnp.maximum(-((H - 1 - y_dense) // b_in),
-                                   -((W - 1 - cc) // b_in)), 0)
-    covered = i_hi >= i_lo
-    sel = jnp.clip(i_hi, 0, K - 1)
-    sel_y = jnp.clip(y_dense - sel * b_in, 0, H - 1)
-    sel_w = jnp.clip(cc - sel * b_in, 0, W - 1)
-
-    def one(block, first, act):
-        rev = block[::-1]
-        wins = jnp.where(win_valid, rev[win_rows, win_cols], 0)   # (K, H, W)
-        outs, vs, taus = [], [], []
-        for i in range(K):
-            win = wins[i]
-            if i > 0:
-                # nested-overlap patch: window i's shared cells with every
-                # earlier window lie inside window i-1's footprint, so one
-                # corner copy forwards all pending updates.
-                win = win.at[:H - b_in, :W - b_in].set(
-                    outs[-1][b_in:, b_in:])
-            out, (v, tau), (v2, tau2) = _chase_window(
-                win, first if i == 0 else jnp.bool_(False), b_in=b_in, tw=tw)
-            out = jnp.where(act[i], out, win)
-            outs.append(out)
-            vs.append(jnp.stack([v, v2]))
-            taus.append(jnp.stack([tau, tau2]))
-        stacked = jnp.stack(outs)                                 # (K, H, W)
-        block_out = jnp.where(covered, stacked[sel, sel_y, sel_w],
-                              block)
-        return block_out, jnp.stack(vs), jnp.stack(taus)
-
-    out, vs, taus = jax.vmap(one)(blocks, is_first, active)
     if with_tape:
         return out, vs, taus
     return out

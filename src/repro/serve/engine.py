@@ -207,7 +207,7 @@ class SVDEngine:
     persistent tuned-config cache (DESIGN.md §11): the first flush of a
     bucket key looks up the measured optimum for that exact ``(device, n,
     bw, dtype, compute_uv, backend)``.  Precedence is explicit: opting in
-    means a cache HIT overrides the engine config's ``tw``/``fuse`` for
+    means a cache HIT overrides the engine config's ``tw`` for
     that bucket — per-bucket measured optima are the point of the flag,
     and the engine config's knobs were resolved for its own default
     shape, not this bucket's; on a MISS the engine's own config stays in
@@ -387,24 +387,24 @@ class SVDEngine:
                 dtype=np.dtype(dtype).name, compute_uv=compute_uv,
                 backend=self.config.backend, path=self.autotune_cache)
         if entry is not None:
-            # Tuned bucket: the measured optimum decides tw/fuse (and
+            # Tuned bucket: the measured optimum decides tw (and
             # max_batch when the search explored the batch axis — absent
             # otherwise, leaving the Eq.-1 default in charge).  The engine
             # max_batch remains a cap.
             eff = min(self.config.max_batch,
                       entry.get("max_batch")
                       or tuning.default_bucket_batch(n, bw))
-            tw, fuse = entry["tw"], entry["fuse"]
+            tw = entry["tw"]
         else:
             # Cache miss (or autotune off): the engine's own resolved
-            # config stays in charge — an explicitly-configured tw/fuse is
+            # config stays in charge — an explicitly-configured tw is
             # never silently discarded.  The engine's max_batch is a CAP;
             # per bucket it is tightened by the Eq.-1 occupancy default so
             # large matrices (whose own wavefront already saturates the
             # chip) are not zero-padded 8x for nothing.
             eff = min(self.config.max_batch,
                       tuning.default_bucket_batch(n, bw))
-            tw, fuse = self.config.tw, self.config.fuse
+            tw = self.config.tw
 
         # Stage-3 policy (§14): "auto" + the bucket's crossover collapses to
         # a concrete solver inside resolve (n is known here); dc_n_min < 1
@@ -416,8 +416,7 @@ class SVDEngine:
             return tuning.PipelineConfig.resolve(
                 bw=bw, tw=tw, backend=backend,
                 interpret=self.config.interpret, dtype=np.dtype(dtype), n=n,
-                max_batch=max(1, eff), unroll=self.config.unroll,
-                compute_uv=compute_uv, fuse=fuse, stage3=stage3,
+                max_batch=max(1, eff), compute_uv=compute_uv, stage3=stage3,
                 dc_leaf_n=self.config.dc_leaf_n, dc_n_min=max(dmin, 1))
 
         cfg = None
@@ -601,8 +600,8 @@ class SVDEngine:
             n, bw, dtype, _banded, compute_uv = key
             self._degraded_memo[key] = tuning.PipelineConfig.resolve(
                 bw=bw, backend="ref", dtype=np.dtype(dtype), n=n,
-                max_batch=self.config.max_batch, unroll=self.config.unroll,
-                compute_uv=compute_uv, stage3="bisect")
+                max_batch=self.config.max_batch, compute_uv=compute_uv,
+                stage3="bisect")
         return self._degraded_memo[key]
 
     def _note_failure(self, key: tuple, exc: Exception) -> None:

@@ -2,24 +2,22 @@
 
 Four groups:
 
-* cost-model properties — strictly cheaper with fuse until the VMEM cliff,
-  monotone in n / bw / dtype byte-width, exact units vs a hand-computed
-  small case;
+* cost-model properties — monotone in n / bw / dtype byte-width, exact
+  units vs a hand-computed small case;
 * cache — round trip, atomicity contract (merge keeps other keys),
   corruption tolerance (garbage file reads as empty, half-written entries
-  never half-configure);
+  never half-configure), and files written by older tuners still load;
 * search — CPU ref end-to-end smoke: the returned config beats or ties
   the static default on measured time, the model ranks the measured best
   within top-K, injectable-measure unit behavior;
 * integration — the acceptance loop: ``python -m repro.autotune`` (in
   process) persists an entry that ``PipelineConfig.resolve(autotune=True)``
   then picks up, including through ``SVDEngine``'s per-bucket resolution;
-  plus the degenerate-edge guards (``default_fuse_depth`` floor,
-  ``check_vmem_budget`` raising instead of silently mis-tiling).
+  plus the degenerate-edge guard (``check_vmem_budget`` raising instead
+  of silently mis-tiling).
 """
 
 import json
-import math
 import os
 
 import jax.numpy as jnp
@@ -41,20 +39,6 @@ CPU = at_model.PROFILES["cpu"]
 # ---------------------------------------------------------------------------
 
 class TestCostModel:
-    def test_cost_strictly_decreases_with_fuse_until_vmem_cliff(self):
-        # A budget that admits K in {1, 2, 4} but not 8: costs must fall
-        # strictly while feasible, then hit the cliff (inf).
-        budget = tuning.vmem_working_set_bytes(32, 8, fuse=4) + 1
-        prof = at_model.DeviceProfile("t", mem_bw=CPU.mem_bw,
-                                      launch_overhead_s=CPU.launch_overhead_s,
-                                      fast_mem_bytes=budget,
-                                      execution_units=1)
-        costs = [at_model.stage_cost(1024, 32, 8, fuse=k, profile=prof)
-                 for k in (1, 2, 4, 8)]
-        assert costs[0].seconds > costs[1].seconds > costs[2].seconds
-        assert math.isinf(costs[3].seconds) and not costs[3].feasible
-        assert all(c.feasible for c in costs[:3])
-
     def test_monotone_in_n(self):
         costs = [at_model.stage_cost(n, 32, 8, profile=CPU).seconds
                  for n in (128, 256, 512, 1024)]
@@ -73,10 +57,10 @@ class TestCostModel:
         assert f64.bytes_moved == 2 * f32.bytes_moved
 
     def test_units_sanity_hand_computed(self):
-        # n=16, b_in=4, tw=2, fuse=1, batch=1 on a 1 GB/s, 1 us-launch,
+        # n=16, b_in=4, tw=2, batch=1 on a 1 GB/s, 1 us-launch,
         # single-unit device.  By hand: H=9, W=7; cycles = sum_{r<13}
         # ((13-r)//4 + 1) = 31; bytes = 31 * 2*9*7 * 4 = 15624;
-        # supercycles = 3*12 + 1 = 37.
+        # dispatches = 3*12 + 1 = 37.
         prof = at_model.DeviceProfile("hand", mem_bw=1e9,
                                       launch_overhead_s=1e-6,
                                       fast_mem_bytes=1 << 30,
@@ -84,7 +68,7 @@ class TestCostModel:
         c = at_model.stage_cost(16, 4, 2, profile=prof)
         assert c.cycles == 31
         assert c.bytes_moved == 15624.0
-        assert c.supercycles == 37
+        assert c.dispatches == 37
         assert c.mem_seconds == pytest.approx(15624.0 / 1e9)
         assert c.launch_seconds == pytest.approx(37e-6)
         assert c.seconds == pytest.approx(c.mem_seconds + c.launch_seconds)
@@ -136,18 +120,18 @@ KEY = dict(device_kind="testdev", n=128, bw=16, dtype="float32",
 class TestCache:
     def test_round_trip(self, tmp_path):
         p = str(tmp_path / "cache.json")
-        entry = {"tw": 8, "fuse": 2, "max_batch": 4, "measured_us": 12.5}
+        entry = {"tw": 8, "max_batch": 4, "measured_us": 12.5}
         assert at_cache.lookup(**KEY, path=p) is None
         at_cache.store(entry, **KEY, path=p)
         got = at_cache.lookup(**KEY, path=p)
-        assert got["tw"] == 8 and got["fuse"] == 2 and got["max_batch"] == 4
+        assert got["tw"] == 8 and got["max_batch"] == 4
         assert "tuned_at_unix" in got
 
     def test_merge_keeps_other_keys(self, tmp_path):
         p = str(tmp_path / "cache.json")
         other = dict(KEY, n=256)
-        at_cache.store({"tw": 8, "fuse": 2, "max_batch": 4}, **KEY, path=p)
-        at_cache.store({"tw": 4, "fuse": 1, "max_batch": 2}, **other, path=p)
+        at_cache.store({"tw": 8, "max_batch": 4}, **KEY, path=p)
+        at_cache.store({"tw": 4, "max_batch": 2}, **other, path=p)
         assert at_cache.lookup(**KEY, path=p)["tw"] == 8
         assert at_cache.lookup(**other, path=p)["tw"] == 4
 
@@ -158,30 +142,54 @@ class TestCache:
         assert at_cache.load(p)["entries"] == {}
         assert at_cache.lookup(**KEY, path=p) is None
         # store() over the corrupt file recovers it
-        at_cache.store({"tw": 8, "fuse": 2, "max_batch": 4}, **KEY, path=p)
+        at_cache.store({"tw": 8, "max_batch": 4}, **KEY, path=p)
         assert at_cache.lookup(**KEY, path=p)["tw"] == 8
         json.load(open(p))                        # file is valid JSON again
 
     def test_wrong_schema_and_partial_entries_rejected(self, tmp_path):
         p = str(tmp_path / "cache.json")
         doc = {"version": 999, "entries": {at_cache.make_key(**KEY):
-                                           {"tw": 8, "fuse": 2,
-                                            "max_batch": 4}}}
+                                           {"tw": 8, "max_batch": 4}}}
         with open(p, "w") as f:
             json.dump(doc, f)
         assert at_cache.lookup(**KEY, path=p) is None   # version mismatch
-        # Valid version but half-written entry (missing fuse): rejected.
+        # Valid version but half-written entry (missing tw): rejected.
         doc["version"] = at_cache.SCHEMA_VERSION
-        doc["entries"][at_cache.make_key(**KEY)] = {"tw": 8, "max_batch": 4}
+        doc["entries"][at_cache.make_key(**KEY)] = {"max_batch": 4}
         with open(p, "w") as f:
             json.dump(doc, f)
         assert at_cache.lookup(**KEY, path=p) is None
+        # A missing retired knob (fuse) is no longer part of an entry.
+        doc["entries"][at_cache.make_key(**KEY)] = {"tw": 8, "max_batch": 4}
+        with open(p, "w") as f:
+            json.dump(doc, f)
+        assert at_cache.lookup(**KEY, path=p)["tw"] == 8
+
+    def test_older_entry_with_fuse_still_resolves(self, tmp_path):
+        """A cache file as older tuners wrote it — same schema version, a
+        ``fuse`` knob in the entry — still configures tw and max_batch;
+        its fuse is ignored."""
+        p = str(tmp_path / "cache.json")
+        key = dict(KEY, device_kind=at_model.device_kind())
+        doc = {"version": 1, "entries": {at_cache.make_key(**key): {
+            "tw": 3, "fuse": 4, "max_batch": 7, "measured_us": 12.5,
+            "predicted_us": 10.0, "default_measured_us": 13.0,
+            "model_rank_of_best": 1, "schema": 1,
+            "tuned_at_unix": 1700000000}}}
+        with open(p, "w") as f:
+            json.dump(doc, f, indent=2, sort_keys=True)
+        assert at_cache.lookup(**key, path=p)["tw"] == 3
+        cfg = tuning.PipelineConfig.resolve(
+            n=key["n"], bw=key["bw"], backend="ref", autotune=True,
+            autotune_cache=p)
+        assert (cfg.tw, cfg.max_batch) == (3, 7)
+        assert not hasattr(cfg, "fuse")
 
     def test_env_var_overrides_path(self, tmp_path, monkeypatch):
         p = str(tmp_path / "env-cache.json")
         monkeypatch.setenv(at_cache.ENV_VAR, p)
         assert at_cache.cache_path() == p
-        at_cache.store({"tw": 8, "fuse": 2, "max_batch": 4}, **KEY)
+        at_cache.store({"tw": 8, "max_batch": 4}, **KEY)
         assert os.path.exists(p)
         assert at_cache.lookup(**KEY)["tw"] == 8
 
@@ -193,63 +201,61 @@ class TestCache:
 class TestSearch:
     def test_grid_contains_anchors(self):
         grid = at_search.candidate_grid(512, 32)
-        tws = {t for t, _, _ in grid}
+        tws = {t for t, _ in grid}
         assert {1, 2, 4, 8, 16, 31} <= tws
         assert tuning.default_tilewidth(32, jnp.float32) in tws
         assert all(1 <= t <= 31 for t in tws)
 
     def test_model_pruning_with_injected_measure(self):
-        # A fake measurement that inverts the model's opinion of fuse: the
+        # A fake measurement that disagrees with the model's ranking: the
         # search must still return the measured best, and the validation
         # table must expose the disagreement via the rank.
         calls = []
 
-        def fake_measure(tw, fuse, batch):
-            calls.append((tw, fuse, batch))
-            return 1.0 + fuse * 0.5 + abs(tw - 8) * 0.01
+        def fake_measure(tw, batch):
+            calls.append((tw, batch))
+            return 1.0 + abs(tw - 8) * 0.01
 
         res = at_search.search(256, 16, backend="ref", top_k=3,
                                profile=CPU, measure_fn=fake_measure)
         # Only top-K (+ default if outside) measured — pruning is real.
         assert len(calls) == len(res.measured) <= 3 + 1
         best_by_fake = min(res.measured,
-                           key=lambda c: fake_measure(c.tw, c.fuse, c.batch))
-        assert (res.best.tw, res.best.fuse) == (best_by_fake.tw,
-                                                best_by_fake.fuse)
+                           key=lambda c: fake_measure(c.tw, c.batch))
+        assert res.best.tw == best_by_fake.tw
         assert 1 <= res.model_rank_of_best() <= len(res.candidates)
         table = res.table()
         assert "measured_us" in table and "<- best" in table
 
     def test_default_always_measured_and_never_beaten_silently(self):
-        def fake_measure(tw, fuse, batch):
+        def fake_measure(tw, batch):
             d_tw = tuning.default_tilewidth(16, jnp.float32)
-            return 0.5 if (tw, fuse) == (d_tw, 1) else 1.0    # default wins
+            return 0.5 if tw == d_tw else 1.0                 # default wins
 
         res = at_search.search(256, 16, backend="ref", top_k=2,
                                profile=CPU, measure_fn=fake_measure)
         assert res.default in res.measured
-        assert (res.best.tw, res.best.fuse) == (res.default.tw,
-                                                res.default.fuse)
+        assert res.best.tw == res.default.tw
         assert res.best.measured_s <= res.default.measured_s
 
     def test_search_smoke_cpu_beats_or_ties_static_default(self):
         # Real measurements on the ref path, tiny shape: the tuned config
         # must beat or tie the static default (it is in the measured set).
         res = at_search.search(64, 8, backend="ref", top_k=2,
-                               fuses=(1, 2), warmup=1, iters=1)
+                               warmup=1, iters=1)
         assert res.best.measured_s is not None
         assert res.default.measured_s is not None
         assert res.best.measured_s <= res.default.measured_s
         assert res.model_rank_of_best() <= len(res.candidates)
         entry = res.to_entry()
-        assert entry["tw"] >= 1 and entry["fuse"] >= 1
+        assert entry["tw"] >= 1 and "fuse" not in entry
         # batches=(1,) means the batch axis was never searched: persisting
         # max_batch=1 would serialize serve bucketing, so it is omitted.
         assert "max_batch" not in entry
 
     def test_to_entry_round_trips_through_cache(self, tmp_path):
         res = at_search.search(256, 16, backend="ref", top_k=2, profile=CPU,
-                               measure_fn=lambda tw, fuse, batch: 1.0)
+                               measure_fn=lambda tw, batch: 1.0)
         p = str(tmp_path / "cache.json")
         at_cache.store(res.to_entry(), device_kind="testdev", n=256, bw=16,
                        dtype="float32", compute_uv=False, backend="ref",
@@ -257,12 +263,12 @@ class TestSearch:
         got = at_cache.lookup(device_kind="testdev", n=256, bw=16,
                               dtype="float32", compute_uv=False,
                               backend="ref", path=p)
-        assert got["tw"] == res.best.tw and got["fuse"] == res.best.fuse
+        assert got["tw"] == res.best.tw
 
     def test_batch_searched_grid_persists_max_batch(self):
         res = at_search.search(256, 16, backend="ref", top_k=3, profile=CPU,
                                batches=(1, 2, 4),
-                               measure_fn=lambda tw, fuse, batch:
+                               measure_fn=lambda tw, batch:
                                    1.0 / (1 + 0.1 * batch))
         assert res.batch_searched
         assert res.to_entry()["max_batch"] == res.best.batch >= 1
@@ -281,13 +287,6 @@ class TestSearch:
 # ---------------------------------------------------------------------------
 
 class TestDegenerateEdges:
-    def test_default_fuse_depth_never_below_one(self):
-        for budget in (0, 1, -5, 100):
-            assert tuning.default_fuse_depth(32, 8,
-                                             budget_bytes=budget) == 1
-        assert tuning.default_fuse_depth(32, 8, cap=0) == 1
-        assert tuning.default_fuse_depth(32, 8, cap=-3) == 1
-
     def test_check_vmem_budget_raises_clearly(self):
         with pytest.raises(ValueError, match="fast memory"):
             tuning.check_vmem_budget(32, 8, budget_bytes=16)
@@ -305,9 +304,8 @@ class TestDegenerateEdges:
             tuning.ChaseConfig.resolve(8192, 4096, tw=1024)
 
     def test_normal_shapes_still_resolve(self):
-        cfg = tuning.PipelineConfig.resolve(bw=64, n=1024, backend="ref",
-                                            fuse=None)
-        assert cfg.fuse >= 1
+        cfg = tuning.PipelineConfig.resolve(bw=64, n=1024, backend="ref")
+        assert 1 <= cfg.tw <= 63
         tuning.ChaseConfig.resolve(1024, 64)
 
 
@@ -345,7 +343,7 @@ class TestIntegration:
 
         cfg = tuning.PipelineConfig.resolve(n=64, bw=8, backend="ref",
                                             autotune=True)
-        assert (cfg.tw, cfg.fuse) == (entry["tw"], entry["fuse"])
+        assert cfg.tw == entry["tw"]
         # The default CLI grid has batches=(1,): max_batch is NOT tuned and
         # the Eq.-1 analytic bucket default must stay in charge.
         assert "max_batch" not in entry
@@ -375,17 +373,17 @@ class TestIntegration:
     def test_resolve_explicit_kwargs_beat_cache(self, tmp_path, monkeypatch):
         p = str(tmp_path / "cache.json")
         monkeypatch.setenv(at_cache.ENV_VAR, p)
-        at_cache.store({"tw": 3, "fuse": 4, "max_batch": 7},
+        at_cache.store({"tw": 3, "max_batch": 7},
                        device_kind=at_model.device_kind(), n=128, bw=16,
                        dtype="float32", compute_uv=False, backend="ref",
                        path=p)
         cfg = tuning.PipelineConfig.resolve(n=128, bw=16, backend="ref",
                                             autotune=True)
-        assert (cfg.tw, cfg.fuse, cfg.max_batch) == (3, 4, 7)
+        assert (cfg.tw, cfg.max_batch) == (3, 7)
         cfg2 = tuning.PipelineConfig.resolve(n=128, bw=16, backend="ref",
-                                             tw=8, fuse=2, max_batch=2,
+                                             tw=8, max_batch=2,
                                              autotune=True)
-        assert (cfg2.tw, cfg2.fuse, cfg2.max_batch) == (8, 2, 2)
+        assert (cfg2.tw, cfg2.max_batch) == (8, 2)
 
     def test_resolve_miss_falls_back_to_analytic_defaults(self, tmp_path,
                                                           monkeypatch):
@@ -399,13 +397,13 @@ class TestIntegration:
             self, tmp_path, monkeypatch):
         p = str(tmp_path / "cache.json")
         monkeypatch.setenv(at_cache.ENV_VAR, p)
-        at_cache.store({"tw": 3, "fuse": 4},        # batch axis not searched
+        at_cache.store({"tw": 3},                   # batch axis not searched
                        device_kind=at_model.device_kind(), n=128, bw=16,
                        dtype="float32", compute_uv=False, backend="ref",
                        path=p)
         cfg = tuning.PipelineConfig.resolve(n=128, bw=16, backend="ref",
                                             autotune=True)
-        assert (cfg.tw, cfg.fuse) == (3, 4)
+        assert cfg.tw == 3
         assert cfg.max_batch == tuning.default_bucket_batch(128, 16)
 
     def test_engine_resolves_tuned_config_per_bucket(self, tmp_path,
@@ -414,7 +412,7 @@ class TestIntegration:
         p = str(tmp_path / "cache.json")
         monkeypatch.setenv(at_cache.ENV_VAR, p)
         n, bw = 24, 4
-        at_cache.store({"tw": 2, "fuse": 2, "max_batch": 2},
+        at_cache.store({"tw": 2, "max_batch": 2},
                        device_kind=at_model.device_kind(), n=n, bw=bw,
                        dtype="float32", compute_uv=False, backend="ref",
                        path=p)
@@ -427,7 +425,7 @@ class TestIntegration:
             eng.submit(SVDRequest(uid=uid, matrix=a, bw=bw))
         key = (n, bw, "float32", False, False)
         cfg = eng._cfg_for(key)
-        assert (cfg.tw, cfg.fuse, cfg.max_batch) == (2, 2, 2)
+        assert (cfg.tw, cfg.max_batch) == (2, 2)
         assert eng._cfg_for(key) is cfg          # memoized per bucket
         done = eng.run()
         assert len(done) == 3 and eng.calls == 2  # 3 reqs / bucket of 2
@@ -446,14 +444,13 @@ class TestIntegration:
     def test_engine_autotune_miss_keeps_explicit_config(self, tmp_path,
                                                         monkeypatch):
         # An explicitly-configured engine with an empty cache must not have
-        # its tw/fuse silently replaced by the analytic defaults.
+        # its tw silently replaced by the analytic defaults.
         from repro.serve.engine import SVDEngine
         monkeypatch.setenv(at_cache.ENV_VAR, str(tmp_path / "none.json"))
-        base = tuning.PipelineConfig.resolve(bw=16, tw=4, fuse=2,
-                                             backend="ref")
+        base = tuning.PipelineConfig.resolve(bw=16, tw=4, backend="ref")
         cfg = SVDEngine(base, autotune=True)._cfg_for(
             (128, 16, "float32", False, False))
-        assert (cfg.tw, cfg.fuse) == (4, 2)
+        assert cfg.tw == 4
 
 
 # ---------------------------------------------------------------------------

@@ -5,10 +5,11 @@ memory as ONE ``chase_stage`` kernel that keeps each matrix's band in VMEM
 and runs the whole wavefront loop inside, recording the reflector tape
 when one is asked for.  Covered here:
 
-  1. the resident stage equals the streamed K = 1 stage bit for bit, for
-     one and several matrices, single- and multi-stage tile-width plans,
-     and sizes whose last sweeps' windows run off the band; so does its
-     tape, and the full SVD built on it;
+  1. the resident stage equals the streamed stage bit for bit, for one
+     and several matrices, single- and multi-stage tile-width plans, and
+     sizes whose last sweeps' windows run off the band — at fixed shapes
+     and at hypothesis-drawn ones; so does its tape, and the full SVD
+     built on it;
   2. the path is chosen from the input alone: the ref backend, bf16 or
      float64 data and an over-budget band (counted with the tape's staging
      slots when a tape is recorded) keep the streamed path;
@@ -19,13 +20,13 @@ Interpret mode steps the whole T x G wavefront loop on the CPU, so the
 sizes stay small.
 """
 
-import dataclasses
 import re
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro import obs
 from repro.core import band as bandmod
@@ -42,24 +43,44 @@ def banded_f32(n, bw, seed, lead=()):
 
 
 # (n, b_in, tw): b_out = 1 in one stage; a first stage of a two-stage plan;
-# n = 37 leaves the last sweeps' windows past column n - 1 (n + W - 1 > n).
-STAGES = [(32, 6, 5), (40, 8, 3), (37, 7, 6)]
+# n = 37 leaves the last sweeps' windows past column n - 1 (n + W - 1 > n);
+# (26, 5, 2) the first stage of the plan 5 -> 3 -> 1; then shapes from the
+# range n 8-36, b_in 2-7, tw 1 .. b_in - 1, down to a single sweep (n = 8,
+# b_in = 3, tw = 2 has n - 1 - b_out = 6 sweeps of one or two cycles).
+STAGES = [(32, 6, 5), (40, 8, 3), (37, 7, 6), (26, 5, 2),
+          (8, 3, 2), (13, 2, 1), (19, 7, 1), (23, 4, 3), (29, 6, 2),
+          (36, 7, 4)]
+
+
+def _assert_stage_matches(packed, n, b_in, tw):
+    kw = dict(n=n, b_in=b_in, tw=tw, backend="pallas")
+    assert bc.stage_path(packed.dtype, **kw) == "resident"
+    out = np.asarray(bc.reduce_stage_packed(packed, **kw))
+    ref = np.asarray(bc._reduce_stage_streamed(packed, **kw))
+    assert out.shape == ref.shape == packed.shape
+    np.testing.assert_array_equal(out, ref)
+    # the stage did reduce the band: rows past b_out + tw hold zeros only
+    assert not np.any(out[..., tw + (b_in - tw) + 1:, :n])
 
 
 @pytest.mark.parametrize("batch", [1, 3])
 @pytest.mark.parametrize("n,b_in,tw", STAGES)
 def test_resident_stage_matches_streamed_bitwise(n, b_in, tw, batch):
     lead = (batch,) if batch > 1 else ()
-    packed = bandmod.pack(banded_f32(n, b_in, seed=n + batch, lead=lead),
-                          b_in, tw)
-    kw = dict(n=n, b_in=b_in, tw=tw, backend="pallas")
-    assert bc.stage_path(packed.dtype, **kw) == "resident"
-    out = np.asarray(bc.reduce_stage_packed(packed, **kw))
-    ref = np.asarray(bc._reduce_stage_streamed(packed, fuse=1, **kw))
-    assert out.shape == ref.shape == packed.shape
-    np.testing.assert_array_equal(out, ref)
-    # the stage did reduce the band: rows past b_out + tw hold zeros only
-    assert not np.any(out[..., tw + (b_in - tw) + 1:, :n])
+    _assert_stage_matches(
+        bandmod.pack(banded_f32(n, b_in, seed=n + batch, lead=lead),
+                     b_in, tw), n, b_in, tw)
+
+
+@settings(max_examples=4, deadline=None)
+@given(st.integers(8, 36), st.integers(2, 7), st.data(), st.booleans())
+def test_resident_stage_matches_streamed_randomized(n, b_in, data, batched):
+    tw = data.draw(st.integers(1, b_in - 1), label="tw")
+    seed = data.draw(st.integers(0, 2 ** 16), label="seed")
+    lead = (2,) if batched else ()
+    _assert_stage_matches(
+        bandmod.pack(banded_f32(n, b_in, seed=seed, lead=lead), b_in, tw),
+        n, b_in, tw)
 
 
 def test_resident_stage_passes_wide_storage_through():
@@ -94,7 +115,7 @@ def test_resident_plan_matches_streamed_bitwise(batch):
         strm = jax.lax.slice_in_dim(strm, start, start + h, axis=-2)
         kw = dict(n=n, b_in=b_in, tw=twi, backend="pallas")
         res = bc.reduce_stage_packed(res, **kw)
-        strm = bc._reduce_stage_streamed(strm, fuse=1, **kw)
+        strm = bc._reduce_stage_streamed(strm, **kw)
         np.testing.assert_array_equal(np.asarray(res), np.asarray(strm))
         tw_cur = twi
     d, e = bc.bidiagonalize(banded_f32(n, bw, seed=4, lead=lead), bw=bw,
@@ -105,20 +126,6 @@ def test_resident_plan_matches_streamed_bitwise(batch):
     np.testing.assert_array_equal(np.asarray(e),
                                   np.asarray(bandmod.band_extract_diag(
                                       strm, tw_cur, 1, n)))
-
-
-def test_resident_stage_ignores_fuse():
-    """The resident path is chosen whatever ``fuse`` says, and matches the
-    streamed super-step output at every depth."""
-    n, b_in, tw = 34, 6, 3
-    packed = bandmod.pack(banded_f32(n, b_in, seed=2), b_in, tw)
-    kw = dict(n=n, b_in=b_in, tw=tw, backend="pallas")
-    base = np.asarray(bc.reduce_stage_packed(packed, **kw))
-    for k in (2, 4):
-        np.testing.assert_array_equal(
-            np.asarray(bc.reduce_stage_packed(packed, fuse=k, **kw)), base)
-        np.testing.assert_array_equal(
-            np.asarray(bc._reduce_stage_streamed(packed, fuse=k, **kw)), base)
 
 
 def _assert_tape_stage_matches(res, strm, n, b_in, tw):
@@ -138,17 +145,18 @@ def _assert_tape_stage_matches(res, strm, n, b_in, tw):
     assert not v[..., idle, :, :].any() and not tau[..., idle, :].any()
 
 
+# (19, 7, 1) is left out: its plan has six stages, and its first stage is
+# already in test_resident_stage_matches_streamed_bitwise
 @pytest.mark.parametrize("batch", [1, 3])
-@pytest.mark.parametrize("case", ["stage", "plan"])
-def test_resident_tape_matches_streamed_bitwise(case, batch):
-    """The resident stage's tape against the streamed K = 1 stage's: one
-    stage whose last windows run off the band (n = 37), and every stage of
-    the plan bw 8 -> 5 -> 2 -> 1 at n = 40, tw = 3."""
-    n, bw, tw = (37, 7, 6) if case == "stage" else (40, 8, 3)
+@pytest.mark.parametrize("n,bw,tw", [s for s in STAGES[1:] if s != (19, 7, 1)])
+def test_resident_tape_matches_streamed_bitwise(n, bw, tw, batch):
+    """The resident stage's tape against the streamed stage's, for every
+    stage of the plan ``stage_plan(bw, tw)``: one stage whose last windows
+    run off the band (n = 37), the plan bw 8 -> 5 -> 2 -> 1 at n = 40,
+    tw = 3, and the plans of the shapes after it in ``STAGES``."""
     lead = (batch,) if batch > 1 else ()
     cur = bandmod.pack(banded_f32(n, bw, seed=n + batch, lead=lead), bw, tw)
     plan = tuning.stage_plan(bw, tw)
-    assert len(plan) == (1 if case == "stage" else 3)
     tw_cur = tw
     for b_in, twi in plan:
         start = tw_cur - twi
@@ -157,7 +165,7 @@ def test_resident_tape_matches_streamed_bitwise(case, batch):
         kw = dict(n=n, b_in=b_in, tw=twi, backend="pallas", tape=True)
         assert bc.stage_path(cur.dtype, **kw) == "resident"
         res = bc.reduce_stage_packed(cur, **kw)
-        strm = bc._reduce_stage_streamed(cur, fuse=1, **kw)
+        strm = bc._reduce_stage_streamed(cur, **kw)
         assert res[1].shape[:len(lead)] == lead
         _assert_tape_stage_matches(res, strm, n, b_in, twi)
         cur, tw_cur = strm[0], twi
@@ -176,23 +184,23 @@ def test_resident_tape_stage_without_cycles():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@pytest.mark.parametrize("fuse", [1, 2])
-def test_resident_tape_svd_matches_streamed_bitwise(fuse, monkeypatch):
+@pytest.mark.parametrize("batch", [1, 3])
+def test_resident_tape_svd_matches_streamed_bitwise(batch, monkeypatch):
     """banded_svd on the resident tape stage gives the same U, sigma and
-    V^T bits as with every stage forced onto the streamed path.  At
-    ``fuse = 2`` the resident tape is still recorded in the K = 1 order,
-    and its ``ChaseTape`` must say so for the replay to read it."""
+    V^T bits as with every stage forced onto the streamed path, for one
+    matrix and a batch: both paths record the one tape layout the replay
+    reads."""
     n, bw, tw = 24, 6, 3
-    a = banded_f32(n, bw, seed=8)
+    lead = (batch,) if batch > 1 else ()
+    a = banded_f32(n, bw, seed=8, lead=lead)
     cfg = PipelineConfig.resolve(n=n, bw=bw, tw=tw, backend="pallas",
-                                 dtype=jnp.float32, fuse=fuse)
-    assert cfg.fuse == fuse
+                                 dtype=jnp.float32)
     tr = obs.Tracer("resident")
     res = svdmod.banded_svd(a, config=cfg, trace=tr)
     assert {sp.attrs["path"] for sp in _stage2_spans(tr)} == {"resident"}
     monkeypatch.setattr(bc, "stage_path", lambda *_, **__: "streamed")
     monkeypatch.setattr(bc, "reduce_stage_packed", bc._reduce_stage_streamed)
-    strm = svdmod.banded_svd(a, config=dataclasses.replace(cfg, fuse=1))
+    strm = svdmod.banded_svd(a, config=cfg)
     for x, y in zip(res, strm):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 

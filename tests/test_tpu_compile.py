@@ -69,26 +69,18 @@ def _spec(one_chip, shape, dtype=jnp.float32):
 
 # b_in = 32 with tw = 16 (a two-stage plan's first stage), and b_in = tw + 1
 # (a stage that ends at bandwidth 1; (32, 31) is the f32 default plan at
-# bw = 32); G up to the wavefront of n ~ 8k.
+# bw = 32); G up to the wavefront of n ~ 8k; the first stages of the f32
+# default plans at bw = 64 and bw = 128 (tw = 32), which the streamed path
+# runs for bands over the resident budget.
 @pytest.mark.parametrize("b_in,tw,g", [(32, 16, 200), (17, 16, 300),
-                                       (32, 31, 140)])
+                                       (32, 31, 140), (64, 32, 100),
+                                       (128, 32, 50)])
 @pytest.mark.parametrize("tape", [False, True], ids=["values", "tape"])
 def test_chase_kernel_compiles(one_chip, x64, b_in, tw, g, tape):
     h, w = b_in + 2 * tw + 1, b_in + tw + 1
     _compile(lambda win, first: bulge_chase.chase_cycle_pallas(
                  win, first, b_in=b_in, tw=tw, with_tape=tape),
              _spec(one_chip, (g, h, w)), _spec(one_chip, (g,), jnp.bool_))
-
-
-@pytest.mark.parametrize("tape", [False, True], ids=["values", "tape"])
-def test_superstep_kernel_compiles(one_chip, x64, tape):
-    b_in, tw, fuse, g = 32, 16, 4, 100
-    h, wk = b_in + 2 * tw + 1, fuse * b_in + tw + 1
-    _compile(lambda blk, first, act: bulge_chase.chase_superstep_pallas(
-                 blk, first, act, b_in=b_in, tw=tw, fuse=fuse,
-                 with_tape=tape),
-             _spec(one_chip, (g, h, wk)), _spec(one_chip, (g,), jnp.bool_),
-             _spec(one_chip, (g, fuse), jnp.bool_))
 
 
 def _largest_resident_n(b_in, tw):
@@ -101,11 +93,15 @@ def _largest_resident_n(b_in, tw):
     return lo
 
 
-# the benchmark's stage (n = 1024, the f32 default plan at bw = 32), and the
-# largest band the resident path admits at that plan
-@pytest.mark.parametrize("n", [1024, _largest_resident_n(32, 31)])
-def test_chase_stage_kernel_compiles(one_chip, x64, n):
-    b_in, tw = 32, 31
+# the benchmark's stage (n = 1024, the f32 default plan at bw = 32), the
+# largest band the resident path admits at that plan, and the stages of the
+# f32 default plans at bw = 64 (64 -> 32 -> 1) and bw = 128 (128 -> 96 ->
+# 64 -> 32 -> 1) at n = 1024
+@pytest.mark.parametrize("n,b_in,tw", [(1024, 32, 31),
+                                       (_largest_resident_n(32, 31), 32, 31),
+                                       (1024, 64, 32), (1024, 96, 32),
+                                       (1024, 128, 32)])
+def test_chase_stage_kernel_compiles(one_chip, x64, n, b_in, tw):
     assert tuning.resident_band_bytes(n, b_in, tw) <= tuning.VMEM_BUDGET_BYTES
     compiled = _compile(lambda band: bulge_chase.chase_stage_pallas(
                             band, n=n, b_in=b_in, tw=tw),
@@ -113,11 +109,12 @@ def test_chase_stage_kernel_compiles(one_chip, x64, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# the same stage recording the reflector tape: the per-cycle DMA of a
+# the same stages recording the reflector tape: the per-cycle DMA of a
 # staging slot to the dynamic tape row (b, t) is what Mosaic must accept
-@pytest.mark.parametrize("n", [1024, 4096])
-def test_chase_stage_tape_kernel_compiles(one_chip, x64, n):
-    b_in, tw = 32, 31
+@pytest.mark.parametrize("n,b_in,tw", [(1024, 32, 31), (4096, 32, 31),
+                                       (1024, 64, 32), (1024, 96, 32),
+                                       (1024, 128, 32)])
+def test_chase_stage_tape_kernel_compiles(one_chip, x64, n, b_in, tw):
     assert (tuning.resident_band_bytes(n, b_in, tw, tape=True)
             <= tuning.VMEM_BUDGET_BYTES)
     compiled = _compile(lambda band: bulge_chase.chase_stage_pallas(

@@ -273,17 +273,26 @@ def _graded_band(n, bw, case):
 
 
 @pytest.mark.parametrize("case", ["plain", "bottom_edge", "tiny"])
-@pytest.mark.parametrize("fuse", [1, 2])
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
-def test_chase_tape_reflectors_are_orthogonal(backend, fuse, case):
+@pytest.mark.parametrize("path", ["ref", "streamed", "resident"])
+def test_chase_tape_reflectors_are_orthogonal(path, case, monkeypatch):
     """tau * ||v||^2 = 2 for every reflector with tau != 0 on the chase tape,
     to 4 ulps of 2 (the float32 rounding of v's entries, tau and the sum),
     and every entry of such a reflector at a row or column past n - 1 is
-    exactly zero, so the replay may drop those rows."""
+    exactly zero, so the replay may drop those rows — on each stage-2 path
+    that records a tape: the ref backend, the streamed Pallas stage and the
+    band-resident Pallas stage."""
     n, bw, tw = 48, 8, 7
     a = _graded_band(n, bw, case)
+    backend = "ref" if path == "ref" else "pallas"
+    if path == "streamed":
+        monkeypatch.setattr(bc, "stage_path", lambda *_, **__: "streamed")
+        monkeypatch.setattr(bc, "reduce_stage_packed",
+                            bc._reduce_stage_streamed)
+    assert bc.stage_path(a.dtype, n=n, b_in=bw, tw=tw, backend=backend,
+                         tape=True) == ("resident" if path == "resident"
+                                        else "streamed")
     _, _, tapes = bc.bidiagonalize(a, bw=bw, tw=tw, backend=backend,
-                                   tape=True, fuse=fuse)
+                                   tape=True)
     eps = float(np.finfo(np.float32).eps)
     for tape in tapes:
         v = np.asarray(tape.v, np.float64)
@@ -294,16 +303,9 @@ def test_chase_tape_reflectors_are_orthogonal(backend, fuse, case):
         assert drift.max() <= 8 * eps, (case, drift.max())
         T, G = v.shape[:2]
         t, g = np.meshgrid(np.arange(T), np.arange(G), indexing="ij")
-        _, _, p, _, _ = bc.chase_cycle_indices(t, g, n, tape.b_in, tape.tw,
-                                               tape.fuse)
-        k = np.arange(tape.tw + 1)
-        if tape.fuse == 1:
-            index = p[..., None] + k                         # (T, G, k)
-            past = np.broadcast_to((index > n - 1)[:, :, None], v.shape)
-        else:
-            index = (p[..., None, None] + tape.b_in
-                     * np.arange(tape.fuse)[:, None] + k)   # (T, G, K, k)
-            past = np.broadcast_to((index > n - 1)[:, :, :, None], v.shape)
+        _, _, p, _, _ = bc.chase_cycle_indices(t, g, n, tape.b_in, tape.tw)
+        index = p[..., None] + np.arange(tape.tw + 1)       # (T, G, k)
+        past = np.broadcast_to((index > n - 1)[:, :, None], v.shape)
         assert np.all(v[past & live[..., None]] == 0)
 
 
