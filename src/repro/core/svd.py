@@ -374,8 +374,9 @@ def _uv_pipeline(a: jax.Array, *, config: tuning.PipelineConfig,
     Stage-1/2 band arithmetic is identical to the values-only path (the tape
     is recorded alongside, never read by it), so (d, e) — and the bisection
     sigma — are bit-identical.  The tapes are then replayed into transposed
-    accumulators through the ``tape_apply`` registry op, and stage 3's
-    bidiagonal vectors are composed on top.
+    accumulators (``transforms.accumulate_transforms``: the resident
+    ``replay_stage`` kernel or the streamed ``tape_apply`` loop per chase
+    stage), and stage 3's bidiagonal vectors are composed on top.
     """
     n = a.shape[-1]
     lead = a.shape[:-2]
@@ -434,8 +435,9 @@ def svd(a: jax.Array, *, bw: int | None = None, tw: int | None = None,
     ``compute_uv=False`` degrades to :func:`singular_values` (and the sigma
     returned either way are bit-identical — the tape mode records reflectors
     alongside the same band arithmetic, it never alters it).  Batched inputs
-    run batch-native end to end, including the tape replay (one fused
-    ``tape_apply`` call over all B*G wavefront slots per cycle).
+    run batch-native end to end, including the tape replay (one resident
+    kernel per chase stage over all B matrices, or on the streamed path
+    one fused ``tape_apply`` call over all B*G wavefront slots per cycle).
 
     ``check=True`` (DESIGN.md §15) validates sigma, checks U/V^T
     finiteness, and spot-checks the residual and the orthogonality of the

@@ -13,16 +13,24 @@ This module replays those tapes into accumulators, producing ``U`` and
 accumulators are kept TRANSPOSED (``U^T`` and ``V^T``) so every recorded
 reflector — left or right — is replayed as the same primitive: a compact-WY
 *left* apply ``X <- (I - V T V^T) X``, dispatched through the kernel
-registry (``kernels/ops.py::tape_apply``, with ``ref`` and ``pallas``
-impls in ``kernels/hh_apply.py``).
+registry (``kernels/ops.py``: ``tape_apply``, with ``ref`` and ``pallas``
+impls, and the Pallas-only ``replay_stage``, both in
+``kernels/hh_apply.py``).
 
-The chase replay preserves the wavefront batching of the chase itself: per
-global cycle, the G per-slot row slices of all B problems are gathered into
-one fused ``tape_apply`` call over ``B*G`` slots (grid ``(B·G, stripes)``)
-and scattered back — the 3-cycle separation that makes chase windows
-disjoint also makes the replayed row ranges ``[p, p+tw]`` disjoint, so the
-scatter is race-free.  Memory cost of a stage tape is ``O(n·tw)`` per cycle
-(two ``(tw+1)``-reflectors per slot, ``G ~ n / (3 b_in)`` slots).
+A chase stage's tape is replayed on one of two paths, chosen by
+:func:`replay_path` from what the call can observe, as ``bc.stage_path``
+chooses stage 2's.  The *resident* path (Pallas, 32-bit accumulators, a
+128-lane column stripe fits fast memory) is one ``ops.replay_stage``
+kernel per stage: each column stripe of ``U^T`` / ``V^T`` stays in VMEM
+while every reflector of the stage is applied to it in cycle order.  The
+*streamed* path (the ``ref`` backend, float64) keeps the wavefront
+batching of the chase itself: per global cycle, the G per-slot row slices
+of all B problems are gathered into one fused ``tape_apply`` call over
+``B*G`` slots and scattered back — the 3-cycle separation that makes chase
+windows disjoint also makes the replayed row ranges ``[p, p+tw]``
+disjoint, so the scatter is race-free.  Memory cost of a stage tape is
+``O(n·tw)`` per cycle (two ``(tw+1)``-reflectors per slot,
+``G ~ n / (3 b_in)`` slots).
 """
 
 from __future__ import annotations
@@ -37,7 +45,7 @@ from repro import obs
 from repro.core import bulge_chasing as bc
 
 __all__ = ["ChaseTape", "accumulate_transforms", "replay_stage1",
-           "replay_chase"]
+           "replay_chase", "replay_path"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,6 +99,25 @@ def replay_stage1(ut: jax.Array, vt: jax.Array, tape, *, config=None):
     return jax.lax.fori_loop(0, n_panels, body, (ut, vt))
 
 
+def replay_path(dtype, *, n: int, b_in: int, tw: int, backend: str = "auto",
+                config=None) -> str:
+    """``"resident"`` or ``"streamed"``: which implementation
+    :func:`replay_chase` runs for one chase stage's tape of ``dtype`` data
+    (DESIGN.md §8).  Resident iff the resolved backend is "pallas"
+    (interpret mode off the TPU), the accumulators are 32-bit (bf16 data
+    accumulates in f32) and a 128-lane column stripe fits
+    ``tuning.VMEM_BUDGET_BYTES`` (``tuning.replay_stripe_cols``)."""
+    from repro.core import tuning
+    from repro.kernels import ops
+    acc = _acc_dtype(jnp.dtype(dtype))
+    if jnp.dtype(acc).itemsize != 4:
+        return "streamed"
+    if ops.resolved_backend(backend, config, acc) != "pallas":
+        return "streamed"
+    fits = tuning.replay_stripe_cols(n, b_in, tw, acc) > 0
+    return "resident" if fits else "streamed"
+
+
 @functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "config"))
 @obs.scope("replay")
 def replay_chase(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
@@ -98,12 +125,28 @@ def replay_chase(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
                  config=None):
     """Replay one chase stage's tape into the transposed accumulators.
 
-    ut/vt: (B, n, n).  Reuses the chase schedule (``chase_cycle_indices``)
-    to recover each slot's pivot — the tape stores only (v, tau), the row
-    ranges are shape-derived, exactly like the chase's own window gather.
-    Inactive slots were recorded with tau = 0 and are routed to disjoint
-    dump rows (identity applies on scratch space).
+    ut/vt: (B, n, n).  On the path :func:`replay_path` picks: the resident
+    ``ops.replay_stage`` kernel, or :func:`_replay_chase_streamed`; both
+    recover each slot's pivot from the chase schedule — the tape stores
+    only (v, tau), the row ranges are shape-derived, exactly like the
+    chase's own window gather.
     """
+    if replay_path(ut.dtype, n=n, b_in=b_in, tw=tw,
+                   config=config) == "resident":
+        from repro.kernels import ops
+        return ops.replay_stage(ut, vt, tape_v, tape_tau, n=n, b_in=b_in,
+                                tw=tw, config=config)
+    return _replay_chase_streamed(ut, vt, tape_v, tape_tau, n=n, b_in=b_in,
+                                  tw=tw, config=config)
+
+
+def _replay_chase_streamed(ut, vt, tape_v, tape_tau, *, n: int, b_in: int,
+                           tw: int, config=None):
+    """The streamed replay: per global cycle, gather every slot's rows of
+    U^T and V^T, apply with one ``ops.tape_apply`` call per side, scatter
+    back.  ``chase_cycle_indices`` gives each slot's pivot; inactive slots
+    were recorded with tau = 0 and are routed to disjoint dump rows
+    (identity applies on scratch space)."""
     from repro.kernels import ops
 
     nsweeps, T, G = bc.stage_schedule(n, b_in, tw)
@@ -146,7 +189,10 @@ def accumulate_transforms(n: int, *, s1_tape=None, chase_tapes=(),
     """Replay all tapes from identity: returns (u, vt) with A = U B V^T.
 
     ``lead`` is the batch shape; accumulators run in the fp32-or-better
-    accumulation dtype of ``dtype`` and are cast back at the end.
+    accumulation dtype of ``dtype`` and are cast back at the end.  Each
+    chase stage's replay runs on the path :func:`replay_path` picks; its
+    ``replay_chase`` span carries ``path=`` and ``obs.count_replay_stage``
+    counts it.
     """
     acc = _acc_dtype(jnp.dtype(dtype))
     b = 1
@@ -162,8 +208,11 @@ def accumulate_transforms(n: int, *, s1_tape=None, chase_tapes=(),
     for tape in chase_tapes:
         tv = tape.v.reshape((b,) + tape.v.shape[len(lead):]).astype(acc)
         tt = tape.tau.reshape((b,) + tape.tau.shape[len(lead):]).astype(acc)
+        path = replay_path(acc, n=tape.n, b_in=tape.b_in, tw=tape.tw,
+                           config=config)
+        obs.count_replay_stage(path)
         with obs.span("replay_chase", n=tape.n, b_in=tape.b_in, tw=tape.tw,
-                      tape_bytes=tape.nbytes):
+                      tape_bytes=tape.nbytes, path=path):
             ut, vt = replay_chase(ut, vt, tv, tt, n=tape.n, b_in=tape.b_in,
                                   tw=tape.tw, config=config)
     u = jnp.swapaxes(ut, -1, -2)

@@ -32,6 +32,8 @@ __all__ = [
     "vmem_working_set_bytes", "check_vmem_budget",
     "fused_working_set_bytes", "check_fused_vmem_budget",
     "resident_band_layout", "resident_band_bytes", "tape_stage_lanes",
+    "REPLAY_CHUNK", "replay_layout", "resident_replay_bytes",
+    "replay_stripe_cols",
     "DEFAULT_FUSED_CROSSOVER", "STAGE3_CHOICES",
     "stage_plan", "default_bucket_batch", "ChaseConfig", "PipelineConfig",
 ]
@@ -159,6 +161,57 @@ def tape_stage_lanes(tw: int) -> int:
     """Lanes of one reflector-tape row of the band-resident stage kernel:
     v's tw + 1 entries, then tau, padded to the lane width."""
     return _round_up(tw + 2, LANE)
+
+
+# Cycles of chase tape per DMA of the resident replay kernel: a multiple of
+# the 3-cycle sweep separation, so the kernel splits t = 3*t3 + r statically,
+# and of every count of cycles one tape block may hold (1 to 4).
+REPLAY_CHUNK = 12
+
+
+def replay_layout(n: int, b_in: int, tw: int) -> tuple[int, int, int, int]:
+    """(rows, block_rows, lanes, cycles_per_block) of the resident replay
+    kernel (DESIGN.md §8).  One apply touches an 8-aligned block of
+    ``block_rows`` = round_up(tw + 9, 8) rows starting at p - p % 8,
+    which holds the reflector's rows [p, p + tw] whatever p % 8 is, and
+    one row more; the accumulator stripe has ``rows`` = round_up(n +
+    block_rows, 8), so a block at a pivot p <= n - 1 stays inside it.  A
+    tape block of (block_rows, lanes) holds ``cycles_per_block`` cycles of
+    one side: cycle i's slot g in lane i * G + g, v shifted down to row
+    p % 8 and tau in the last row."""
+    g = max_concurrent_sweeps(n, b_in)
+    blk = _round_up(tw + SUBLANE + 1, SUBLANE)
+    lanes = _round_up(g, LANE)
+    per_block = max(c for c in (1, 2, 3, 4) if c * g <= lanes)
+    return _round_up(n + blk, SUBLANE), blk, lanes, per_block
+
+
+def resident_replay_bytes(n: int, b_in: int, tw: int, cols: int,
+                          dtype=jnp.float32) -> int:
+    """VMEM bytes the resident replay kernel allocates for a stripe of
+    ``cols`` accumulator columns: the (rows, cols) stripe, copied in and
+    out by hand, and two tape buffers of ``REPLAY_CHUNK`` cycles each."""
+    rows, blk, lanes, per_block = replay_layout(n, b_in, tw)
+    tape = 2 * (REPLAY_CHUNK // per_block) * blk * lanes
+    return (rows * cols + tape) * _bytes(dtype)
+
+
+def replay_stripe_cols(n: int, b_in: int, tw: int, dtype=jnp.float32, *,
+                       budget_bytes: int | None = None) -> int:
+    """Column-stripe width of the resident replay: the fewest stripes of
+    whole 128-lane tiles whose widest fits ``budget_bytes`` (default
+    ``VMEM_BUDGET_BYTES``) as :func:`resident_replay_bytes` counts it, each
+    as narrow as that count allows; 0 when not even one lane tile fits."""
+    budget = VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
+    width = _round_up(n, LANE)
+    widest = width
+    while widest and resident_replay_bytes(n, b_in, tw, widest,
+                                           dtype) > budget:
+        widest -= LANE
+    if not widest:
+        return 0
+    stripes = -(-width // widest)
+    return _round_up(-(-n // stripes), LANE)
 
 
 # Default fused-vs-staged crossover (DESIGN.md §13): the ROADMAP names

@@ -27,6 +27,7 @@ from repro import obs
 from repro.kernels import ref as _ref
 
 __all__ = ["chase_cycle", "chase_stage", "hh_block_apply", "tape_apply",
+           "replay_stage",
            "fused_svd", "register_backend", "resolve_backend",
            "resolved_backend", "backend_names"]
 
@@ -157,6 +158,13 @@ def _pallas_tape(v, t, c, *, block_cols, interpret):
                                       block_cols=block_cols)
 
 
+def _pallas_replay_stage(ut, vt, tape_v, tape_tau, *, n, b_in, tw,
+                         interpret):
+    from repro.kernels import hh_apply
+    return hh_apply.replay_stage_pallas(ut, vt, tape_v, tape_tau, n=n,
+                                        b_in=b_in, tw=tw, interpret=interpret)
+
+
 def _pallas_fused(mats, *, bw, compute_uv, interpret):
     from repro.kernels import fused_small
     return fused_small.fused_small_svd_pallas(mats, bw=bw,
@@ -166,7 +174,8 @@ def _pallas_fused(mats, *, bw, compute_uv, interpret):
 
 register_backend("pallas", chase_cycle=_pallas_chase,
                  chase_stage=_pallas_chase_stage, hh_block_apply=_pallas_hh,
-                 tape_apply=_pallas_tape, fused_svd=_pallas_fused)
+                 tape_apply=_pallas_tape, replay_stage=_pallas_replay_stage,
+                 fused_svd=_pallas_fused)
 
 
 # ---- "fused_small" (DESIGN.md §13): the one-dispatch small-n SVD tier ------
@@ -253,6 +262,24 @@ def tape_apply(v: jax.Array, t: jax.Array, c: jax.Array, *,
     backend, interpret = _resolve(backend, interpret, config, c.dtype)
     return _impl("tape_apply", backend)(v, t, c, block_cols=block_cols,
                                         interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "b_in", "tw", "backend",
+                                             "interpret", "config"))
+def replay_stage(ut: jax.Array, vt: jax.Array, tape_v: jax.Array,
+                 tape_tau: jax.Array, *, n: int, b_in: int, tw: int,
+                 backend: str = "auto", interpret: bool | None = None,
+                 config=None):
+    """Replay one chase stage's reflector tape ``(B, T, G, 2, tw+1)`` /
+    ``(B, T, G, 2)`` into the transposed accumulators ``U^T``, ``V^T``
+    ``(B, n, n)``, each column stripe resident in fast memory for the
+    stage (DESIGN.md §8); returns the updated ``(U^T, V^T)``.  Only the
+    "pallas" backend implements it; ``core.transforms.replay_path`` says
+    when it is used."""
+    backend, interpret = _resolve(backend, interpret, config, ut.dtype)
+    return _impl("replay_stage", backend)(ut, vt, tape_v, tape_tau, n=n,
+                                          b_in=b_in, tw=tw,
+                                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "interpret",

@@ -6,7 +6,8 @@ Three pieces, importable from this package root:
   that land on the profiler's clock as ``repro/<name>`` annotations, are
   recorded into a tracer only when one is active, and never change what
   runs; plus the program's own compile counter, :func:`compile_counts`,
-  the stage-2 chase-path counter, :func:`chase_stage_counts`, and the
+  the stage-2 chase-path counter, :func:`chase_stage_counts`, the
+  tape-replay path counter, :func:`replay_stage_counts`, and the
   reflector-tape byte counter, :func:`tape_bytes` (``trace.py``);
   :func:`scope` names a stage's jitted body ``repro.<stage>`` for the
   device trace.  Ambient-tracer helpers: :func:`current`,
@@ -30,9 +31,11 @@ from .trace import (
     chase_stage_counts,
     compile_counts,
     count_chase_stage,
+    count_replay_stage,
     count_tape_bytes,
     current,
     install,
+    replay_stage_counts,
     scope,
     span,
     tape_bytes,
@@ -53,9 +56,11 @@ __all__ = [
     "chase_stage_counts",
     "compile_counts",
     "count_chase_stage",
+    "count_replay_stage",
     "count_tape_bytes",
     "current",
     "install",
+    "replay_stage_counts",
     "scope",
     "span",
     "tape_bytes",

@@ -182,9 +182,10 @@ def render_compile_metrics() -> str:
     """Exposition text for the program's compile counter: executables
     compiled or loaded from the persistent cache, and the loads among them,
     by the innermost program span open when each happened; then the
-    stage-2 stages by chase path and the bytes of reflector tape recorded
-    by stage."""
-    from repro.obs.trace import chase_stage_counts, compile_counts, tape_bytes
+    stage-2 stages by chase path, the chase-tape replays by replay path
+    and the bytes of reflector tape recorded by stage."""
+    from repro.obs.trace import (chase_stage_counts, compile_counts,
+                                 replay_stage_counts, tape_bytes)
 
     counts = compile_counts()
     lines: list[str] = []
@@ -204,6 +205,13 @@ def render_compile_metrics() -> str:
     lines.append("# TYPE repro_chase_stages_total counter")
     for path, n in sorted(chase_stage_counts().items()):
         lines.append(_sample("repro_chase_stages_total", {"path": path},
+                             int(n)))
+    lines.append("# HELP repro_replay_stages_total Chase-tape replays by "
+                 "replay path, per eager call or per trace of a jitted "
+                 "pipeline.")
+    lines.append("# TYPE repro_replay_stages_total counter")
+    for path, n in sorted(replay_stage_counts().items()):
+        lines.append(_sample("repro_replay_stages_total", {"path": path},
                              int(n)))
     lines.append("# HELP repro_tape_bytes_total Bytes of reflector tape "
                  "recorded, by stage, per eager call or per trace of a "

@@ -44,8 +44,10 @@ recorded span (``compiles``, ``cache_loads``), in its JSONL export, and
 process-wide in :func:`compile_counts`, which ``obs.prom`` renders as
 ``repro_compiles_total{span=...}``.  Beside it, :func:`chase_stage_counts`
 (``repro_chase_stages_total{path=...}``) counts stage-2 stages by chase
-path and :func:`tape_bytes` (``repro_tape_bytes_total{stage=...}``) the
-bytes of reflector tape recorded.
+path, :func:`replay_stage_counts` (``repro_replay_stages_total{path=...}``)
+chase-tape replays by replay path, and :func:`tape_bytes`
+(``repro_tape_bytes_total{stage=...}``) the bytes of reflector tape
+recorded.
 """
 
 from __future__ import annotations
@@ -71,6 +73,8 @@ __all__ = [
     "compile_counts",
     "count_chase_stage",
     "chase_stage_counts",
+    "count_replay_stage",
+    "replay_stage_counts",
     "count_tape_bytes",
     "tape_bytes",
     "scope",
@@ -381,6 +385,26 @@ def chase_stage_counts() -> dict[str, int]:
     """Process-wide stage-2 stage counts by chase path."""
     with _compile_lock:
         return dict(_chase_stages)
+
+
+# ----------------------------------------------------------------------
+# tape-replay path counter
+
+_replay_stages: collections.Counter = collections.Counter()
+
+
+def count_replay_stage(path: str) -> None:
+    """Count one chase stage's tape replay by the path that runs it
+    (``"resident"`` or ``"streamed"``, DESIGN.md §8); counted as
+    :func:`count_chase_stage` counts."""
+    with _compile_lock:
+        _replay_stages[path] += 1
+
+
+def replay_stage_counts() -> dict[str, int]:
+    """Process-wide chase-tape replays by replay path."""
+    with _compile_lock:
+        return dict(_replay_stages)
 
 
 # ----------------------------------------------------------------------

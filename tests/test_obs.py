@@ -367,7 +367,8 @@ def test_svd_uv_trace_has_replay_children():
 def test_tape_bytes_counter_and_replay_span():
     """``repro_tape_bytes_total`` counts each recorded tape's bytes from its
     static shapes where it is made, stage 1's and stage 2's apart, and the
-    ``replay_chase`` span carries the bytes it replays."""
+    ``replay_chase`` span carries the bytes it replays and the replay path
+    (float64 on the ref backend: streamed)."""
     from repro.core import bulge_chasing as bc
     cfg = PipelineConfig.resolve(n=16, bw=4, tw=3, backend="ref",
                                  dtype=np.float64)
@@ -387,6 +388,7 @@ def test_tape_bytes_counter_and_replay_span():
     assert counted() - c0 == {"stage2": stage2}
     (span,) = tr.roots[0].find("replay_chase")
     assert span.attrs["tape_bytes"] == stage2
+    assert span.attrs["path"] == "streamed"
     c0 = counted()
     svdmod.svd(jnp.asarray(np.random.default_rng(3).standard_normal((16, 16))),
                config=cfg)

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import bulge_chasing as bc
-from repro.core import svd, tuning
+from repro.core import svd, transforms, tuning
 from repro.kernels import bulge_chase, fused_small, hh_apply
 
 
@@ -126,17 +126,36 @@ def test_chase_stage_tape_kernel_compiles(one_chip, x64, n, b_in, tw):
 def test_uv_pipeline_compiles(one_chip, x64):
     """The full banded SVD the chip runs at n = 1024, bw = 32: its stage 2
     is the band-resident ``chase_stage`` kernel recording the tape, and no
-    streamed ``chase_cycle`` is left in it."""
+    streamed ``chase_cycle`` is left in it; its replay is the resident
+    ``replay_stage`` kernel, and no ``tape_apply`` is left in it."""
     n = 1024
     cfg = tuning.PipelineConfig.resolve(bw=32, backend="pallas",
                                         interpret=False, dtype=jnp.float32,
                                         n=n).kernel()
     assert bc.stage_path(jnp.float32, n=n, b_in=32, tw=cfg.tw, config=cfg,
                          tape=True) == "resident"
+    assert transforms.replay_path(jnp.float32, n=n, b_in=32, tw=cfg.tw,
+                                  config=cfg) == "resident"
     text = _compile(lambda a: svd._uv_pipeline(a, config=cfg, banded=True),
                     _spec(one_chip, (n, n))).as_text()
     # (``chase_cycle_indices``, the replay's schedule, stays in op metadata)
     assert "chase_stage" in text and not re.search(r"chase_cycle\b", text)
+    assert "replay_stage" in text and "tape_apply" not in text
+
+
+# the benchmark's replay (n = 1024, the f32 default plan at bw = 32: one
+# stripe a side), two matrices, and n = 4096 (five 896-column stripes)
+@pytest.mark.parametrize("n,b_in,tw,batch", [(1024, 32, 31, 1),
+                                             (1024, 32, 31, 2),
+                                             (4096, 32, 31, 1)])
+def test_replay_stage_kernel_compiles(one_chip, x64, n, b_in, tw, batch):
+    _, T, G = bc.stage_schedule(n, b_in, tw)
+    acc = _spec(one_chip, (batch, n, n))
+    compiled = _compile(lambda ut, vt, v, tau: hh_apply.replay_stage_pallas(
+                            ut, vt, v, tau, n=n, b_in=b_in, tw=tw),
+                        acc, acc, _spec(one_chip, (batch, T, G, 2, tw + 1)),
+                        _spec(one_chip, (batch, T, G, 2)))
+    assert "replay_stage" in compiled.as_text()
 
 
 # stage-1 panel replay at m = 4096 (k = nb = 32) and chase-tape replay
